@@ -28,6 +28,8 @@ def main() -> None:
                     help="render roofline table from dry-run artifacts")
     args = ap.parse_args()
 
+    from repro.compile_cache import place_compile_cache
+    place_compile_cache()
     from . import (alpha, enginebench, itemsize, kernelbench, overhead,
                    setsize, shardbench, statesync, throughput, wirebench)
     suites = [

@@ -33,11 +33,8 @@ def resolve_backend(backend: str) -> str:
         raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
     if backend != "auto":
         return backend
-    try:
-        import jax
-        return "device" if jax.default_backend() == "tpu" else "host"
-    except Exception:
-        return "host"
+    import jax
+    return "device" if jax.default_backend() == "tpu" else "host"
 
 
 @dataclasses.dataclass

@@ -9,16 +9,18 @@ hash.  Constant cost per mapped index, O(log m) mapped indices in the first
 ``m`` — the property that gives Rateless IBLT its O(ℓ·log d) costs.
 
 Determinism contract: the host (numpy) and device (JAX uint32-pair) chains
-produce *identical* index sequences.  All real arithmetic is float32 with an
-identical op sequence on both paths (no FMA-fusable patterns), so IEEE-754
-guarantees bit-equal results.
+produce *identical* index sequences.  The host's real arithmetic is IEEE-754
+float32; the device reproduces its square root and reciprocal in exact
+integer arithmetic and keeps only a subtract, a multiply and a ceil in
+float32, which every backend rounds the same way.
 """
 from __future__ import annotations
 
 import math
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .hashing import map_key, siphash24, siphash24_pair
 
@@ -165,15 +167,82 @@ def map_seeds_pair(words, key, nbytes: int | None = None):
     return hi, lo | jnp.uint32(1)
 
 
+_JUMP_CAP = 2.0 ** 30     # device jumps are clamped here; needs m < 2**30
+
+
+def _sqrt_rn(J):
+    """``(Q, fb)`` with ``Q·2**-fb`` = float32 round-to-nearest of
+    ``sqrt(J)``, for int32 ``J`` in [1, 2**24].
+
+    ``fb`` is the number of fraction bits float32 leaves beside the
+    integer part of ``sqrt(J)``; ``Q = RN(sqrt(J·4**fb))`` comes from the
+    binary digit-by-digit square root of the 48-bit ``J·4**fb``, fed two
+    bits at a time.  Root and remainder stay below 2**27: exact int32.
+    """
+    e = (jax.lax.bitcast_convert_type(J.astype(jnp.float32), jnp.int32)
+         >> 23) - 127                                  # floor(log2 J)
+    fb = 23 - (e >> 1)
+
+    def digit(i, carry):
+        root, rem = carry
+        sh = 2 * (23 - i) - 2 * fb                     # bit pair of J·4**fb
+        pair = jnp.where(sh >= 0, (J >> jnp.maximum(sh, 0)) & 3, 0)
+        rem = (rem << 2) | pair
+        trial = (root << 2) | 1
+        take = rem >= trial
+        return ((root << 1) | take.astype(jnp.int32),
+                jnp.where(take, rem - trial, rem))
+
+    zero = jnp.zeros_like(J)
+    root, rem = jax.lax.fori_loop(0, 24, digit, (zero, zero))
+    # no ties: rem > root  <=>  J·4**fb >= root² + root + 1
+    return root + (rem > root).astype(jnp.int32), fb
+
+
+def _recip_rn(Q):
+    """``RN(2**47 / Q)`` for int32 ``Q`` in [2**23, 2**24], by binary long
+    division; the remainder stays below 2**25.  No ties: 2**48 is not an
+    odd multiple of Q."""
+    q = (Q <= (1 << 23)).astype(jnp.int32)
+    rem = (1 << 23) - q * Q
+
+    def bit(_, carry):
+        q, rem = carry
+        rem = rem << 1
+        take = rem >= Q
+        return ((q << 1) | take.astype(jnp.int32),
+                jnp.where(take, rem - Q, rem))
+
+    q, rem = jax.lax.fori_loop(0, 24, bit, (q, rem))
+    return q + ((rem << 1) > Q).astype(jnp.int32)
+
+
+def _pow2(e):
+    """float32 ``2**e`` for int32 ``e`` in the normal range."""
+    return jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
+
+
 def _jump_j(idx, h, l):
-    """One skip-sampling step on device.  idx int32, (h, l) uint32 state."""
+    """One skip-sampling step on device.  idx int32, (h, l) uint32 state.
+
+    Equal to :func:`_jump_np` for every index below ``m < 2**30``.
+    ``t = 1/sqrt(1 - r)`` is computed in exact integer arithmetic with the
+    roundings of float32 (``_sqrt_rn``, ``_recip_rn``): compilers replace
+    float32 ``1/sqrt`` by approximations (XLA's CPU ``rsqrt`` is 1 ulp off
+    for 29% of the inputs), and one ulp moves a chain.  ``f·u`` reaches
+    ~4096·m, past int32, and backends convert out-of-range floats
+    differently, so the jump is clamped to 2**30 in float first: a clamped
+    jump still lands at or past ``m``, where the callers saturate the chain.
+    """
     h, l = _xs64_pair(h, l)
-    rbits = (h >> jnp.uint32(8)).astype(jnp.float32)      # top 24 bits of u64
-    r = rbits * jnp.float32(2.0 ** -24)
-    t = jnp.float32(1.0) / jnp.sqrt(jnp.float32(1.0) - r)
+    # 1 - r = J·2**-24 with r = (top 24 state bits)·2**-24; then
+    # t = 2**12 / RN(sqrt(J)) = 2**12 · RN(2**47/Q) · 2**(fb-47)
+    J = (1 << 24) - (h >> jnp.uint32(8)).astype(jnp.int32)
+    Q, fb = _sqrt_rn(J)
+    t = _recip_rn(Q).astype(jnp.float32) * _pow2(fb - 35)
     u = t - jnp.float32(1.0)
     f = jnp.float32(1.5) + idx.astype(jnp.float32)
-    g = jnp.ceil(f * u).astype(jnp.int32)
+    g = jnp.minimum(jnp.ceil(f * u), jnp.float32(_JUMP_CAP)).astype(jnp.int32)
     g = jnp.maximum(g, 1)
     return idx + g, h, l
 

@@ -9,8 +9,17 @@ bit-identical by construction.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashing import map_key, siphash24_pair
+
+
+def pallas_interpret(interpret: bool):
+    """``pallas_call``'s ``interpret`` argument for a kernel flag: the TPU
+    interpreter, or compiled.  XLA compiles the generic interpreter's
+    program for a multi-step grid of the SipHash kernels in minutes; the
+    TPU interpreter's in seconds."""
+    return pltpu.InterpretParams() if interpret else False
 
 
 def checksum_pair(items, key, nbytes: int):

@@ -2,19 +2,27 @@
 
 Part 2 of the encoder (paper §7.2 shows XOR-summing dominates compute).
 TPUs have no scatter-XOR, so the Go design (heap + pointer-chased XOR into
-one symbol at a time) is replaced by dense VPU work (DESIGN.md §3):
+one symbol at a time) is replaced by dense VPU work:
 
   grid (m_blocks, n_blocks) — n innermost so each (BM, L) output tile stays
-  resident in VMEM while every item block streams past it once.  For item
-  block j and symbol tile i: build an equality mask between the block's
-  mapped indices (BN, K) and the tile's symbol iota (BM,), then XOR-reduce
-  masked items over the item axis with a log2(BN) halving tree.
+  resident in VMEM while every item block streams past it once.  Within an
+  item block, a loop over chunks of ``_CHUNK`` items builds the chunk's
+  (CH, BM) mask — one compare of each mapped-index column against the
+  tile's symbol iota — and XOR-reduces the masked items over the chunk with
+  a log2(CH) halving tree.
 
-VMEM working set: items (BN·L) + idx (BN·K) + out tile (BM·(L+3)) words
-plus the transient masked product (BN·BM·L u32) that feeds the XOR tree —
-BN=256, BM=256, L=8 → ~2 MB transient, inside the ~16 MB v5e VMEM with
-double buffering.  BM is 128-aligned for lane-width friendliness; block
-sizes are tunable (see EXPERIMENTS.md §Perf for the sweep).
+One kernel serves the encoder and the peel decoder's chain removal: counts
+accumulate a signed weight per item (+1 to encode, the item's side to
+remove it).  The prefix length ``m`` is a scalar-prefetch operand, not a
+compile-time constant, so one compiled kernel serves every prefix length
+in a tile bucket.
+
+VMEM working set (v5e scoped limit 16 MiB): double-buffered input blocks
+items (BN·L) + idx (BN·K) + chks/sides (BN·3) words, the (BM, L+3) output
+tiles, and per chunk the (CH, BM) mask plus the (CH, BM, L) masked product.
+L sits on the 128-lane axis, so the product takes CH·BM·128·4 B = 1 MiB at
+CH=8, BM=256 for any L ≤ 128.  Materialising the whole block's (BN, K, BM)
+compare and (BN, BM, L) product instead needs 28 MiB at BN=BM=256, K=65.
 """
 from __future__ import annotations
 
@@ -23,6 +31,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .common import pallas_interpret
+
+_CHUNK = 8   # items per XOR tree: one sublane tile
 
 
 def _tree_xor(v):
@@ -34,8 +47,8 @@ def _tree_xor(v):
     return v[0]
 
 
-def _kernel(items_ref, idx_ref, chk_ref, sums_ref, checks_ref, counts_ref,
-            *, K: int, block_m: int, m: int):
+def _kernel(m_ref, items_ref, idx_ref, chk_ref, wt_ref, sums_ref, checks_ref,
+            counts_ref, *, block_m: int):
     i = pl.program_id(0)   # symbol tile
     j = pl.program_id(1)   # item block (innermost: accumulation)
 
@@ -45,52 +58,84 @@ def _kernel(items_ref, idx_ref, chk_ref, sums_ref, checks_ref, counts_ref,
         checks_ref[...] = jnp.zeros_like(checks_ref)
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    items = items_ref[...]          # (BN, L) uint32
-    chks = chk_ref[...]             # (BN, 2) uint32
-    idxs = idx_ref[...]             # (BN, K) int32
-    bn, L = items.shape
-    base = i * block_m
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, block_m), 1) + base
+    bn, K = idx_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, block_m), 1) + \
+        i * block_m
+    live = lane < m_ref[0]          # pad index m (and rows past m) map nowhere
 
-    # The chain is strictly increasing, so an item maps to a given symbol at
-    # most once within its K slots: the (BN, K, BM) equality tensor reduces
-    # to a (BN, BM) mask with `any` — no loop over K, ~25 VPU ops total.
-    eq = (idxs[:, :, None] == lane[:, None, :]) & (idxs[:, :, None] < m)
-    mask = jnp.any(eq, axis=1)                         # (BN, BM)
-    mask_u = mask.astype(jnp.uint32)
-    counts_ref[...] = counts_ref[...] + \
-        jnp.sum(mask, axis=0, dtype=jnp.int32)[:, None]
-    sums_ref[...] = sums_ref[...] ^ \
-        _tree_xor(mask_u[:, :, None] * items[:, None, :])
-    checks_ref[...] = checks_ref[...] ^ \
-        _tree_xor(mask_u[:, :, None] * chks[:, None, :])
+    def chunk(c, acc):
+        sums, checks, counts = acc
+        rows = pl.ds(pl.multiple_of(c * _CHUNK, _CHUNK), _CHUNK)
+        idxs = idx_ref[rows, :]                          # (CH, K) int32
+        # a chain is strictly increasing, so an item hits a symbol at most
+        # once among its K slots: OR-ing the K column compares is exact
+        hit = idxs[:, 0:1] == lane
+        for k in range(1, K):
+            hit = hit | (idxs[:, k:k + 1] == lane)
+        hit = hit & live                                 # (CH, BM)
+        counts = counts + jnp.sum(hit.astype(jnp.int32) * wt_ref[rows, :],
+                                  axis=0)[:, None]
+        hit_u = hit.astype(jnp.uint32)[:, :, None]
+        sums = sums ^ _tree_xor(hit_u * items_ref[rows, :][:, None, :])
+        checks = checks ^ _tree_xor(hit_u * chk_ref[rows, :][:, None, :])
+        return sums, checks, counts
+
+    sums, checks, counts = jax.lax.fori_loop(
+        0, bn // _CHUNK, chunk,
+        (sums_ref[...], checks_ref[...], counts_ref[...]))
+    sums_ref[...] = sums
+    checks_ref[...] = checks
+    counts_ref[...] = counts
+
+
+def iblt_apply(items, idxs, chks, sides, *, m, m_out: int | None = None,
+               block_m: int = 256, block_n: int = 256,
+               interpret: bool = True):
+    """Signed coded-symbol delta of ``items`` over their mapped chains.
+
+    items (n, L) uint32, idxs (n, K) int32 (an index ≥ m maps nowhere),
+    chks (n, 2) uint32, sides (n,) int32 -> (sums (m', L) uint32,
+    checks (m', 2) uint32, counts (m', 1) int32) with m' = ``m_out``, by
+    default m rounded up to block_m.  ``m`` may be traced when ``m_out``
+    is given.  The peel decoder XORs the sums/checks delta into its symbol
+    state and *subtracts* the counts delta (removal = encode with negated
+    sign).
+    """
+    n, L = items.shape
+    K = idxs.shape[1]
+    assert n % block_n == 0 and block_n % _CHUNK == 0, (n, block_n)
+    if m_out is None:
+        m_out = -(-int(m) // block_m) * block_m
+    assert m_out % block_m == 0, (m_out, block_m)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(m_out // block_m, n // block_n),
+        in_specs=[pl.BlockSpec((block_n, L), lambda i, j, m: (j, 0)),
+                  pl.BlockSpec((block_n, K), lambda i, j, m: (j, 0)),
+                  pl.BlockSpec((block_n, 2), lambda i, j, m: (j, 0)),
+                  pl.BlockSpec((block_n, 1), lambda i, j, m: (j, 0))],
+        out_specs=[pl.BlockSpec((block_m, L), lambda i, j, m: (i, 0)),
+                   pl.BlockSpec((block_m, 2), lambda i, j, m: (i, 0)),
+                   pl.BlockSpec((block_m, 1), lambda i, j, m: (i, 0))])
+    return pl.pallas_call(
+        functools.partial(_kernel, block_m=block_m),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((m_out, L), jnp.uint32),
+                   jax.ShapeDtypeStruct((m_out, 2), jnp.uint32),
+                   jax.ShapeDtypeStruct((m_out, 1), jnp.int32)],
+        interpret=pallas_interpret(interpret),
+    )(jnp.asarray(m, jnp.int32).reshape(1), items, idxs, chks,
+      sides.astype(jnp.int32)[:, None])
 
 
 def iblt_encode(items, idxs, chks, *, m: int, block_m: int = 256,
                 block_n: int = 256, interpret: bool = True):
-    """Accumulate coded symbols.
+    """Accumulate coded symbols: :func:`iblt_apply` with every weight +1.
 
     items (n, L) uint32, idxs (n, K) int32 (pad = m), chks (n, 2) uint32
     -> (sums (m', L) uint32, checks (m', 2) uint32, counts (m', 1) int32)
     with m' = m rounded up to block_m (ops.py trims).
     """
-    n, L = items.shape
-    K = idxs.shape[1]
-    assert n % block_n == 0
-    mp = ((m + block_m - 1) // block_m) * block_m
-    grid = (mp // block_m, n // block_n)
-    kernel = functools.partial(_kernel, K=K, block_m=block_m, m=m)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_n, L), lambda i, j: (j, 0)),
-                  pl.BlockSpec((block_n, K), lambda i, j: (j, 0)),
-                  pl.BlockSpec((block_n, 2), lambda i, j: (j, 0))],
-        out_specs=[pl.BlockSpec((block_m, L), lambda i, j: (i, 0)),
-                   pl.BlockSpec((block_m, 2), lambda i, j: (i, 0)),
-                   pl.BlockSpec((block_m, 1), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((mp, L), jnp.uint32),
-                   jax.ShapeDtypeStruct((mp, 2), jnp.uint32),
-                   jax.ShapeDtypeStruct((mp, 1), jnp.int32)],
-        interpret=interpret,
-    )(items, idxs, chks)
+    return iblt_apply(items, idxs, chks, jnp.ones(items.shape[0], jnp.int32),
+                      m=m, block_m=block_m, block_n=block_n,
+                      interpret=interpret)

@@ -4,9 +4,10 @@
 counterpart of ``repro.core.encode`` and produces bit-identical coded
 symbols; ``decode_device`` (pad → wave peeling, :mod:`kernels.peel`) is the
 counterpart of ``repro.core.peel`` and recovers the identical difference.
-``interpret=None`` auto-selects: real kernels on TPU, interpret mode on CPU
-(where the pure-jnp "ref" engines are used — the Pallas interpreter pays
-~10 ms/op; the kernels themselves are validated in tests at small sizes).
+:func:`peel_engine` is the one place the platform picks the engine: the
+compiled Pallas kernels on TPU, the pure-jnp "ref" engine elsewhere (the
+Pallas interpreter pays ~10 ms/op; the kernels themselves are validated in
+interpret mode in tests at small sizes).
 """
 from __future__ import annotations
 
@@ -24,10 +25,27 @@ from .map_indices import map_indices
 from .peel import peel_waves, peel_waves_batched
 
 
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
+def peel_engine(batched: bool = False) -> str:
+    """The kernel engine the device path runs here: ``"pallas"`` (compiled
+    kernels) or ``"ref"`` (pure jnp).
+
+    A lone decode and the encoder take the Pallas kernels on a TPU and the
+    ref engine elsewhere.  The batched decode always takes the ref engine:
+    its dense stages ``vmap`` over the unit axis and compile for any
+    backend.
+    """
+    if batched or jax.default_backend() != "tpu":
+        return "ref"
+    return "pallas"
+
+
+def _engine(kernel, interpret):
+    """Fill unset ``kernel``/``interpret`` from :func:`peel_engine`: Pallas
+    kernels run compiled where the ref engine is not the default, in
+    interpret mode otherwise."""
+    auto = peel_engine()
+    return (auto if kernel is None else kernel,
+            auto == "ref" if interpret is None else interpret)
 
 
 def _pad_items(items, block_n):
@@ -46,14 +64,12 @@ def encode_device(items, *, m: int, nbytes: int | None = None,
                   mapping: str | None = None):
     """items (n, L) uint32 -> (sums (m, L) u32, checks (m, 2) u32,
     counts (m,) i32).  Fixed-shape device encoder (chains truncated at
-    kmax(m); see DESIGN.md §3 — truncation probability < 1e-12).
+    kmax(m); truncation probability < 1e-12).
 
-    ``mapping``: "pallas" (map_indices kernel) or "ref" (pure-jnp chain).
-    Defaults to pallas on TPU; on CPU-interpret the chain kernel pays the
-    interpreter's ~10 ms/op tax over K·~15 sequential ops, so "ref" is the
-    default there (the kernel itself is still validated in tests at small
-    K).  Both produce identical indices."""
-    interpret = _auto_interpret(interpret)
+    ``mapping``: "pallas" (map_indices kernel) or "ref" (pure-jnp chain);
+    the default is :func:`peel_engine`'s.  Both produce identical
+    indices."""
+    mapping, interpret = _engine(mapping, interpret)
     items = jnp.asarray(items, dtype=jnp.uint32)
     n0 = items.shape[0]
     L = items.shape[1]
@@ -61,8 +77,6 @@ def encode_device(items, *, m: int, nbytes: int | None = None,
         nbytes = 4 * L
     if K is None:
         K = kmax(m)
-    if mapping is None:
-        mapping = "ref" if interpret else "pallas"
 
     def run(items_padded):
         # mask first, map second: pad rows are zero items whose mappings
@@ -113,13 +127,14 @@ def device_symbols_to_host(sums, checks, counts, nbytes: int):
 
 def host_symbols_to_device(sym):
     """CodedSymbols -> (sums (m, L) u32, checks (m, 2) u32, counts (m,) i32),
-    the device layout (uint64 checksums split into (hi, lo) word pairs).
-    Inverse of :func:`device_symbols_to_host` (tested round-trip)."""
+    the device layout (uint64 checksums split into (hi, lo) word pairs) in
+    host arrays; :func:`decode_device` pads and stages them.  Inverse of
+    :func:`device_symbols_to_host` (tested round-trip)."""
     checks = np.empty((sym.m, 2), np.uint32)
     checks[:, 0] = (sym.checks >> np.uint64(32)).astype(np.uint32)
     checks[:, 1] = (sym.checks & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    return (jnp.asarray(sym.sums, jnp.uint32), jnp.asarray(checks),
-            jnp.asarray(sym.counts.astype(np.int32)))
+    return (np.asarray(sym.sums, np.uint32), checks,
+            sym.counts.astype(np.int32))
 
 
 class DeviceDecodeResult(NamedTuple):
@@ -153,16 +168,15 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
     unapplied (items/residual cover only the completed waves) and the
     caller should fall back to the host decoder.
 
-    ``kernel``: "pallas" (purity/map/apply kernels) or "ref" (pure jnp).
-    Defaults to pallas on TPU, ref on CPU-interpret — same policy and
-    rationale as :func:`encode_device`.  On TPU the whole wave loop stages
-    into one jit program under ``jax.lax.while_loop``; chains are truncated
-    at ``kmax(m)`` like the device encoder (< 1e-12 probability).
+    ``kernel``: "pallas" (purity/map/apply kernels) or "ref" (pure jnp);
+    the default is :func:`peel_engine`'s.  Off the interpreter the whole
+    wave loop is one jit program per tile bucket under
+    ``jax.lax.while_loop``, with ``m`` traced, so a growing prefix
+    recompiles only when it crosses a tile boundary.  Chains are truncated
+    at ``kmax(mp)`` like the device encoder (< 1e-12 probability).
     """
-    interpret = _auto_interpret(interpret)
-    if kernel is None:
-        kernel = "ref" if interpret else "pallas"
-    sums = jnp.asarray(sums, jnp.uint32)
+    kernel, interpret = _engine(kernel, interpret)
+    sums = np.asarray(sums, np.uint32)
     m, L = sums.shape
     if nbytes is None:
         nbytes = 4 * L
@@ -178,22 +192,18 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
     if K is None:
         K = kmax(mp)
     D = mp if max_diff is None else max(int(max_diff), 1)
-    checks = jnp.asarray(checks, jnp.uint32)
-    counts = jnp.asarray(counts, jnp.int32)
-
-    def run(sums, checks, counts):
-        sums = jnp.pad(sums, ((0, mp - m), (0, 0)))
-        checks = jnp.pad(checks, ((0, mp - m), (0, 0)))
-        counts = jnp.pad(counts, (0, mp - m))[:, None]
-        return peel_waves(sums, checks, counts, m=m, nbytes=nbytes, key=key,
-                          max_diff=D, K=K, max_rounds=max_rounds,
-                          kernel=kernel, block_m=block_m, block_n=block_n,
-                          interpret=interpret,
-                          use_while_loop=not interpret)
-
-    if not interpret:
-        run = jax.jit(run)
-    state, success = run(sums, checks, counts)
+    # pad on the host: the padded shapes are the bucket's, whatever m is
+    sums_p = np.zeros((mp, L), np.uint32)
+    checks_p = np.zeros((mp, 2), np.uint32)
+    counts_p = np.zeros((mp, 1), np.int32)
+    sums_p[:m] = sums
+    checks_p[:m] = np.asarray(checks, np.uint32)
+    counts_p[:m, 0] = np.asarray(counts, np.int32)
+    state, success = peel_waves(
+        jnp.asarray(sums_p), jnp.asarray(checks_p), jnp.asarray(counts_p),
+        m=m, nbytes=nbytes, key=key, max_diff=D, K=K, max_rounds=max_rounds,
+        kernel=kernel, block_m=block_m, block_n=block_n, interpret=interpret,
+        use_while_loop=not interpret)
 
     n_rec = int(state.n_rec)
     items = np.asarray(state.rec_items)[:n_rec]
@@ -202,7 +212,8 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
         rchk[:, 1].astype(np.uint64)
     sides = np.asarray(state.rec_sides)[:n_rec].astype(np.int8)
     residual = device_symbols_to_host(
-        state.sums[:m], state.checks[:m], state.counts[:m, 0], nbytes)
+        np.asarray(state.sums)[:m], np.asarray(state.checks)[:m],
+        np.asarray(state.counts)[:m, 0], nbytes)
     return DeviceDecodeResult(items, hashes, sides, bool(success),
                               bool(state.overflow), int(state.rounds),
                               residual)
@@ -307,7 +318,9 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     Returns a :class:`PendingBatchedDecode`; ``wait()`` yields one
     :class:`DeviceDecodeResult` per unit, in input order.
     """
-    interpret = _auto_interpret(interpret)
+    # the batched stages are the ref engine's everywhere; ``interpret``
+    # only picks one staged program (compiled platforms) or a Python loop
+    _, interpret = _engine(None, interpret)
     from repro.core.symbols import CodedSymbols
     U = len(units)
     if U == 0:

@@ -20,8 +20,7 @@ restate each belief-propagation round as three dense, fixed-shape stages:
 
 The three stages iterate to a fixed point — ``jax.lax.while_loop`` when the
 whole program is jitted for TPU, a plain Python loop in eager/interpret
-mode on CPU (XLA-compiling the interpreter's op sequence takes minutes; see
-the note in ``tests/test_kernels.py``).  Every shape is static: symbols are
+mode on CPU.  Every shape is static: symbols are
 padded to ``block_m`` tiles, per-wave compaction holds ``cap`` rows, and the
 recovered-item buffer holds ``max_diff`` rows — a wave that would overflow
 it leaves the state untouched and raises the ``overflow`` flag so the
@@ -46,8 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import checksum_pair
-from .iblt_encode import _tree_xor
+from .common import checksum_pair, pallas_interpret
+from .iblt_encode import iblt_apply
 from .map_indices import map_indices
 from .ref import iblt_apply_ref, map_indices_ref
 
@@ -93,74 +92,9 @@ def purity_scan(sums, checks, counts, *, key, nbytes: int,
                   pl.BlockSpec((block_m, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, 1), jnp.int32),
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(sums, checks, counts)
     return out[:, 0]
-
-
-# ---------------------------------------------------------------------------
-# Stage 3: signed dense XOR removal (iblt_encode's tile, plus sides).
-# ---------------------------------------------------------------------------
-def _apply_kernel(items_ref, idx_ref, chk_ref, side_ref, sums_ref, checks_ref,
-                  counts_ref, *, block_m: int, m: int):
-    i = pl.program_id(0)   # symbol tile
-    j = pl.program_id(1)   # item block (innermost: accumulation)
-
-    @pl.when(j == 0)
-    def _init():
-        sums_ref[...] = jnp.zeros_like(sums_ref)
-        checks_ref[...] = jnp.zeros_like(checks_ref)
-        counts_ref[...] = jnp.zeros_like(counts_ref)
-
-    items = items_ref[...]          # (BN, L) uint32
-    chks = chk_ref[...]             # (BN, 2) uint32
-    idxs = idx_ref[...]             # (BN, K) int32
-    sides = side_ref[...]           # (BN, 1) int32
-    bn, L = items.shape
-    base = i * block_m
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bn, block_m), 1) + base
-    eq = (idxs[:, :, None] == lane[:, None, :]) & (idxs[:, :, None] < m)
-    mask = jnp.any(eq, axis=1)                         # (BN, BM)
-    mask_u = mask.astype(jnp.uint32)
-    counts_ref[...] = counts_ref[...] + \
-        jnp.sum(mask.astype(jnp.int32) * sides, axis=0)[:, None]
-    sums_ref[...] = sums_ref[...] ^ \
-        _tree_xor(mask_u[:, :, None] * items[:, None, :])
-    checks_ref[...] = checks_ref[...] ^ \
-        _tree_xor(mask_u[:, :, None] * chks[:, None, :])
-
-
-def iblt_apply(items, idxs, chks, sides, *, m: int, block_m: int = 256,
-               block_n: int = 256, interpret: bool = True):
-    """Signed coded-symbol delta of ``items`` over their mapped chains.
-
-    items (n, L) uint32, idxs (n, K) int32 (pad = m kills a row),
-    chks (n, 2) uint32, sides (n,) int32 -> (sums (m', L) uint32,
-    checks (m', 2) uint32, counts (m', 1) int32), m' = m rounded up to
-    block_m.  The caller XORs the sums/checks delta into its symbol state
-    and *subtracts* the counts delta (removal = encode with negated sign).
-    """
-    n, L = items.shape
-    K = idxs.shape[1]
-    assert n % block_n == 0
-    mp = ((m + block_m - 1) // block_m) * block_m
-    grid = (mp // block_m, n // block_n)
-    kernel = functools.partial(_apply_kernel, block_m=block_m, m=m)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_n, L), lambda i, j: (j, 0)),
-                  pl.BlockSpec((block_n, K), lambda i, j: (j, 0)),
-                  pl.BlockSpec((block_n, 2), lambda i, j: (j, 0)),
-                  pl.BlockSpec((block_n, 1), lambda i, j: (j, 0))],
-        out_specs=[pl.BlockSpec((block_m, L), lambda i, j: (i, 0)),
-                   pl.BlockSpec((block_m, 2), lambda i, j: (i, 0)),
-                   pl.BlockSpec((block_m, 1), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((mp, L), jnp.uint32),
-                   jax.ShapeDtypeStruct((mp, 2), jnp.uint32),
-                   jax.ShapeDtypeStruct((mp, 1), jnp.int32)],
-        interpret=interpret,
-    )(items, idxs, chks, sides.astype(jnp.int32)[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -257,22 +191,21 @@ def _wave(state: PeelState, m, *, mp: int, cap: int, max_diff: int,
         lambda a, b: jnp.where(overflow, a, b), frozen, out)
 
 
-def _engines(*, nbytes: int, key, K: int, kernel: str, m: int | None,
-             mp: int, block_m: int, block_n: int, interpret: bool):
+def _engines(*, nbytes: int, key, K: int, kernel: str, mp: int, block_m: int,
+             block_n: int, interpret: bool):
     """Build (purity_fn, map_fn(items, m), apply_fn(items, idxs, chks,
-    sides, m)) for one engine.  The ref engine treats ``m`` as data (so one
-    jitted program serves every prefix length within a tile bucket); the
-    Pallas engine bakes the static ``m`` into its kernels."""
+    sides, m)) for one engine.  Both engines take ``m`` as data, so one
+    compiled program serves every prefix length within a tile bucket."""
     if kernel == "pallas":
         purity_fn = functools.partial(purity_scan, key=key, nbytes=nbytes,
                                       block_m=block_m, interpret=interpret)
 
-        def map_fn(items, _m):
+        def map_fn(items, m):
             return map_indices(items, K=K, m=m, nbytes=nbytes, key=key,
                                block_n=block_n, interpret=interpret)
 
-        def apply_fn(items, idxs, chks, sides, _m):
-            return iblt_apply(items, idxs, chks, sides, m=m,
+        def apply_fn(items, idxs, chks, sides, m):
+            return iblt_apply(items, idxs, chks, sides, m=m, m_out=mp,
                               block_m=block_m, block_n=block_n,
                               interpret=interpret)
     else:
@@ -287,6 +220,37 @@ def _engines(*, nbytes: int, key, K: int, kernel: str, m: int | None,
     return purity_fn, map_fn, apply_fn
 
 
+def _cap(D: int, mp: int, block_n: int) -> int:
+    """Per-wave compaction slots: 2·max_diff, at most mp, whole blocks."""
+    cap = min(2 * max(D, 1), mp)
+    return max(-(-cap // block_n) * block_n, block_n)
+
+
+def _init_state(sums, checks, counts, D: int) -> PeelState:
+    """Fresh wave state over padded symbols; a leading unit axis, if the
+    symbols have one, carries through every leaf."""
+    lead, L = sums.shape[:-2], sums.shape[-1]
+    return PeelState(
+        sums=jnp.asarray(sums, jnp.uint32),
+        checks=jnp.asarray(checks, jnp.uint32),
+        counts=jnp.asarray(counts, jnp.int32),
+        rec_items=jnp.zeros(lead + (D, L), jnp.uint32),
+        rec_checks=jnp.zeros(lead + (D, 2), jnp.uint32),
+        rec_sides=jnp.zeros(lead + (D,), jnp.int32),
+        n_rec=jnp.zeros(lead, jnp.int32),
+        changed=jnp.ones(lead, bool),
+        overflow=jnp.zeros(lead, bool),
+        rounds=jnp.zeros(lead, jnp.int32),
+    )
+
+
+def _success(state: PeelState):
+    """All symbols empty (the ρ(0)=1 termination signal) and no overflow."""
+    empty = (state.counts[..., 0] == 0) & (state.checks[..., 0] == 0) & \
+        (state.checks[..., 1] == 0) & jnp.all(state.sums == 0, axis=-1)
+    return jnp.all(empty, axis=-1) & ~state.overflow
+
+
 @functools.lru_cache(maxsize=128)
 def _ref_stages_jit(mp: int, cap: int, max_diff: int, K: int, L: int,
                     nbytes: int, key):
@@ -296,13 +260,37 @@ def _ref_stages_jit(mp: int, cap: int, max_diff: int, K: int, L: int,
     re-uses one compiled program until it crosses a tile boundary.
     """
     purity_fn, map_fn, apply_fn = _engines(
-        nbytes=nbytes, key=key, K=K, kernel="ref", m=None, mp=mp,
+        nbytes=nbytes, key=key, K=K, kernel="ref", mp=mp,
         block_m=mp, block_n=cap, interpret=True)
     s1 = jax.jit(functools.partial(_stage1, mp=mp, cap=cap,
                                    max_diff=max_diff, purity_fn=purity_fn))
     s2 = jax.jit(functools.partial(_stage2, mp=mp, max_diff=max_diff,
                                    map_fn=map_fn, apply_fn=apply_fn))
     return s1, s2
+
+
+@functools.lru_cache(maxsize=64)
+def _peel_program(mp: int, D: int, K: int, nbytes: int, key,
+                  max_rounds: int, kernel: str, block_m: int, block_n: int,
+                  interpret: bool):
+    """The whole decode of one shape bucket as one jitted program: state
+    set-up, the wave loop under ``lax.while_loop``, and the success flag.
+    ``m`` is a traced argument, so the program compiles once per bucket."""
+    cap = _cap(D, mp, block_n)
+    purity_fn, map_fn, apply_fn = _engines(
+        nbytes=nbytes, key=key, K=K, kernel=kernel, mp=mp, block_m=block_m,
+        block_n=block_n, interpret=interpret)
+    body = functools.partial(_wave, mp=mp, cap=cap, max_diff=D,
+                             purity_fn=purity_fn, map_fn=map_fn,
+                             apply_fn=apply_fn)
+
+    def run(sums, checks, counts, m):
+        state = jax.lax.while_loop(
+            lambda s: s.changed & ~s.overflow & (s.rounds < max_rounds),
+            lambda s: body(s, m), _init_state(sums, checks, counts, D))
+        return state, _success(state)
+
+    return jax.jit(run)
 
 
 def peel_waves(sums, checks, counts, *, m: int, nbytes: int, key,
@@ -317,94 +305,93 @@ def peel_waves(sums, checks, counts, *, m: int, nbytes: int, key,
     ``success`` scalar (all symbols empty — the ρ(0)=1 termination signal
     holds: symbol 0 empties last).
 
-    ``use_while_loop=True`` runs the loop as ``jax.lax.while_loop`` so the
-    whole decode stages into one jit program (the TPU path).  Otherwise the
-    loop runs in Python: the ref engine's stages are jitted per shape
-    bucket (with ``m`` as data), and waves that recover nothing skip the
-    removal stage entirely — the common case while a stream decoder is
-    still below the decode threshold.
+    ``use_while_loop=True`` runs the cached per-bucket program of
+    :func:`_peel_program` (the TPU path: one dispatch, no recompile as
+    ``m`` grows within a bucket).  Otherwise the loop runs in Python: the
+    ref engine's stages are jitted per shape bucket (with ``m`` as data),
+    and waves that recover nothing skip the removal stage entirely — the
+    common case while a stream decoder is still below the decode threshold.
     """
     mp, L = sums.shape
     D = max_diff
-    cap = min(2 * max(D, 1), mp)
-    cap = max(((cap + block_n - 1) // block_n) * block_n, block_n)
     key = tuple(key)
-    state = PeelState(
-        sums=jnp.asarray(sums, jnp.uint32),
-        checks=jnp.asarray(checks, jnp.uint32),
-        counts=jnp.asarray(counts, jnp.int32),
-        rec_items=jnp.zeros((D, L), jnp.uint32),
-        rec_checks=jnp.zeros((D, 2), jnp.uint32),
-        rec_sides=jnp.zeros(D, jnp.int32),
-        n_rec=jnp.int32(0),
-        changed=jnp.array(True),
-        overflow=jnp.array(False),
-        rounds=jnp.int32(0),
-    )
-
     if use_while_loop:
-        purity_fn, map_fn, apply_fn = _engines(
-            nbytes=nbytes, key=key, K=K, kernel=kernel, m=m, mp=mp,
-            block_m=block_m, block_n=block_n, interpret=interpret)
-        body = functools.partial(_wave, mp=mp, cap=cap, max_diff=D,
-                                 purity_fn=purity_fn, map_fn=map_fn,
-                                 apply_fn=apply_fn)
-        state = jax.lax.while_loop(
-            lambda s: s.changed & ~s.overflow & (s.rounds < max_rounds),
-            lambda s: body(s, m), state)
-    else:
-        if kernel == "ref":
-            s1, s2 = _ref_stages_jit(mp, cap, D, K, L, nbytes, key)
-        else:
-            purity_fn, map_fn, apply_fn = _engines(
-                nbytes=nbytes, key=key, K=K, kernel=kernel, m=m, mp=mp,
-                block_m=block_m, block_n=block_n, interpret=interpret)
-            s1 = functools.partial(_stage1, mp=mp, cap=cap, max_diff=D,
-                                   purity_fn=purity_fn)
-            s2 = functools.partial(_stage2, mp=mp, max_diff=D,
-                                   map_fn=map_fn, apply_fn=apply_fn)
-        rounds = 0
-        while rounds < max_rounds:
-            p_items, p_chk, p_side, keep, n_new, overflow = s1(
-                state.sums, state.checks, state.counts, state.rec_checks,
-                state.n_rec, m)
-            rounds += 1
-            if bool(overflow) or int(n_new) == 0:
-                state = state._replace(changed=jnp.array(False),
-                                       overflow=jnp.asarray(overflow),
-                                       rounds=jnp.int32(rounds))
-                break
-            state = s2(state, p_items, p_chk, p_side, keep, m)
-            state = state._replace(changed=jnp.array(True),
-                                   rounds=jnp.int32(rounds))
+        program = _peel_program(mp, D, K, nbytes, key, max_rounds, kernel,
+                                block_m, block_n, interpret)
+        return program(sums, checks, counts, jnp.int32(m))
 
-    empty = (state.counts[:, 0] == 0) & (state.checks[:, 0] == 0) & \
-            (state.checks[:, 1] == 0) & jnp.all(state.sums == 0, axis=1)
-    success = jnp.all(empty) & ~state.overflow
-    return state, success
+    cap = _cap(D, mp, block_n)
+    state = _init_state(sums, checks, counts, D)
+    if kernel == "ref":
+        s1, s2 = _ref_stages_jit(mp, cap, D, K, L, nbytes, key)
+    else:
+        purity_fn, map_fn, apply_fn = _engines(
+            nbytes=nbytes, key=key, K=K, kernel=kernel, mp=mp,
+            block_m=block_m, block_n=block_n, interpret=interpret)
+        s1 = functools.partial(_stage1, mp=mp, cap=cap, max_diff=D,
+                               purity_fn=purity_fn)
+        s2 = functools.partial(_stage2, mp=mp, max_diff=D,
+                               map_fn=map_fn, apply_fn=apply_fn)
+    rounds = 0
+    while rounds < max_rounds:
+        p_items, p_chk, p_side, keep, n_new, overflow = s1(
+            state.sums, state.checks, state.counts, state.rec_checks,
+            state.n_rec, m)
+        rounds += 1
+        if bool(overflow) or int(n_new) == 0:
+            state = state._replace(changed=jnp.array(False),
+                                   overflow=jnp.asarray(overflow),
+                                   rounds=jnp.int32(rounds))
+            break
+        state = s2(state, p_items, p_chk, p_side, keep, m)
+        state = state._replace(changed=jnp.array(True),
+                               rounds=jnp.int32(rounds))
+    return state, _success(state)
 
 
 # ---------------------------------------------------------------------------
 # Batched wave loop: S independent shard decodes as ONE device program.
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=64)
-def _batched_wave_jit(S: int, mp: int, cap: int, max_diff: int, K: int,
-                      L: int, nbytes: int, key):
+def _batched_wave_jit(mp: int, cap: int, max_diff: int, K: int, nbytes: int,
+                      key):
     """One jitted, ``vmap``-ed peel wave over the unit axis.
 
-    Cached per static-shape bucket ``(S, mp, cap, max_diff, K, L)``; the
+    Cached per static-shape bucket ``(mp, cap, max_diff, K)``; the
     per-unit prefix lengths ``m`` enter as a traced ``(S,)`` vector, so a
     set of growing unit prefixes re-uses one compiled program until the
     *longest* unit crosses a tile boundary.  Always the ref engine: dense
     jnp stages vmap cleanly and compile for both CPU and TPU.
     """
+    return jax.jit(_batched_wave(mp, cap, max_diff, K, nbytes, key))
+
+
+def _batched_wave(mp, cap, max_diff, K, nbytes, key):
     purity_fn, map_fn, apply_fn = _engines(
-        nbytes=nbytes, key=key, K=K, kernel="ref", m=None, mp=mp,
+        nbytes=nbytes, key=key, K=K, kernel="ref", mp=mp,
         block_m=mp, block_n=cap, interpret=True)
     wave = functools.partial(_wave, mp=mp, cap=cap, max_diff=max_diff,
                              purity_fn=purity_fn, map_fn=map_fn,
                              apply_fn=apply_fn)
-    return jax.jit(jax.vmap(wave, in_axes=(0, 0)))
+    return jax.vmap(wave, in_axes=(0, 0))
+
+
+@functools.lru_cache(maxsize=64)
+def _batched_program(mp: int, cap: int, max_diff: int, K: int, nbytes: int,
+                     key, max_rounds: int):
+    """The whole batched decode of one bucket as one jitted program
+    (state set-up, ``lax.while_loop`` over the vmapped wave, per-unit
+    success); the ``(S,)`` prefix lengths are traced."""
+    wave = _batched_wave(mp, cap, max_diff, K, nbytes, key)
+
+    def run(sums, checks, counts, m):
+        state = jax.lax.while_loop(
+            lambda s: jnp.any(s.changed & ~s.overflow) &
+            jnp.all(s.rounds < max_rounds),
+            lambda s: wave(s, m), _init_state(sums, checks, counts, max_diff))
+        return state, _success(state)
+
+    return jax.jit(run)
 
 
 def peel_waves_batched(sums, checks, counts, *, m, nbytes: int, key,
@@ -434,43 +421,25 @@ def peel_waves_batched(sums, checks, counts, *, m, nbytes: int, key,
     the leading unit axis, and a ``(S,)`` bool of per-unit success (all
     of the unit's symbols emptied and no overflow).
 
-    ``use_while_loop=True`` stages the whole loop into the jit program via
-    ``jax.lax.while_loop`` (one device dispatch total — the TPU serving
+    ``use_while_loop=True`` runs the cached per-bucket program of
+    :func:`_batched_program` (one device dispatch total — the TPU serving
     path); the default Python loop issues one batched dispatch per wave,
     which is the right trade on CPU where each jitted wave is cheap but
     staging thousands of waves is not.
     """
-    S, mp, L = sums.shape
+    mp = sums.shape[1]
     D = max_diff
-    cap = min(2 * max(D, 1), mp)
-    cap = max(((cap + block_n - 1) // block_n) * block_n, block_n)
+    cap = _cap(D, mp, block_n)
     key = tuple(key)
-    state = PeelState(
-        sums=jnp.asarray(sums, jnp.uint32),
-        checks=jnp.asarray(checks, jnp.uint32),
-        counts=jnp.asarray(counts, jnp.int32),
-        rec_items=jnp.zeros((S, D, L), jnp.uint32),
-        rec_checks=jnp.zeros((S, D, 2), jnp.uint32),
-        rec_sides=jnp.zeros((S, D), jnp.int32),
-        n_rec=jnp.zeros(S, jnp.int32),
-        changed=jnp.ones(S, bool),
-        overflow=jnp.zeros(S, bool),
-        rounds=jnp.zeros(S, jnp.int32),
-    )
     m = jnp.asarray(m, jnp.int32)
-    wave = _batched_wave_jit(S, mp, cap, D, K, L, nbytes, key)
     if use_while_loop:
-        state = jax.lax.while_loop(
-            lambda s: jnp.any(s.changed & ~s.overflow) &
-            jnp.all(s.rounds < max_rounds),
-            lambda s: wave(s, m), state)
-    else:
-        while True:
-            state = wave(state, m)
-            if not bool(jnp.any(state.changed & ~state.overflow)) or \
-                    int(state.rounds.max()) >= max_rounds:
-                break
-    empty = (state.counts[..., 0] == 0) & (state.checks[..., 0] == 0) & \
-            (state.checks[..., 1] == 0) & jnp.all(state.sums == 0, axis=2)
-    success = jnp.all(empty, axis=1) & ~state.overflow
-    return state, success
+        program = _batched_program(mp, cap, D, K, nbytes, key, max_rounds)
+        return program(sums, checks, counts, m)
+    state = _init_state(sums, checks, counts, D)
+    wave = _batched_wave_jit(mp, cap, D, K, nbytes, key)
+    while True:
+        state = wave(state, m)
+        if not bool(jnp.any(state.changed & ~state.overflow)) or \
+                int(state.rounds.max()) >= max_rounds:
+            break
+    return state, _success(state)

@@ -14,13 +14,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 takes axis_types (and defaults changed); older jax has
-    # neither the kwarg nor jax.sharding.AxisType.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -25,28 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from .layers import _init
 
-def _resolve_shard_map():
-    """jax moved shard_map from jax.experimental to the top level and later
-    renamed check_rep -> check_vma; pick whichever this jax speaks."""
-    import inspect
-    if hasattr(jax, "shard_map"):
-        impl = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as impl
-    params = inspect.signature(impl).parameters
-    flag = "check_vma" if "check_vma" in params else "check_rep"
-    return functools.partial(impl, **{flag: False})
-
-
-_shard_map = _resolve_shard_map()
-
-
-def _axis_size(axis) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)  # constant-folds to a static int on old jax
-
-
 def init_moe(key, cfg, dtype, fsdp: bool):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -84,7 +62,7 @@ def _route(x2d, router, k):
 def _ep_ffn_local(x2d, router, wi, wg, wo, *, k, cf, axis):
     """Runs inside shard_map: x2d (T_loc, d); wi/wg/wo local expert slices
     (E_loc, d, f).  Experts are sharded over mesh axis `axis`."""
-    n_shards = _axis_size(axis)
+    n_shards = jax.lax.axis_size(axis)
     T, d = x2d.shape
     e_loc = wi.shape[0]
     E = e_loc * n_shards
@@ -135,7 +113,7 @@ def moe_ffn(x, p, cfg, mesh_axes):
     pspec_w = P("model", None, None)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, check_vma=False, mesh=mesh,
         in_specs=(pspec_x, pspec_r, pspec_w, pspec_w, pspec_w),
         out_specs=(pspec_x, P()))
     def run(xb, router, wi, wg, wo):

@@ -99,6 +99,11 @@ class PeerState:
         self.sharded = sharded
         self.bytes_received = 0
         self.grow_steps = 0
+        # decode accounting: where each unit decode ran, and how many
+        # device decodes overflowed max_diff (each then re-ran on the host)
+        self.device_decodes = 0
+        self.host_decodes = 0
+        self.overflows = 0
         # the ENGINE owns decode dispatch (plan/execute), so the decoders
         # never self-dispatch here; their backend/max_diff are still kept
         # in sync so a decoder used directly (decoder.receive) behaves
@@ -308,6 +313,8 @@ class PendingRound:
         for units, pending in self._dispatches:
             for du, res in zip(units, pending.wait()):
                 if res.overflow:
+                    du.peer.overflows += 1
+                    du.peer.host_decodes += 1
                     du.unit.pinned_host = True
                     du.unit.decoder.peel_window(du.old, du.m)
                 else:
@@ -340,10 +347,13 @@ def execute_round(units: list[DecodeUnit], block_m: int = 256,
     from repro.kernels import ops
     plan = build_plan(units, block_m)
     for du in plan.host:
+        du.peer.host_decodes += 1
         du.unit.decoder.peel_window(du.old, du.m)
         du.unit.decoder.mark_decoded(at=du.m)
     dispatches = []
     for (mp, L, nbytes, key, D), us in plan.buckets.items():
+        for du in us:
+            du.peer.device_decodes += 1
         works = [du.unit.decoder.work for du in us]
         if pipeline:
             pending = ops.decode_device_batched_start(

@@ -27,6 +27,10 @@ class ReportBase:
     symbols_received: int     # including pacing overshoot
     bytes_received: int       # wire-mode traffic (0 for in-process sessions)
     remote_items: int | None  # |remote set|, learned from frame headers
+    grow_steps: int           # windows (merged, if sharded) consumed
+    device_decodes: int       # unit decodes dispatched to the device
+    host_decodes: int         # unit decodes on the host, fallbacks included
+    overflows: int            # device decodes past max_diff (host re-ran)
 
     def only_remote_bytes(self) -> np.ndarray:
         """(r, ℓ) uint8 — remote-exclusive items as raw bytes."""
@@ -67,7 +71,6 @@ class ShardedReport(ReportBase):
     keeps the per-shard breakdown.
     """
     shards: list[ShardReport]  # per-shard breakdown
-    grow_steps: int            # merged windows consumed (decode rounds run)
 
 
 def build_session_report(peer) -> SessionReport:
@@ -85,7 +88,14 @@ def build_session_report(peer) -> SessionReport:
         symbols_used=unit.decoder.decoded_at or unit.decoder.symbols_received,
         symbols_received=unit.decoder.symbols_received,
         bytes_received=peer.bytes_received,
-        remote_items=unit.remote_items)
+        remote_items=unit.remote_items,
+        **_decode_counts(peer))
+
+
+def _decode_counts(peer) -> dict:
+    return dict(grow_steps=peer.grow_steps,
+                device_decodes=peer.device_decodes,
+                host_decodes=peer.host_decodes, overflows=peer.overflows)
 
 
 def build_sharded_report(peer) -> ShardedReport:
@@ -109,4 +119,4 @@ def build_sharded_report(peer) -> ShardedReport:
         bytes_received=peer.bytes_received,
         remote_items=None if any(c is None for c in counts) else sum(counts),
         shards=per_shard,
-        grow_steps=peer.grow_steps)
+        **_decode_counts(peer))

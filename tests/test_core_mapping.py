@@ -1,12 +1,16 @@
 """Mapping + hashing invariants (paper §4.1–4.2)."""
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import siphash24, siphash24_pair, rho, kmax
-from repro.core.mapping import (indices_matrix_j, indices_matrix_np,
-                                item_indices_np, map_seeds, map_seeds_pair)
+from repro.core.hashing import DEFAULT_KEY
+from repro.core.mapping import (_pow2, _recip_rn, _sqrt_rn, indices_matrix_j,
+                                indices_matrix_np, item_indices_np, map_seeds,
+                                map_seeds_pair)
+from repro.kernels.ref import map_indices_ref
 
 RNG = np.random.default_rng(1234)
 
@@ -77,6 +81,34 @@ def test_host_device_chains_identical():
     hi, lo = map_seeds_pair(jnp.asarray(w), (11, 13), 32)
     Md = np.asarray(indices_matrix_j(hi, lo, m, K=Mh.shape[1]))
     np.testing.assert_array_equal(Mh, Md.astype(np.int64))
+
+
+def test_device_inverse_sqrt_is_float32_exact():
+    """The device's t = 1/sqrt(1 - r) equals numpy's float32 for all 2**24
+    values of r, compiled (XLA would otherwise fuse 1/sqrt into rsqrt)."""
+    rbits = np.arange(1 << 24, dtype=np.uint32)
+    r = rbits.astype(np.float32) * np.float32(2.0 ** -24)
+    want = np.float32(1.0) / np.sqrt(np.float32(1.0) - r)
+
+    @jax.jit
+    def t(rbits):
+        Q, fb = _sqrt_rn((1 << 24) - rbits.astype(jnp.int32))
+        return _recip_rn(Q).astype(jnp.float32) * _pow2(fb - 35)
+
+    np.testing.assert_array_equal(np.asarray(t(jnp.asarray(rbits))), want)
+
+
+def test_jitted_device_chains_identical():
+    """The compiled device chains equal the host's at a real prefix length
+    and item width (with a float32 1/sqrt, one of these 4096 rows moved by
+    one index)."""
+    n, m = 4096, 2048
+    w = np.random.default_rng(0).integers(0, 2**32, size=(n, 23),
+                                          dtype=np.uint32)
+    Mh = indices_matrix_np(map_seeds(w, DEFAULT_KEY, 92), m)
+    Md, _ = jax.jit(lambda w: map_indices_ref(
+        w, K=Mh.shape[1], m=m, nbytes=92, key=DEFAULT_KEY))(jnp.asarray(w))
+    np.testing.assert_array_equal(Mh, np.asarray(Md).astype(np.int64))
 
 
 def test_kmax_bounds_chain_length():

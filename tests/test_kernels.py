@@ -26,15 +26,13 @@ def rand_items(n, L):
 
 
 # -------------------------------------------------------------- mapping --
-# NOTE on coverage: XLA-CPU takes minutes to compile the interpret-mode
-# wrapper for this kernel once the unrolled SipHash/jump chain crosses
-# ~2 message blocks or ~2 grid steps (LLVM chokes on the long sequential
-# u32 dependency chain; measured 3m26s for a single extra block — see
-# DESIGN.md §3).  Interpret tests therefore pin L=2 (8-byte items — the
-# paper's §7.2 benchmark size) and a single grid step; wider L / multi-block
-# coverage runs through the identical-math ref path (`map_indices_ref`,
-# tested against the host chains at all L in test_core_mapping) and through
-# the `slow` marker below.
+# NOTE on coverage: the kernels run in the TPU interpreter here.  The
+# generic interpreter took XLA-CPU minutes to compile once the SipHash
+# kernel spanned more than one grid step, so these tests pin L=2 (8-byte
+# items — the paper's §7.2 benchmark size) and a single grid step; wider L /
+# multi-block coverage runs through the identical-math ref path
+# (`map_indices_ref`, tested against the host chains in test_core_mapping),
+# the `slow` cases below, and the compiles in test_tpu_compile.
 @pytest.mark.parametrize("K", [4, 6, 8])
 def test_map_indices_kernel_vs_ref(K):
     L, block_n = 2, 64

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import ARCHS, get_config, smoke_config
+from repro.launch.mesh import make_local_mesh
 from repro.models import build_model
 from repro.train.loop import init_train_state, make_opt_config, make_train_step
 
@@ -16,7 +17,7 @@ warnings.filterwarnings("ignore", category=DeprecationWarning)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_local_mesh()
 
 
 def make_batch(cfg, B=2, S=32, key=0):
