@@ -33,14 +33,7 @@ def timeit(fn, *args, repeat: int = 1, **kw):
     return best, out
 
 
-# name -> µs/call for every emit() since process start; benchmarks/run.py
-# snapshots this around each suite to build machine-readable artifacts
-# (BENCH_kernels.json) for perf-trajectory tracking.
-RESULTS: dict[str, float] = {}
-
-
 def emit(name: str, us_per_call: float, derived: str):
-    RESULTS[name] = us_per_call
     print(f"{name},{us_per_call:.2f},{derived}", flush=True)
 
 

@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.mapping import kmax
+from repro.trace import STAGE, UNSTAGE, WAIT, span
 
 from .iblt_encode import iblt_encode
 from .map_indices import map_indices
@@ -146,6 +147,19 @@ class DeviceDecodeResult(NamedTuple):
     overflow: bool        # max_diff exceeded — decode stopped mid-peel
     rounds: int           # peel waves executed
     residual: object      # CodedSymbols — symbols after all removals
+    transfer_bytes: int = 0   # host→device staged + device→host fetched
+
+
+def _stage(*arrays):
+    """Copy host arrays to the device; returns them and the bytes sent."""
+    return jax.device_put(arrays), sum(a.nbytes for a in arrays)
+
+
+def _fetch(tree):
+    """Copy a pytree of device arrays to the host (one blocking read of
+    every leaf); returns it and the bytes received."""
+    host = jax.device_get(tree)
+    return host, sum(np.asarray(a).nbytes for a in jax.tree.leaves(host))
 
 
 def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
@@ -192,31 +206,33 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
     if K is None:
         K = kmax(mp)
     D = mp if max_diff is None else max(int(max_diff), 1)
-    # pad on the host: the padded shapes are the bucket's, whatever m is
-    sums_p = np.zeros((mp, L), np.uint32)
-    checks_p = np.zeros((mp, 2), np.uint32)
-    counts_p = np.zeros((mp, 1), np.int32)
-    sums_p[:m] = sums
-    checks_p[:m] = np.asarray(checks, np.uint32)
-    counts_p[:m, 0] = np.asarray(counts, np.int32)
-    state, success = peel_waves(
-        jnp.asarray(sums_p), jnp.asarray(checks_p), jnp.asarray(counts_p),
-        m=m, nbytes=nbytes, key=key, max_diff=D, K=K, max_rounds=max_rounds,
-        kernel=kernel, block_m=block_m, block_n=block_n, interpret=interpret,
-        use_while_loop=not interpret)
-
-    n_rec = int(state.n_rec)
-    items = np.asarray(state.rec_items)[:n_rec]
-    rchk = np.asarray(state.rec_checks)[:n_rec]
-    hashes = (rchk[:, 0].astype(np.uint64) << np.uint64(32)) | \
-        rchk[:, 1].astype(np.uint64)
-    sides = np.asarray(state.rec_sides)[:n_rec].astype(np.int8)
-    residual = device_symbols_to_host(
-        np.asarray(state.sums)[:m], np.asarray(state.checks)[:m],
-        np.asarray(state.counts)[:m, 0], nbytes)
-    return DeviceDecodeResult(items, hashes, sides, bool(success),
-                              bool(state.overflow), int(state.rounds),
-                              residual)
+    with span(STAGE, units=1, mp=mp):
+        # pad on the host: the padded shapes are the bucket's, whatever m
+        sums_p = np.zeros((mp, L), np.uint32)
+        checks_p = np.zeros((mp, 2), np.uint32)
+        counts_p = np.zeros((mp, 1), np.int32)
+        sums_p[:m] = sums
+        checks_p[:m] = np.asarray(checks, np.uint32)
+        counts_p[:m, 0] = np.asarray(counts, np.int32)
+        staged, sent = _stage(sums_p, checks_p, counts_p)
+        out = peel_waves(
+            *staged, m=m, nbytes=nbytes, key=key, max_diff=D, K=K,
+            max_rounds=max_rounds, kernel=kernel, block_m=block_m,
+            block_n=block_n, interpret=interpret,
+            use_while_loop=not interpret)
+    with span(WAIT):
+        (state, success), got = _fetch(out)
+    with span(UNSTAGE):
+        n_rec = int(state.n_rec)
+        rchk = state.rec_checks[:n_rec]
+        hashes = (rchk[:, 0].astype(np.uint64) << np.uint64(32)) | \
+            rchk[:, 1].astype(np.uint64)
+        residual = device_symbols_to_host(
+            state.sums[:m], state.checks[:m], state.counts[:m, 0], nbytes)
+        return DeviceDecodeResult(
+            state.rec_items[:n_rec], hashes,
+            state.rec_sides[:n_rec].astype(np.int8), bool(success),
+            bool(state.overflow), int(state.rounds), residual, sent + got)
 
 
 class PendingBatchedDecode:
@@ -231,14 +247,16 @@ class PendingBatchedDecode:
     ``ready()`` is immediately True.
     """
 
-    __slots__ = ("_state", "_success", "_ms", "_nbytes", "_results")
+    __slots__ = ("_state", "_success", "_ms", "_nbytes", "_results",
+                 "_sent")
 
-    def __init__(self, state, success, ms, nbytes, results=None):
+    def __init__(self, state, success, ms, nbytes, results=None, sent=0):
         self._state = state
         self._success = success
         self._ms = ms
         self._nbytes = nbytes
         self._results = results
+        self._sent = sent
 
     def ready(self) -> bool:
         """Non-blocking: True once the device results can be read without
@@ -249,35 +267,33 @@ class PendingBatchedDecode:
         return bool(is_ready()) if callable(is_ready) else True
 
     def wait(self) -> list[DeviceDecodeResult]:
-        """Materialize (blocking) — one result per input unit, in order."""
+        """Materialize (blocking) — one result per input unit, in order.
+
+        The bucket's bytes, staged and fetched (padding units included),
+        are split evenly over its units' ``transfer_bytes``, so they sum
+        to the bucket's total."""
         if self._results is not None:
             return self._results
-        state, success, ms, nbytes = \
-            self._state, self._success, self._ms, self._nbytes
-        rec_items = np.asarray(state.rec_items)
-        rec_checks = np.asarray(state.rec_checks)
-        rec_sides = np.asarray(state.rec_sides)
-        n_recs = np.asarray(state.n_rec)
-        overflow = np.asarray(state.overflow)
-        rounds = np.asarray(state.rounds)
-        success = np.asarray(success)
-        r_sums = np.asarray(state.sums)
-        r_checks = np.asarray(state.checks)
-        r_counts = np.asarray(state.counts)
-
-        out = []
-        for s, m_s in enumerate(ms):
-            n_rec = int(n_recs[s])
-            rchk = rec_checks[s, :n_rec]
-            hashes = (rchk[:, 0].astype(np.uint64) << np.uint64(32)) | \
-                rchk[:, 1].astype(np.uint64)
-            residual = device_symbols_to_host(
-                r_sums[s, :m_s], r_checks[s, :m_s], r_counts[s, :m_s, 0],
-                nbytes)
-            out.append(DeviceDecodeResult(
-                rec_items[s, :n_rec].copy(), hashes,
-                rec_sides[s, :n_rec].astype(np.int8), bool(success[s]),
-                bool(overflow[s]), int(rounds[s]), residual))
+        ms, nbytes = self._ms, self._nbytes
+        with span(WAIT):
+            (state, success), got = _fetch((self._state, self._success))
+        with span(UNSTAGE):
+            total = self._sent + got
+            out = []
+            for s, m_s in enumerate(ms):
+                n_rec = int(state.n_rec[s])
+                rchk = state.rec_checks[s, :n_rec]
+                hashes = (rchk[:, 0].astype(np.uint64) <<
+                          np.uint64(32)) | rchk[:, 1].astype(np.uint64)
+                residual = device_symbols_to_host(
+                    state.sums[s, :m_s], state.checks[s, :m_s],
+                    state.counts[s, :m_s, 0], nbytes)
+                out.append(DeviceDecodeResult(
+                    state.rec_items[s, :n_rec].copy(), hashes,
+                    state.rec_sides[s, :n_rec].astype(np.int8),
+                    bool(success[s]), bool(state.overflow[s]),
+                    int(state.rounds[s]), residual,
+                    total // len(ms) + (s < total % len(ms))))
         self._results = out
         self._state = self._success = None   # free device references
         return out
@@ -344,24 +360,25 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
         K = kmax(mp)
     D = mp if max_diff is None else max(int(max_diff), 1)
 
-    sums = np.zeros((Up, mp, L), np.uint32)
-    checks = np.zeros((Up, mp, 2), np.uint32)
-    counts = np.zeros((Up, mp, 1), np.int32)
-    for s, sym in enumerate(units):
-        sums[s, : sym.m] = sym.sums
-        checks[s, : sym.m, 0] = (sym.checks >> np.uint64(32)).astype(np.uint32)
-        checks[s, : sym.m, 1] = (sym.checks &
-                                 np.uint64(0xFFFFFFFF)).astype(np.uint32)
-        counts[s, : sym.m, 0] = sym.counts.astype(np.int32)
-
-    state, success = peel_waves_batched(
-        jnp.asarray(sums), jnp.asarray(checks), jnp.asarray(counts),
-        m=np.asarray(ms + [0] * (Up - U), np.int32), nbytes=nbytes, key=key,
-        max_diff=D, K=K, max_rounds=max_rounds,
-        use_while_loop=not interpret)
+    with span(STAGE, units=U, mp=mp):
+        sums = np.zeros((Up, mp, L), np.uint32)
+        checks = np.zeros((Up, mp, 2), np.uint32)
+        counts = np.zeros((Up, mp, 1), np.int32)
+        for s, sym in enumerate(units):
+            sums[s, : sym.m] = sym.sums
+            checks[s, : sym.m, 0] = \
+                (sym.checks >> np.uint64(32)).astype(np.uint32)
+            checks[s, : sym.m, 1] = (sym.checks &
+                                     np.uint64(0xFFFFFFFF)).astype(np.uint32)
+            counts[s, : sym.m, 0] = sym.counts.astype(np.int32)
+        (sums, checks, counts, m), sent = _stage(
+            sums, checks, counts, np.asarray(ms + [0] * (Up - U), np.int32))
+        state, success = peel_waves_batched(
+            sums, checks, counts, m=m, nbytes=nbytes, key=key, max_diff=D,
+            K=K, max_rounds=max_rounds, use_while_loop=not interpret)
     # wait() materializes per entry of ms (length U): dummy pad units past
     # U are simply never read back
-    return PendingBatchedDecode(state, success, ms, nbytes)
+    return PendingBatchedDecode(state, success, ms, nbytes, sent=sent)
 
 
 def decode_device_batched(units, *, nbytes: int, key=DEFAULT_KEY,

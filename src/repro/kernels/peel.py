@@ -284,13 +284,14 @@ def _peel_program(mp: int, D: int, K: int, nbytes: int, key,
                              purity_fn=purity_fn, map_fn=map_fn,
                              apply_fn=apply_fn)
 
-    def run(sums, checks, counts, m):
+    # the function's name is the program's name in a profiler trace
+    def peel_lone(sums, checks, counts, m):
         state = jax.lax.while_loop(
             lambda s: s.changed & ~s.overflow & (s.rounds < max_rounds),
             lambda s: body(s, m), _init_state(sums, checks, counts, D))
         return state, _success(state)
 
-    return jax.jit(run)
+    return jax.jit(peel_lone)
 
 
 def peel_waves(sums, checks, counts, *, m: int, nbytes: int, key,
@@ -384,14 +385,15 @@ def _batched_program(mp: int, cap: int, max_diff: int, K: int, nbytes: int,
     success); the ``(S,)`` prefix lengths are traced."""
     wave = _batched_wave(mp, cap, max_diff, K, nbytes, key)
 
-    def run(sums, checks, counts, m):
+    # the function's name is the program's name in a profiler trace
+    def peel_batched(sums, checks, counts, m):
         state = jax.lax.while_loop(
             lambda s: jnp.any(s.changed & ~s.overflow) &
             jnp.all(s.rounds < max_rounds),
             lambda s: wave(s, m), _init_state(sums, checks, counts, max_diff))
         return state, _success(state)
 
-    return jax.jit(run)
+    return jax.jit(peel_batched)
 
 
 def peel_waves_batched(sums, checks, counts, *, m, nbytes: int, key,

@@ -54,6 +54,8 @@ from typing import NamedTuple
 from repro.core.decoder import resolve_backend
 from repro.core.stream import StreamDecoder
 from repro.core.wire import decode_frames, decode_shard_frames
+from repro.trace import (ABSORB, HOST_PEEL, MERGE, PLAN, REPORT, SERVE, TICK,
+                         WIRE_DECODE, span)
 
 
 class ProtocolError(RuntimeError):
@@ -85,7 +87,9 @@ class PeerState:
     S=1 special case), the pacing policy, the backend/``max_diff`` decode
     configuration, and the wire accounting.  Wrappers keep a ``PeerState``
     as their single source of truth; a :class:`ReconcileEngine` drives any
-    number of them through one shared plan/execute loop.
+    number of them through one shared plan/execute loop.  ``index`` is the
+    peer's registration index on its engine, the ``peer`` argument of its
+    trace spans (:mod:`repro.trace`).
     """
 
     def __init__(self, *, nbytes: int, key, locals_, pacing, max_m: int,
@@ -97,6 +101,7 @@ class PeerState:
         self.backend = resolve_backend(backend)
         self.max_diff = max_diff
         self.sharded = sharded
+        self.index = 0
         self.bytes_received = 0
         self.grow_steps = 0
         # decode accounting: where each unit decode ran, and how many
@@ -104,6 +109,10 @@ class PeerState:
         self.device_decodes = 0
         self.host_decodes = 0
         self.overflows = 0
+        # device work of those decodes: peel waves run, and host<->device
+        # bytes staged and fetched (a batched bucket's split over its units)
+        self.device_waves = 0
+        self.transfer_bytes = 0
         # the ENGINE owns decode dispatch (plan/execute), so the decoders
         # never self-dispatch here; their backend/max_diff are still kept
         # in sync so a decoder used directly (decoder.receive) behaves
@@ -220,24 +229,26 @@ def absorb_round(peer: PeerState, windows) -> list[DecodeUnit]:
     accepted = validate_round(peer, windows)
     if not accepted:
         return []
-    spans: dict[int, DecodeUnit] = {}
-    for unit, sym in accepted:
-        old, m = unit.decoder.absorb(sym)
-        prev = spans.get(unit.shard)
-        spans[unit.shard] = DecodeUnit(peer, unit,
-                                       prev.old if prev else old, m)
-    peer.grow_steps += 1
-    out = []
-    for du in spans.values():
-        if du.unit.decoder.mark_decoded(at=du.m):
-            continue                          # settled on absorb alone
-        out.append(du)
+    touched: dict[int, DecodeUnit] = {}
+    with span(ABSORB, peer=peer.index):
+        for unit, sym in accepted:
+            old, m = unit.decoder.absorb(sym)
+            prev = touched.get(unit.shard)
+            touched[unit.shard] = DecodeUnit(peer, unit,
+                                             prev.old if prev else old, m)
+        peer.grow_steps += 1
+        out = []
+        for du in touched.values():
+            if du.unit.decoder.mark_decoded(at=du.m):
+                continue                      # settled on absorb alone
+            out.append(du)
     return out
 
 
 def ingest_frames(peer: PeerState, data: bytes) -> list[DecodeUnit]:
     """Absorb one self-describing wire frame (plain, single-unit peers)."""
-    sym, n_items, start = decode_frames(data)
+    with span(WIRE_DECODE, peer=peer.index):
+        sym, n_items, start = decode_frames(data)
     peer.bytes_received += len(data)
     peer.units[0].remote_items = n_items
     return absorb_round(peer, [(0, sym, start)])
@@ -245,7 +256,8 @@ def ingest_frames(peer: PeerState, data: bytes) -> list[DecodeUnit]:
 
 def ingest_payload(peer: PeerState, data: bytes) -> list[DecodeUnit]:
     """Absorb one merged shard payload (sharded peers)."""
-    n_shards, frames = decode_shard_frames(data)
+    with span(WIRE_DECODE, peer=peer.index):
+        n_shards, frames = decode_shard_frames(data)
     if n_shards != peer.n_units:
         raise ProtocolError(f"partition mismatch: payload has {n_shards} "
                             f"shards, session {peer.n_units}")
@@ -282,14 +294,17 @@ class DecodePlan:
 def build_plan(units: list[DecodeUnit], block_m: int = 256) -> DecodePlan:
     """Split pending units into host work and per-shape device buckets."""
     host, buckets = [], {}
-    for du in units:
-        if du.peer.backend != "device" or du.unit.pinned_host:
-            host.append(du)
-            continue
-        mp = ((du.m + block_m - 1) // block_m) * block_m
-        D = mp if du.peer.max_diff is None else max(int(du.peer.max_diff), 1)
-        key = (mp, du.unit.decoder.work.L, du.peer.nbytes, du.peer.key, D)
-        buckets.setdefault(key, []).append(du)
+    with span(PLAN):
+        for du in units:
+            if du.peer.backend != "device" or du.unit.pinned_host:
+                host.append(du)
+                continue
+            mp = ((du.m + block_m - 1) // block_m) * block_m
+            D = mp if du.peer.max_diff is None else \
+                max(int(du.peer.max_diff), 1)
+            key = (mp, du.unit.decoder.work.L, du.peer.nbytes, du.peer.key,
+                   D)
+            buckets.setdefault(key, []).append(du)
     return DecodePlan(host, buckets)
 
 
@@ -312,14 +327,24 @@ class PendingRound:
     def finish(self) -> None:
         for units, pending in self._dispatches:
             for du, res in zip(units, pending.wait()):
+                du.peer.device_waves += res.rounds
+                du.peer.transfer_bytes += res.transfer_bytes
                 if res.overflow:
                     du.peer.overflows += 1
-                    du.peer.host_decodes += 1
                     du.unit.pinned_host = True
-                    du.unit.decoder.peel_window(du.old, du.m)
-                else:
+                    _host_peel(du)
+                    continue
+                with span(MERGE, peer=du.peer.index):
                     du.unit.decoder.merge_device_result(res)
-                du.unit.decoder.mark_decoded(at=du.m)
+                    du.unit.decoder.mark_decoded(at=du.m)
+
+
+def _host_peel(du: DecodeUnit) -> None:
+    """Peel one unit's new rows on the exact host engine."""
+    du.peer.host_decodes += 1
+    with span(HOST_PEEL, peer=du.peer.index):
+        du.unit.decoder.peel_window(du.old, du.m)
+        du.unit.decoder.mark_decoded(at=du.m)
 
 
 def _next_pow2(n: int) -> int:
@@ -347,9 +372,7 @@ def execute_round(units: list[DecodeUnit], block_m: int = 256,
     from repro.kernels import ops
     plan = build_plan(units, block_m)
     for du in plan.host:
-        du.peer.host_decodes += 1
-        du.unit.decoder.peel_window(du.old, du.m)
-        du.unit.decoder.mark_decoded(at=du.m)
+        _host_peel(du)
     dispatches = []
     for (mp, L, nbytes, key, D), us in plan.buckets.items():
         for du in us:
@@ -446,25 +469,28 @@ class ReconcileEngine:
         elif n_shards is not None:
             raise ProtocolError("plain Session registered against a "
                                 "ShardedStream; use ShardedSession")
+        peer.index = len(self._peers)
         self._peers.append(_Registered(stream, session, peer, wire))
-        return len(self._peers) - 1
+        return peer.index
 
     # -- ingest (request + fetch + absorb, no decode) -----------------------
     def _gather_one(self, entry: _Registered,
                     strict: bool = True) -> list[DecodeUnit]:
-        reqs = entry.peer.requests(strict=strict)
+        peer, stream = entry.peer, entry.stream
+        reqs = peer.requests(strict=strict)
         if not reqs:
             return []
-        if entry.peer.sharded:
-            if entry.wire:
-                return ingest_payload(entry.peer, entry.stream.payload(reqs))
-            windows = [(s, entry.stream.window(s, lo, hi), lo)
-                       for s, lo, hi in reqs]
-            return absorb_round(entry.peer, windows)
-        ((_, lo, hi),) = reqs
-        if entry.wire:
-            return ingest_frames(entry.peer, entry.stream.frames(lo, hi))
-        return absorb_round(entry.peer, [(0, entry.stream.window(lo, hi), lo)])
+        with span(SERVE, peer=peer.index):
+            if peer.sharded:
+                got = stream.payload(reqs) if entry.wire else \
+                    [(s, stream.window(s, lo, hi), lo) for s, lo, hi in reqs]
+            else:
+                ((_, lo, hi),) = reqs
+                got = stream.frames(lo, hi) if entry.wire else \
+                    [(0, stream.window(lo, hi), lo)]
+        if not entry.wire:
+            return absorb_round(peer, got)
+        return (ingest_payload if peer.sharded else ingest_frames)(peer, got)
 
     def _gather(self, strict: bool = True) -> list[DecodeUnit]:
         units = []
@@ -478,11 +504,13 @@ class ReconcileEngine:
         """One synchronous plan/execute round over all live peers.
         Returns True while any peer still has work (event-driven callers
         loop on it; :meth:`run` adds the double-buffered fast path)."""
-        units = self._gather()
-        if not units:
-            return any(not e.peer.decoded for e in self._peers)
-        self.ticks += 1
-        self.dispatches += execute_round(units, self.block_m).n_dispatches
+        with span(TICK, tick=self.ticks + 1) as s:
+            units = self._gather()
+            if units:
+                self.ticks += 1
+                n = execute_round(units, self.block_m).n_dispatches
+                self.dispatches += n
+                s.set_metadata(units=len(units), buckets=n)
         return any(not e.peer.decoded for e in self._peers)
 
     def run(self) -> list:
@@ -495,47 +523,55 @@ class ReconcileEngine:
         staged = self._gather()
         while staged:
             self.ticks += 1
-            round_ = execute_round(staged, self.block_m, pipeline=True)
-            self.dispatches += round_.n_dispatches
-            # device busy → absorb the next tick's frames now.  Speculative:
-            # decodes in flight count as "not decoded", and a unit already
-            # at max_m defers its non-convergence verdict.
-            staged = self._gather(strict=False)
-            round_.finish()
-            # units that deferred (skipped by the speculative gather, still
-            # undecoded after their results landed) get an authoritative
-            # verdict now — this is where a genuinely diverging
-            # reconciliation raises, at most one tick later than serial.
-            speculated = {id(du.unit) for du in staged}
-            for entry in self._peers:
-                peer = entry.peer
-                if peer.decoded:
-                    continue
-                pending = [u for u in peer.units if not u.decoder.decoded]
-                unstaged = [u for u in pending
-                            if id(u) not in speculated]
-                for u in unstaged:
-                    if u.decoder.symbols_received >= peer.max_m:
-                        what = f"shard {u.shard}" if peer.sharded else \
-                            "reconciliation"
-                        raise RuntimeError(
-                            f"{what} did not converge within "
-                            f"{peer.max_m} symbols")
-                if unstaged:
-                    # defensive: an undecoded unit below max_m is always
-                    # staged by the speculative gather today — regather
-                    # authoritatively rather than exit with it stalled
-                    staged += self._gather_one(entry, strict=True)
-            # drop speculative units whose peer terminated meanwhile — the
-            # absorbed window stays as accounted pacing overshoot.
-            staged = [du for du in staged if not du.unit.decoder.decoded]
+            with span(TICK, tick=self.ticks, units=len(staged)) as s:
+                round_ = execute_round(staged, self.block_m, pipeline=True)
+                self.dispatches += round_.n_dispatches
+                s.set_metadata(buckets=round_.n_dispatches)
+                # device busy → absorb the next tick's frames now.
+                # Speculative: decodes in flight count as "not decoded",
+                # and a unit already at max_m defers its non-convergence
+                # verdict.
+                staged = self._gather(strict=False)
+                round_.finish()
+                # units that deferred (skipped by the speculative gather,
+                # still undecoded after their results landed) get an
+                # authoritative verdict now — this is where a genuinely
+                # diverging reconciliation raises, at most one tick later
+                # than serial.
+                speculated = {id(du.unit) for du in staged}
+                for entry in self._peers:
+                    peer = entry.peer
+                    if peer.decoded:
+                        continue
+                    pending = [u for u in peer.units
+                               if not u.decoder.decoded]
+                    unstaged = [u for u in pending
+                                if id(u) not in speculated]
+                    for u in unstaged:
+                        if u.decoder.symbols_received >= peer.max_m:
+                            what = f"shard {u.shard}" if peer.sharded else \
+                                "reconciliation"
+                            raise RuntimeError(
+                                f"{what} did not converge within "
+                                f"{peer.max_m} symbols")
+                    if unstaged:
+                        # defensive: an undecoded unit below max_m is
+                        # always staged by the speculative gather today —
+                        # regather authoritatively rather than exit with
+                        # it stalled
+                        staged += self._gather_one(entry, strict=True)
+                # drop speculative units whose peer terminated meanwhile —
+                # the absorbed window stays as accounted pacing overshoot.
+                staged = [du for du in staged
+                          if not du.unit.decoder.decoded]
         return self.reports()
 
     # -- outcome ------------------------------------------------------------
     def reports(self) -> list:
         """Current reports for every registered peer, in registration
         order (valid mid-run: undecoded peers report partial recovery)."""
-        return [entry.session.report() for entry in self._peers]
+        with span(REPORT):
+            return [entry.session.report() for entry in self._peers]
 
 
 def serve(pairs, *, wire: bool = True, backend: str | None = None,

@@ -31,6 +31,10 @@ class ReportBase:
     device_decodes: int       # unit decodes dispatched to the device
     host_decodes: int         # unit decodes on the host, fallbacks included
     overflows: int            # device decodes past max_diff (host re-ran)
+    # peel waves the device decodes ran, and the host<->device bytes they
+    # staged and fetched (0 where a report was built without them)
+    device_waves: int = dataclasses.field(default=0, kw_only=True)
+    transfer_bytes: int = dataclasses.field(default=0, kw_only=True)
 
     def only_remote_bytes(self) -> np.ndarray:
         """(r, ℓ) uint8 — remote-exclusive items as raw bytes."""
@@ -95,7 +99,9 @@ def build_session_report(peer) -> SessionReport:
 def _decode_counts(peer) -> dict:
     return dict(grow_steps=peer.grow_steps,
                 device_decodes=peer.device_decodes,
-                host_decodes=peer.host_decodes, overflows=peer.overflows)
+                host_decodes=peer.host_decodes, overflows=peer.overflows,
+                device_waves=peer.device_waves,
+                transfer_bytes=peer.transfer_bytes)
 
 
 def build_sharded_report(peer) -> ShardedReport:

@@ -43,6 +43,7 @@ import numpy as np
 from repro.core.hashing import DEFAULT_KEY, bytes_to_words
 from repro.core.mapping import map_seeds
 from repro.core.wire import encode_shard_frames
+from repro.trace import WIRE_ENCODE, span
 
 from .engine import (PeerState, ProtocolError, execute_round, ingest_payload,
                      offer_round)
@@ -173,7 +174,8 @@ class ShardedStream:
         shard-id'd extension headers — settled shards simply don't appear.
         """
         frames = [(s, self.shards[s].frames(lo, hi)) for s, lo, hi in requests]
-        return encode_shard_frames(frames, self.n_shards)
+        with span(WIRE_ENCODE):
+            return encode_shard_frames(frames, self.n_shards)
 
     # -- convenience --------------------------------------------------------
     def session(self, local: "ShardedStream | None" = None,

@@ -26,6 +26,7 @@ from repro.core.encoder import Encoder
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.symbols import CodedSymbols
 from repro.core.wire import encode_frames
+from repro.trace import WIRE_ENCODE, span
 
 
 class SymbolStream:
@@ -86,8 +87,9 @@ class SymbolStream:
         with this stream's ``start=lo`` and set size), so a receiver needs
         no side channel to place it in the stream.
         """
-        return encode_frames(self.window(lo, hi), start=lo,
-                             n_items=self.n_items)
+        sym = self.window(lo, hi)
+        with span(WIRE_ENCODE):
+            return encode_frames(sym, start=lo, n_items=self.n_items)
 
     # -- set mutation (updates the universal cache in place) ----------------
     def add_items(self, items) -> None:

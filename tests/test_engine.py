@@ -251,3 +251,56 @@ def test_engine_mixes_plain_and_sharded_peers():
     assert rep_plain.only_remote.shape[0] == 40
     assert rep_shard.only_remote.shape[0] == 70
     assert len(rep_shard.shards) == 4
+
+
+# ------------------------------------------------ device work counters ----
+@pytest.mark.parametrize("entry", ["engine", "session"])
+def test_device_counters_add_up(monkeypatch, entry):
+    """The reports' ``transfer_bytes`` sum to the bytes of every array
+    staged to and fetched from the device (a batched bucket's padding
+    unit included), and their ``device_waves`` to the waves of the decode
+    results: three pipelined peers in one padded bucket, or one lone
+    session on the non-pipelined path."""
+    import jax
+    from repro.kernels import ops
+    moved = [0]
+    results = {}
+    put, get = jax.device_put, jax.device_get
+    wait = ops.PendingBatchedDecode.wait
+
+    def nbytes(tree):
+        return sum(np.asarray(a).nbytes for a in jax.tree.leaves(tree))
+
+    def spy_put(x, *a, **k):
+        moved[0] += nbytes(x)
+        return put(x, *a, **k)
+
+    def spy_get(x):
+        out = get(x)
+        moved[0] += nbytes(out)
+        return out
+
+    def spy_wait(self):
+        out = wait(self)
+        results.update((id(r), r) for r in out)
+        return out
+    monkeypatch.setattr(jax, "device_put", spy_put)
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    monkeypatch.setattr(ops.PendingBatchedDecode, "wait", spy_wait)
+
+    nbytes_ = 16
+    state = rand_items(900, nbytes_, tag=0)
+    stream = SymbolStream.from_items(state, nbytes_)
+    mk = lambda lost: Session(local=Sketch.from_items(state[:-lost], nbytes_),
+                              pacing=FixedBlock(16), backend="device")
+    if entry == "engine":
+        reports = serve([(stream, mk(lost)) for lost in (20, 30, 40)])
+    else:
+        reports = [run_session(stream, mk(30), wire=True)]
+    assert [r.overflows + r.host_decodes for r in reports] == \
+        [0] * len(reports)
+    assert sum(r.transfer_bytes for r in reports) == moved[0] > 0
+    assert sum(r.device_waves for r in reports) == \
+        sum(r.rounds for r in results.values()) > 0
+    for r in reports:
+        assert r.device_waves >= r.device_decodes > 0
