@@ -31,9 +31,11 @@ def peel_engine(batched: bool = False) -> str:
     kernels) or ``"ref"`` (pure jnp).
 
     A lone decode and the encoder take the Pallas kernels on a TPU and the
-    ref engine elsewhere.  The batched decode always takes the ref engine:
-    its dense stages ``vmap`` over the unit axis and compile for any
-    backend.
+    ref engine elsewhere.  The batched decode takes ``"ref"`` everywhere:
+    the ref engine's purity scan and chains, whose dense stages ``vmap``
+    over the unit axis and compile for any backend, with chain removal as
+    one matrix product over the wave's candidate rows
+    (:func:`repro.kernels.iblt_dense.iblt_apply_dense`).
     """
     if batched or jax.default_backend() != "tpu":
         return "ref"
@@ -334,7 +336,7 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     Returns a :class:`PendingBatchedDecode`; ``wait()`` yields one
     :class:`DeviceDecodeResult` per unit, in input order.
     """
-    # the batched stages are the ref engine's everywhere; ``interpret``
+    # the batched stages are the same everywhere; ``interpret``
     # only picks one staged program (compiled platforms) or a Python loop
     _, interpret = _engine(None, interpret)
     from repro.core.symbols import CodedSymbols

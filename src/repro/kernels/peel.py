@@ -32,6 +32,9 @@ CPU runs and oracle tests; both engines produce bit-identical waves.
 For batched serving, :func:`peel_waves_batched` ``vmap``s the identical
 wave over a leading **unit axis** — U independent decodes, ragged prefix
 lengths as data, one compiled program (see ``ops.decode_device_batched``).
+Its chain removal is one matrix product over the wave's candidate rows
+(:func:`repro.kernels.iblt_dense.iblt_apply_dense`), bit-identical to the
+ref engine's bit-parity scatter.
 A unit was originally one shard of a sharded session; through
 ``repro.protocol.engine`` it is any (peer, shard) pair in a shape bucket,
 so N concurrent peers cost one dispatch per tick, not N.
@@ -46,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .common import checksum_pair, pallas_interpret
+from .iblt_dense import iblt_apply_dense
 from .iblt_encode import iblt_apply
 from .map_indices import map_indices
 from .ref import iblt_apply_ref, map_indices_ref
@@ -361,16 +365,23 @@ def _batched_wave_jit(mp: int, cap: int, max_diff: int, K: int, nbytes: int,
     Cached per static-shape bucket ``(mp, cap, max_diff, K)``; the
     per-unit prefix lengths ``m`` enter as a traced ``(S,)`` vector, so a
     set of growing unit prefixes re-uses one compiled program until the
-    *longest* unit crosses a tile boundary.  Always the ref engine: dense
-    jnp stages vmap cleanly and compile for both CPU and TPU.
+    *longest* unit crosses a tile boundary.  The same stages on every
+    backend: dense jnp stages vmap cleanly and compile for both CPU and
+    TPU.
     """
     return jax.jit(_batched_wave(mp, cap, max_diff, K, nbytes, key))
 
 
 def _batched_wave(mp, cap, max_diff, K, nbytes, key):
-    purity_fn, map_fn, apply_fn = _engines(
+    """The vmapped wave: the ref engine's purity scan and chains, and chain
+    removal as one product over the ``cap`` candidate rows."""
+    purity_fn, map_fn, _ = _engines(
         nbytes=nbytes, key=key, K=K, kernel="ref", mp=mp,
         block_m=mp, block_n=cap, interpret=True)
+
+    def apply_fn(items, idxs, chks, sides, m):
+        return iblt_apply_dense(items, idxs, chks, sides, m=m, m_out=mp)
+
     wave = functools.partial(_wave, mp=mp, cap=cap, max_diff=max_diff,
                              purity_fn=purity_fn, map_fn=map_fn,
                              apply_fn=apply_fn)
@@ -412,12 +423,16 @@ def peel_waves_batched(sums, checks, counts, *, m, nbytes: int, key,
     int32 vector of true per-unit prefix lengths and is traced data, not a
     static shape, so ragged unit progress batches into one program.
 
-    Every wave is one vmapped dispatch of the ref-engine stages over the
-    unit axis (:func:`_batched_wave_jit`); a unit whose wave recovers
-    nothing simply no-ops while hotter units keep peeling, and a unit
-    that trips ``max_diff`` freezes its own state and raises only its own
-    ``overflow`` flag — the other units are unaffected (per-unit host
-    fallback, not all-unit).
+    Every wave is one vmapped dispatch over the unit axis
+    (:func:`_batched_wave_jit`): the ref engine's purity scan and chains,
+    and chain removal as one matrix product over the wave's candidate rows
+    (:func:`repro.kernels.iblt_dense.iblt_apply_dense`), so each unit's
+    waves are the lone ref engine's, bit for bit.  A unit whose wave
+    recovers nothing simply no-ops while hotter units keep peeling, and a
+    unit that trips ``max_diff`` freezes its own state and raises only its
+    own ``overflow`` flag — the other units are unaffected (per-unit host
+    fallback, not all-unit).  Every unit counts the batch's waves in
+    ``rounds``.
 
     Returns ``(state, success)``: a :class:`PeelState` whose every leaf has
     the leading unit axis, and a ``(S,)`` bool of per-unit success (all

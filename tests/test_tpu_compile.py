@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.mapping import kmax
+from repro.kernels.iblt_dense import iblt_apply_dense
 from repro.kernels.iblt_encode import iblt_apply
 from repro.kernels.map_indices import map_indices
 from repro.kernels.peel import peel_waves, peel_waves_batched, purity_scan
@@ -26,11 +27,11 @@ from repro.kernels.peel import peel_waves, peel_waves_batched, purity_scan
 MP = 2048                  # symbols: the tile bucket of a d≈1,000 decode
 K = kmax(16384)            # 65 chain slots
 KEY = tuple(DEFAULT_KEY)
-# temporaries of the batched ref-engine decode at U=8, mp=2048, L=23,
-# max_diff=1024 (measured 3.46 GB): the bit unpack of every removed
-# item's chain dominates, and it has to leave most of the chip's 16 GB to
-# the residuals and the other buckets' programs
-BATCHED_TEMP_BUDGET = 4e9
+# temporaries of the batched decode at U=8, mp=2048, L=23, max_diff=1024
+# (measured 25.6 MB with the dense chain removal; the bit unpack of every
+# removed item's K chain slots it replaced took 3.46 GB, and would fail
+# this budget)
+BATCHED_TEMP_BUDGET = 256e6
 
 
 @pytest.fixture(scope="module")
@@ -67,6 +68,19 @@ def test_iblt_apply_compiles(one_chip, L):
     def apply(items, idxs, chks, sides, m):
         return iblt_apply(items, idxs, chks, sides, m=m, m_out=MP,
                           interpret=False)
+    jax.jit(apply).lower(
+        _shape(one_chip, (MP, L), jnp.uint32),
+        _shape(one_chip, (MP, K), jnp.int32),
+        _shape(one_chip, (MP, 2), jnp.uint32),
+        _shape(one_chip, (MP,), jnp.int32),
+        _shape(one_chip, (), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("L", [2, 23])
+def test_iblt_apply_dense_compiles(one_chip, L):
+    """The batched peel's chain removal over one wave's candidate rows."""
+    def apply(items, idxs, chks, sides, m):
+        return iblt_apply_dense(items, idxs, chks, sides, m=m, m_out=MP)
     jax.jit(apply).lower(
         _shape(one_chip, (MP, L), jnp.uint32),
         _shape(one_chip, (MP, K), jnp.int32),
