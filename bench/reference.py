@@ -20,21 +20,26 @@ def _prefix64(rows: np.ndarray) -> np.ndarray:
     return head.view("<u8").ravel()
 
 
-def _sorted_rows(rows: np.ndarray) -> np.ndarray:
-    """Rows in lexicographic byte order (``np.unique`` on whole rows)."""
+def _keys(rows: np.ndarray) -> np.ndarray:
+    """Each row as one ``np.void`` element of its width, which numpy
+    orders and compares byte by byte: one pass over the rows, not one per
+    byte column."""
     rows = np.ascontiguousarray(rows, np.uint8)
-    if rows.shape[0] == 0:
-        return rows
-    order = np.lexsort(rows.T[::-1])
-    return rows[order]
+    return rows.view(np.dtype((np.void, rows.shape[1]))).ravel()
+
+
+def _rows(keys: np.ndarray, nbytes: int) -> np.ndarray:
+    return keys.view(np.uint8).reshape(keys.shape[0], nbytes)
+
+
+def _sorted_rows(rows: np.ndarray) -> np.ndarray:
+    """Rows in lexicographic byte order."""
+    return _rows(np.sort(_keys(rows)), rows.shape[1])
 
 
 def _distinct(rows: np.ndarray) -> np.ndarray:
-    s = _sorted_rows(rows)
-    if s.shape[0] < 2:
-        return s
-    keep = np.r_[True, np.any(s[1:] != s[:-1], axis=1)]
-    return s[keep]
+    """Distinct rows in lexicographic byte order."""
+    return _rows(np.unique(_keys(rows)), rows.shape[1])
 
 
 class RowSet:

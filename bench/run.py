@@ -88,6 +88,17 @@ class Window:
     def reports(self):
         return [rep for r in self.rounds if r.reports for rep in r.reports]
 
+    def self_ms_per_recon(self, spans) -> float | None:
+        """Self time of the host spans named ``spans`` in the traced span,
+        in ms per reconciliation traced; None where the trace holds none
+        of them."""
+        recons = sum(len(r.replicas) for r in self.traced_rounds)
+        found = [self.trace.self_ns[s] for s in spans
+                 if self.trace and s in self.trace.self_ns]
+        if not found or not recons:
+            return None
+        return sum(found) / 1e6 / recons
+
 
 def _span(name):
     import jax

@@ -1,5 +1,5 @@
-"""Self time and idle-gap naming over the program's spans
-(``bench/spans.py``), and the readers of the program's device counters."""
+"""Self time and the idle split over the program's spans
+(``bench/trace.py``), and the readers of the program's device counters."""
 import sys
 from pathlib import Path
 from types import SimpleNamespace
@@ -10,7 +10,7 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import cell, spans  # noqa: E402
+from bench import cell  # noqa: E402
 from bench import trace as tr  # noqa: E402
 
 DEV = "/device:TPU:0"
@@ -42,31 +42,28 @@ EVENTS = [
 def test_self_time_by_hand():
     # bench.traced and bench.run cover the same stretch: the traced span
     # is left with nothing.  JAX's own events take nothing from a span.
-    got = spans.self_ns(EVENTS, [(0, 1000)])
+    got = tr.self_ns(EVENTS, [(0, 1000)])
     assert got == {tr.SPAN: 0.0, "bench.run": 1000.0 - 800 - 50,
                    "repro.tick": 800.0 - 200 - 100 - 400,
                    "repro.absorb": 200.0 + 50, "repro.stage": 100.0,
                    "repro.wait": 400.0, "repro.serve": 50.0}
     # clipped to another stretch, spans outside it are not there at all
-    assert spans.self_ns(EVENTS, [(300, 400)]) == {
+    assert tr.self_ns(EVENTS, [(300, 400)]) == {
         tr.SPAN: 0.0, "bench.run": 0.0, "repro.tick": 0.0,
         "repro.stage": 100.0}
 
 
 def test_gaps_are_named_by_the_innermost_program_span():
-    assert spans.idle_gaps(EVENTS, 0, 1000) == [(0, 380), (780, 1000)]
-    # [0, 380) mid 190 in repro.absorb; [780, 1000) mid 890 in repro.tick
-    assert spans.idle_by_span(EVENTS, 0, 1000) == {"repro.absorb": 380.0,
-                                                   "repro.tick": 220.0}
+    assert tr.idle_gaps(EVENTS, 0, 1000) == [(0, 380), (780, 1000)]
     # split exactly: [0, 100) in bench.run, [100, 300) absorb, [300, 380)
     # stage; [780, 800) wait, [800, 900) tick, [900, 950) bench.run,
     # [950, 1000) the second absorb
-    assert spans.self_ns(EVENTS, spans.idle_gaps(EVENTS, 0, 1000)) == {
+    assert tr.self_ns(EVENTS, tr.idle_gaps(EVENTS, 0, 1000)) == {
         tr.SPAN: 0.0, "bench.run": 150.0, "repro.tick": 100.0,
         "repro.absorb": 250.0, "repro.stage": 80.0, "repro.wait": 20.0}
     # outside every span the gap keeps the traced span's name
     lone = [ev(tr.SPAN, 0, 100), ev("fusion.1", 0, 40, DEV, tr.OPS_LINE)]
-    assert spans.idle_by_span(lone, 0, 100) == {tr.SPAN: 60.0}
+    assert tr.self_ns(lone, tr.idle_gaps(lone, 0, 100)) == {tr.SPAN: 60.0}
 
 
 def _window(reports):

@@ -2,24 +2,32 @@
 
 :func:`capture` records a profiler trace into a temporary directory, and
 :func:`load` flattens its ``.xplane.pb`` into :class:`Event`\\ s.  The rest
-is arithmetic on those events and is tested on a trace recorded on a v5e
+is arithmetic on those events and is tested on traces recorded on a v5e
 (``tests/data``):
 
 * busy time is the union of the intervals of the device's op events inside
   the traced span (the benchmark's own ``bench.traced`` host span);
 * program time is the sum of the device's module (whole program) events;
-* an idle gap is a stretch of the span with no device op; it is named by
-  the innermost ``bench.*`` host span that covers its midpoint.
+* a host span is a ``bench.*`` span of the benchmark or a ``repro.*`` span
+  of the program; its self time is its duration less what the host spans
+  nested in it on the same thread cover;
+* an idle gap is a stretch of the span with no device op; each idle
+  nanosecond goes to the innermost host span covering it, so idle time by
+  span is the spans' self time inside the gaps (``bench.traced`` where no
+  other span covers it).
 """
 from __future__ import annotations
 
+import bisect
 import contextlib
 import dataclasses
 import glob
+import itertools
 import os
 import tempfile
 
 SPAN = "bench.traced"
+PREFIXES = ("bench.", "repro.")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -79,8 +87,9 @@ def is_device_plane(name: str) -> bool:
 
 
 def host_spans(events) -> list[Event]:
+    """The host spans of the benchmark and of the program."""
     return [e for e in events
-            if not is_device_plane(e.plane) and e.name.startswith("bench.")]
+            if not is_device_plane(e.plane) and e.name.startswith(PREFIXES)]
 
 
 def traced_span(events) -> tuple[float, float] | None:
@@ -106,6 +115,85 @@ def _clip(intervals, lo, hi):
             if min(e, hi) > max(s, lo)]
 
 
+class _Cover:
+    """The length of sorted disjoint ``(start, end)`` intervals inside any
+    stretch, in log time: a prefix sum over the intervals."""
+
+    def __init__(self, intervals):
+        self.starts = [a for a, _ in intervals]
+        self.ends = [b for _, b in intervals]
+        self.before = list(itertools.accumulate(
+            (b - a for a, b in intervals), initial=0.0))
+
+    def _upto(self, x: float) -> float:
+        k = bisect.bisect_right(self.starts, x)
+        return self.before[k] - max(0.0, self.ends[k - 1] - x) if k else 0.0
+
+    def within(self, e) -> float:
+        """ns of event ``e`` inside the intervals."""
+        return self._upto(e.end_ns) - self._upto(e.start_ns)
+
+
+def device_planes(events) -> list[str]:
+    return sorted({e.plane for e in events if is_device_plane(e.plane)})
+
+
+def _busy(events, plane, lo, hi) -> list:
+    """The disjoint intervals of ``[lo, hi)`` in which ``plane`` ran an
+    op."""
+    return _union(_clip([(e.start_ns, e.end_ns) for e in events
+                         if e.plane == plane and e.line == OPS_LINE], lo, hi))
+
+
+def _gaps(busy, lo, hi) -> list:
+    edges = [lo] + [x for iv in busy for x in iv] + [hi]
+    return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+            if edges[i + 1] > edges[i]]
+
+
+def idle_gaps(events, lo: float, hi: float) -> list:
+    """``(start, end)`` of each stretch of ``[lo, hi)`` in which a device
+    ran no op, over every device plane."""
+    return [g for plane in device_planes(events)
+            for g in _gaps(_busy(events, plane, lo, hi), lo, hi)]
+
+
+def self_ns(events, intervals) -> dict:
+    """Self time of each host span name inside the sorted disjoint
+    ``(start, end)`` ``intervals``, in ns: the traced span, or one
+    device's idle gaps.
+
+    Spans of one thread nest, so the spans a span covers are its direct
+    children plus theirs, and its children's clipped durations sum to the
+    part of it they cover.  JAX's own trace events are no span's
+    children."""
+    out = {}
+    threads = {}
+    cover = _Cover(intervals)
+    for e in host_spans(events):
+        threads.setdefault((e.plane, e.line), []).append(e)
+    for evs in threads.values():
+        stack = []                       # [event, clipped child ns]
+        evs.sort(key=lambda e: (e.start_ns, -e.end_ns))
+
+        def close(top):
+            e, kids = top
+            own = cover.within(e)
+            if own <= 0:
+                return
+            out[e.name] = out.get(e.name, 0.0) + own - kids
+            if stack:
+                stack[-1][1] += own
+
+        for e in evs:
+            while stack and stack[-1][0].end_ns <= e.start_ns:
+                close(stack.pop())
+            stack.append([e, 0.0])
+        while stack:
+            close(stack.pop())
+    return out
+
+
 @dataclasses.dataclass
 class Reduced:
     devices: int
@@ -114,7 +202,8 @@ class Reduced:
     program_ns: float        # module time summed over devices
     programs: dict           # module name -> ns, summed over devices
     ops: dict                # op name -> ns, summed over devices
-    gaps: list               # (host span name, ns), longest first
+    self_ns: dict            # host span name -> self ns in the span
+    idle_ns: dict            # host span name -> idle ns, mean over devices
 
     @property
     def idle_share(self) -> float:
@@ -122,49 +211,39 @@ class Reduced:
 
 
 def reduce(events, span: tuple[float, float] | None = None) -> Reduced | None:
-    """Reduce flattened events to busy, program and gap times inside
+    """Reduce flattened events to busy, program, self and idle times inside
     ``span`` (default: the ``bench.traced`` host span).  None where the
     trace holds no span or no device op inside it."""
     span = span or traced_span(events)
     if span is None:
         return None
     lo, hi = span
-    planes = sorted({e.plane for e in events if is_device_plane(e.plane)})
-    busy, program_ns, programs, ops, idle = [], 0.0, {}, {}, []
+    planes = device_planes(events)
+    busy, program_ns, programs, ops, idle_ns = [], 0.0, {}, {}, {}
     for plane in planes:
-        op_iv = []
         for e in events:
             if e.plane != plane or e.end_ns <= lo or e.start_ns >= hi:
                 continue
             inside = min(e.end_ns, hi) - max(e.start_ns, lo)
             if e.line == OPS_LINE:
-                op_iv.append((e.start_ns, e.end_ns))
                 ops[e.name] = ops.get(e.name, 0.0) + inside
             elif e.line == MODULES_LINE:
                 programs[e.name] = programs.get(e.name, 0.0) + inside
                 program_ns += inside
-        merged = _union(_clip(op_iv, lo, hi))
+        merged = _busy(events, plane, lo, hi)
         busy.append(sum(e - s for s, e in merged))
-        edges = [lo] + [x for iv in merged for x in iv] + [hi]
-        idle += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-                 if edges[i + 1] > edges[i]]
+        for name, ns in self_ns(events, _gaps(merged, lo, hi)).items():
+            idle_ns[name] = idle_ns.get(name, 0.0) + ns / len(planes)
     if not planes or not any(busy):
         return None
-    spans = [e for e in host_spans(events) if e.name != SPAN]
-    gaps = []
-    for s, e in idle:
-        mid = (s + e) / 2
-        cover = [h for h in spans if h.start_ns <= mid < h.end_ns]
-        name = min(cover, key=lambda h: h.dur_ns).name if cover else SPAN
-        gaps.append((name, e - s))
-    gaps.sort(key=lambda g: -g[1])
     return Reduced(len(planes), hi - lo, sum(busy) / len(planes), program_ns,
-                   programs, ops, gaps)
+                   programs, ops, self_ns(events, [(lo, hi)]),
+                   {k: v for k, v in idle_ns.items() if v > 0})
 
 
 def breakdown(red: Reduced, top: int = 10) -> dict:
-    """The result line's ``breakdown``: the device ops that took most time
-    and the longest idle gaps, in seconds."""
+    """The result line's ``breakdown``: the device ops that took most time,
+    and the host spans that hold the most idle time, in seconds."""
     ops = {}
     for name, ns in red.ops.items():
         # an op event is named by its whole HLO instruction: keep its name
@@ -172,4 +251,5 @@ def breakdown(red: Reduced, top: int = 10) -> dict:
         ops[short] = ops.get(short, 0.0) + ns
     ops = sorted(ops.items(), key=lambda kv: -kv[1])[:top]
     return {"device_ops": [[k, v / 1e9] for k, v in ops],
-            "idle_gaps": [[k, v / 1e9] for k, v in red.gaps[:top]]}
+            "idle_gaps": [[k, v / 1e9] for k, v in sorted(
+                red.idle_ns.items(), key=lambda kv: -kv[1])[:top]]}
