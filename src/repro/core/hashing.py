@@ -163,57 +163,101 @@ def _word(words, j):
     return jax.lax.bitcast_convert_type(w, jnp.uint32)
 
 
+# 8-byte blocks compressed per loop trip of the XLA hash: fewer trips, and
+# one copy of a contiguous slab per trip
+BLOCKS_PER_TRIP = 8
+
+
+def _sip_init(key, lead):
+    k0h, k0l = _const_pair(key[0])
+    k1h, k1l = _const_pair(key[1])
+    c0, c1, c2, c3 = (_const_pair(x) for x in (
+        0x736F6D6570736575, 0x646F72616E646F6D, 0x6C7967656E657261, 0x7465646279746573))
+    return tuple((jnp.broadcast_to(h, lead), jnp.broadcast_to(lo, lead))
+                 for h, lo in ((k0h ^ c0[0], k0l ^ c0[1]),
+                               (k1h ^ c1[0], k1l ^ c1[1]),
+                               (k0h ^ c2[0], k0l ^ c2[1]),
+                               (k1h ^ c3[0], k1l ^ c3[1])))
+
+
+def _sip_block(v, mh, ml):
+    """Compress one 8-byte block ``(mh, ml)`` into the state."""
+    v = list(v)
+    v[3] = (v[3][0] ^ mh, v[3][1] ^ ml)
+    v = list(_sipround_j(tuple(v)))
+    v = list(_sipround_j(tuple(v)))
+    v[0] = (v[0][0] ^ mh, v[0][1] ^ ml)
+    return tuple(v)
+
+
+def _sip_final(v, odd, nbytes: int, lead):
+    """The last block (the odd word, if any, and the length byte) and the
+    four finalization rounds -> (hi, lo)."""
+    bh = jnp.broadcast_to(jnp.uint32((nbytes & 0xFF) << 24), lead)
+    bl = jnp.broadcast_to(jnp.uint32(0) if odd is None else odd, lead)
+    v = list(_sip_block(v, bh, bl))
+    v[2] = (v[2][0], v[2][1] ^ jnp.uint32(0xFF))
+    for _ in range(4):
+        v = list(_sipround_j(tuple(v)))
+    hi = v[0][0] ^ v[1][0] ^ v[2][0] ^ v[3][0]
+    lo = v[0][1] ^ v[1][1] ^ v[2][1] ^ v[3][1]
+    return hi, lo
+
+
 def siphash24_pair(words, key=DEFAULT_KEY, nbytes: int | None = None):
     """JAX SipHash-2-4 of uint32 words ``(..., L)`` -> (hi, lo) uint32 pair.
 
-    Bit-exact with :func:`siphash24` (hi = result >> 32, lo = low word).
-    Works inside jit / vmap / Pallas (elementwise + shifts only).
+    Bit-exact with :func:`siphash24` (hi = result >> 32, lo = low word),
+    O(L) per row: the 8-byte blocks are moved to a leading axis once and
+    a rolled loop reads :data:`BLOCKS_PER_TRIP` of them per trip (rolled:
+    unrolled over every block, the TPU compiler takes ~25 s per hash of a
+    92-byte item).  For XLA; inside a Pallas kernel use
+    :func:`siphash24_pair_kernel`.
     """
     words = jnp.asarray(words, dtype=jnp.uint32)
     L = words.shape[-1]
     if nbytes is None:
         nbytes = 4 * L
     lead = words.shape[:-1]
+    v = _sip_init(key, lead)
+    nb = L // 2
+    # (..., nb, 2) -> (nb, 2, ...): block i is one contiguous slab
+    blocks = jnp.moveaxis(words[..., :2 * nb].reshape(lead + (nb, 2)),
+                          (-2, -1), (0, 1))
+    if nb:
+        # a trip reads the largest whole share of the blocks up to
+        # BLOCKS_PER_TRIP, and no block is compressed outside the loop
+        # (XLA's CPU compiler takes minutes over a few blocks unrolled
+        # beside the mapping chain's loop)
+        k = max(j for j in range(1, BLOCKS_PER_TRIP + 1) if nb % j == 0)
 
-    def bcast(pair):
-        return (jnp.broadcast_to(pair[0], lead), jnp.broadcast_to(pair[1], lead))
+        def trip(v, slab):          # slab (k, 2, ...)
+            for j in range(k):
+                v = _sip_block(v, slab[j, 1], slab[j, 0])
+            return v, None
 
-    k0h, k0l = _const_pair(key[0])
-    k1h, k1l = _const_pair(key[1])
-    c0, c1, c2, c3 = (_const_pair(x) for x in (
-        0x736F6D6570736575, 0x646F72616E646F6D, 0x6C7967656E657261, 0x7465646279746573))
-    v = [bcast((k0h ^ c0[0], k0l ^ c0[1])), bcast((k1h ^ c1[0], k1l ^ c1[1])),
-         bcast((k0h ^ c2[0], k0l ^ c2[1])), bcast((k1h ^ c3[0], k1l ^ c3[1]))]
+        v, _ = jax.lax.scan(trip, v, blocks.reshape(
+            (nb // k, k) + blocks.shape[1:]))
+    return _sip_final(v, words[..., L - 1] if L % 2 else None, nbytes, lead)
+
+
+def siphash24_pair_kernel(words, key=DEFAULT_KEY, nbytes: int | None = None):
+    """:func:`siphash24_pair` for the body of a Pallas TPU kernel, which has
+    no dynamic lane index: each block is read as a masked sum over the
+    word axis, O(L²) per row, fit only for the narrow rows those kernels
+    load whole."""
+    words = jnp.asarray(words, dtype=jnp.uint32)
+    L = words.shape[-1]
+    if nbytes is None:
+        nbytes = 4 * L
+    lead = words.shape[:-1]
 
     def block(i, v):
-        mh, ml = _word(words, 2 * i + 1), _word(words, 2 * i)
-        v = list(v)
-        v[3] = (v[3][0] ^ mh, v[3][1] ^ ml)
-        v = list(_sipround_j(tuple(v)))
-        v = list(_sipround_j(tuple(v)))
-        v[0] = (v[0][0] ^ mh, v[0][1] ^ ml)
-        return tuple(v)
+        return _sip_block(v, _word(words, 2 * i + 1), _word(words, 2 * i))
 
-    # a rolled loop over the 8-byte blocks: unrolled, the TPU compiler
-    # takes ~25 s per hash of a 92-byte item
-    v = list(jax.lax.fori_loop(0, L // 2, block, tuple(v)))
-    bh = jnp.uint32((nbytes & 0xFF) << 24)
-    bl = jnp.uint32(0)
-    if L % 2 == 1:
-        bl = words[..., L - 1]
-    bh = jnp.broadcast_to(bh, lead)
-    bl = jnp.broadcast_to(bl, lead)
-    v[3] = (v[3][0] ^ bh, v[3][1] ^ bl)
-    v = list(_sipround_j(tuple(v)))
-    v = list(_sipround_j(tuple(v)))
-    v[0] = (v[0][0] ^ bh, v[0][1] ^ bl)
-    ffh, ffl = jnp.uint32(0), jnp.uint32(0xFF)
-    v[2] = (v[2][0] ^ ffh, v[2][1] ^ ffl)
-    for _ in range(4):
-        v = list(_sipround_j(tuple(v)))
-    hi = v[0][0] ^ v[1][0] ^ v[2][0] ^ v[3][0]
-    lo = v[0][1] ^ v[1][1] ^ v[2][1] ^ v[3][1]
-    return hi, lo
+    # rolled, as in siphash24_pair
+    v = jax.lax.fori_loop(0, L // 2, block, _sip_init(key, lead))
+    return _sip_final(v, words[..., L - 1] if L % 2 else None, nbytes, lead)
 
 
 # ---------------------------------------------------------------------------

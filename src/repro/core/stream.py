@@ -174,8 +174,9 @@ class StreamDecoder:
         """Fold a successful :func:`repro.kernels.ops.decode_device` (or one
         unit of ``decode_device_batched``) outcome into host state: adopt
         the peeled residual as ``work`` and register each newly recovered
-        item with its chain advanced to the first index ≥ the prefix length
-        (so later windows keep extending it).  ``res.overflow`` must be
+        item with its chain, started from the seed the device returned,
+        advanced to the first index ≥ the prefix length (so later windows
+        keep extending it).  ``res.overflow`` must be
         False — overflowed decodes leave state untouched and the caller
         falls back to :meth:`peel_window`.
 
@@ -196,7 +197,7 @@ class StreamDecoder:
         else:
             self.work = res.residual
         nxt = np.zeros(res.items.shape[0], np.int64)
-        state = map_seeds(res.items, self.key, self.nbytes).copy()
+        state = res.seeds.copy()   # the device hashed them as it peeled
         walk_chains(nxt, state, m0)  # position each chain at first idx >= m0
         # remove the new items from any tail rows and leave every chain
         # parked at the first index >= work.m for future windows

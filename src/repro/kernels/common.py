@@ -11,7 +11,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hashing import map_key, siphash24_pair
+from repro.core.hashing import map_key, siphash24_pair, siphash24_pair_kernel
 
 
 def pallas_interpret(interpret: bool):
@@ -22,18 +22,24 @@ def pallas_interpret(interpret: bool):
     return pltpu.InterpretParams() if interpret else False
 
 
-def checksum_pair(items, key, nbytes: int):
-    """(hi, lo) uint32 checksum of an item block ``(..., L)``."""
-    return siphash24_pair(items, key, nbytes)
+def _siphash(in_kernel: bool):
+    return siphash24_pair_kernel if in_kernel else siphash24_pair
 
 
-def checksum_and_seed(items, key, nbytes: int):
+def checksum_pair(items, key, nbytes: int, *, in_kernel: bool = False):
+    """(hi, lo) uint32 checksum of an item block ``(..., L)``;
+    ``in_kernel`` inside a Pallas kernel's body."""
+    return _siphash(in_kernel)(items, key, nbytes)
+
+
+def checksum_and_seed(items, key, nbytes: int, *, in_kernel: bool = False):
     """Checksum + mapping-PRNG seed for a block of items.
 
     Returns ``(chk_hi, chk_lo, seed_hi, seed_lo)`` uint32 arrays; the seed's
     low word is forced odd so the xorshift64 state is never zero — exactly
     the host-side :func:`repro.core.mapping.map_seeds` contract.
     """
-    chk_hi, chk_lo = siphash24_pair(items, key, nbytes)
-    seed_hi, seed_lo = siphash24_pair(items, map_key(key), nbytes)
+    hash_pair = _siphash(in_kernel)
+    chk_hi, chk_lo = hash_pair(items, key, nbytes)
+    seed_hi, seed_lo = hash_pair(items, map_key(key), nbytes)
     return chk_hi, chk_lo, seed_hi, seed_lo | jnp.uint32(1)

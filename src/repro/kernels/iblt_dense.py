@@ -22,6 +22,11 @@ float32 accumulation are exact and the result is bit-identical to the
 oracle.  (On a v5e the compare that builds H, fused into the product,
 takes nearly all of the time, so int8 operands would gain nothing there;
 XLA's CPU backend multiplies bfloat16 several times faster than int8.)
+
+Rows wider than :data:`TILE_WORDS` words are taken one word tile at a
+time: a tile's bits are unpacked, multiplied and packed back before the
+next, so the temporaries grow with ``n × TILE_WORDS`` and not with the
+item width (a 128-KiB row unpacks to a million bit columns).
 """
 from __future__ import annotations
 
@@ -41,6 +46,17 @@ def _bits(x):
     return bits.reshape(x.shape[0], -1).astype(jnp.bfloat16)
 
 
+# item words per product; a row of at most this many takes one product
+TILE_WORDS = 256
+
+
+def _product(H, cols):
+    """Hᵀ · cols, bf16 operands summed exactly in float32, as int32."""
+    acc = jax.lax.dot_general(H, cols, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    return acc.astype(jnp.int32)
+
+
 def iblt_apply_dense(items, idxs, chks, sides, *, m, m_out: int):
     """Signed coded-symbol delta of ``items`` over their mapped chains.
 
@@ -57,12 +73,31 @@ def iblt_apply_dense(items, idxs, chks, sides, *, m, m_out: int):
     hit = functools.reduce(jnp.logical_or, [idxs[:, k:k + 1] == sym
                                             for k in range(idxs.shape[1])])
     H = (hit & (sym < m)).astype(jnp.bfloat16)                # (n, m_out)
-    cols = jnp.concatenate([_bits(items), _bits(chks),
-                            sides.astype(jnp.bfloat16)[:, None]], axis=1)
-    acc = jax.lax.dot_general(H, cols, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    acc = acc.astype(jnp.int32)
-    par = acc[:, :32 * (L + 2)] & 1
-    sums = _pack_bits(par[:, :32 * L], L)
-    checks = _pack_bits(par[:, 32 * L:], 2)
-    return sums, checks, acc[:, 32 * (L + 2):]
+    side = sides.astype(jnp.bfloat16)[:, None]
+    if L <= TILE_WORDS:
+        acc = _product(H, jnp.concatenate([_bits(items), _bits(chks), side],
+                                          axis=1))
+        par = acc[:, :32 * (L + 2)] & 1
+        sums = _pack_bits(par[:, :32 * L], L)
+        checks = _pack_bits(par[:, 32 * L:], 2)
+        return sums, checks, acc[:, 32 * (L + 2):]
+
+    acc = _product(H, jnp.concatenate([_bits(chks), side], axis=1))
+    checks, counts = _pack_bits(acc[:, :64] & 1, 2), acc[:, 64:]
+    T = TILE_WORDS
+    trips = L // T
+
+    def tile(items_t):
+        return _pack_bits(_product(H, _bits(items_t)) & 1, items_t.shape[1])
+
+    def trip(t, sums):
+        lo = t * T
+        return jax.lax.dynamic_update_slice(
+            sums, tile(jax.lax.dynamic_slice_in_dim(items, lo, T, axis=1)),
+            (0, lo))
+
+    sums = jax.lax.fori_loop(0, trips, trip,
+                             jnp.zeros((m_out, L), jnp.uint32))
+    if L % T:
+        sums = sums.at[:, trips * T:].set(tile(items[:, trips * T:]))
+    return sums, checks, counts

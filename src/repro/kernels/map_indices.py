@@ -8,7 +8,7 @@ VPU work with zero cross-lane traffic, which is why the chain generator is a
 lane-parallel kernel rather than the Go heap.
 
 Layout: items (n, L) uint32 in VMEM blocks of (BN, L); outputs idx (n, K)
-int32, checksum (n, 2) uint32 (hi, lo).
+int32, checksum (n, 2) uint32 (hi, lo), seed (n, 2) uint32 (hi, lo).
 """
 from __future__ import annotations
 
@@ -25,10 +25,13 @@ from .common import checksum_and_seed, pallas_interpret
 _IDX_SAT = int(_JUMP_CAP) - 1   # idx + clamped jump stays below 2**31
 
 
-def _kernel(items_ref, idx_ref, chk_ref, *, K: int, nbytes: int, key):
+def _kernel(items_ref, idx_ref, chk_ref, seed_ref, *, K: int, nbytes: int,
+            key):
     items = items_ref[...]                       # (BN, L) uint32
-    chk_hi, chk_lo, h, l = checksum_and_seed(items, key, nbytes)
+    chk_hi, chk_lo, h, l = checksum_and_seed(items, key, nbytes,
+                                             in_kernel=True)
     chk_ref[...] = jnp.stack([chk_hi, chk_lo], axis=1)
+    seed_ref[...] = jnp.stack([h, l], axis=1)
     bn = items.shape[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (bn, K), 1)
 
@@ -50,7 +53,8 @@ def _kernel(items_ref, idx_ref, chk_ref, *, K: int, nbytes: int, key):
 
 def map_indices(items, *, K: int, m, nbytes: int, key,
                 block_n: int = 256, interpret: bool = True):
-    """items (n, L) uint32 -> (idx (n, K) int32, checksum (n, 2) uint32).
+    """items (n, L) uint32 -> (idx (n, K) int32, checksum (n, 2) uint32,
+    mapping seed (n, 2) uint32).
 
     Indices saturate at ``m`` (the pad that maps nowhere).  ``m`` is
     applied outside the kernel, so it may be traced and one compiled
@@ -62,14 +66,16 @@ def map_indices(items, *, K: int, m, nbytes: int, key,
     assert n % block_n == 0, (n, block_n)
     grid = (n // block_n,)
     kernel = functools.partial(_kernel, K=K, nbytes=nbytes, key=key)
-    idx, chk = pl.pallas_call(
+    idx, chk, seed = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_n, L), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((block_n, K), lambda i: (i, 0)),
+                   pl.BlockSpec((block_n, 2), lambda i: (i, 0)),
                    pl.BlockSpec((block_n, 2), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n, K), jnp.int32),
+                   jax.ShapeDtypeStruct((n, 2), jnp.uint32),
                    jax.ShapeDtypeStruct((n, 2), jnp.uint32)],
         interpret=pallas_interpret(interpret),
     )(items)
-    return jnp.minimum(idx, jnp.asarray(m, jnp.int32)), chk
+    return jnp.minimum(idx, jnp.asarray(m, jnp.int32)), chk, seed

@@ -87,9 +87,9 @@ def encode_device(items, *, m: int, nbytes: int | None = None,
         n_pad = items_padded.shape[0] - n0
         if mapping == "pallas":
             # the kernel needs whole blocks — map everything, mask the pads
-            idxs, chks = map_indices(items_padded, K=K, m=m, nbytes=nbytes,
-                                     key=key, block_n=block_n,
-                                     interpret=interpret)
+            idxs, chks, _ = map_indices(items_padded, K=K, m=m,
+                                        nbytes=nbytes, key=key,
+                                        block_n=block_n, interpret=interpret)
             if n_pad:
                 pad_rows = jnp.arange(items_padded.shape[0]) >= n0
                 idxs = jnp.where(pad_rows[:, None], jnp.int32(m), idxs)
@@ -115,6 +115,12 @@ def encode_device(items, *, m: int, nbytes: int | None = None,
     return run(padded)
 
 
+def _pairs64(pairs) -> np.ndarray:
+    """(r, 2) uint32 (hi, lo) pairs -> (r,) uint64."""
+    return (pairs[:, 0].astype(np.uint64) << np.uint64(32)) | \
+        pairs[:, 1].astype(np.uint64)
+
+
 def device_symbols_to_host(sums, checks, counts, nbytes: int):
     """Convert device output to a host CodedSymbols (checks -> uint64)."""
     from repro.core.symbols import CodedSymbols
@@ -123,9 +129,8 @@ def device_symbols_to_host(sums, checks, counts, nbytes: int):
     sums = np.array(sums, dtype=np.uint32)
     checks = np.asarray(checks, dtype=np.uint32)
     counts = np.asarray(counts)
-    c64 = (checks[:, 0].astype(np.uint64) << np.uint64(32)) | \
-        checks[:, 1].astype(np.uint64)
-    return CodedSymbols(sums, c64, counts.astype(np.int64), nbytes)
+    return CodedSymbols(sums, _pairs64(checks), counts.astype(np.int64),
+                        nbytes)
 
 
 def host_symbols_to_device(sym):
@@ -150,6 +155,9 @@ class DeviceDecodeResult(NamedTuple):
     rounds: int           # peel waves executed
     residual: object      # CodedSymbols — symbols after all removals
     transfer_bytes: int = 0   # host→device staged + device→host fetched
+    # (r,) uint64 — the recovered items' mapping seeds, as
+    # :func:`repro.core.mapping.map_seeds` gives them
+    seeds: np.ndarray = np.zeros(0, np.uint64)
 
 
 def _stage(*arrays):
@@ -226,15 +234,13 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
         (state, success), got = _fetch(out)
     with span(UNSTAGE):
         n_rec = int(state.n_rec)
-        rchk = state.rec_checks[:n_rec]
-        hashes = (rchk[:, 0].astype(np.uint64) << np.uint64(32)) | \
-            rchk[:, 1].astype(np.uint64)
         residual = device_symbols_to_host(
             state.sums[:m], state.checks[:m], state.counts[:m, 0], nbytes)
         return DeviceDecodeResult(
-            state.rec_items[:n_rec], hashes,
+            state.rec_items[:n_rec], _pairs64(state.rec_checks[:n_rec]),
             state.rec_sides[:n_rec].astype(np.int8), bool(success),
-            bool(state.overflow), int(state.rounds), residual, sent + got)
+            bool(state.overflow), int(state.rounds), residual, sent + got,
+            _pairs64(state.rec_seeds[:n_rec]))
 
 
 class PendingBatchedDecode:
@@ -284,18 +290,17 @@ class PendingBatchedDecode:
             out = []
             for s, m_s in enumerate(ms):
                 n_rec = int(state.n_rec[s])
-                rchk = state.rec_checks[s, :n_rec]
-                hashes = (rchk[:, 0].astype(np.uint64) <<
-                          np.uint64(32)) | rchk[:, 1].astype(np.uint64)
                 residual = device_symbols_to_host(
                     state.sums[s, :m_s], state.checks[s, :m_s],
                     state.counts[s, :m_s, 0], nbytes)
                 out.append(DeviceDecodeResult(
-                    state.rec_items[s, :n_rec].copy(), hashes,
+                    state.rec_items[s, :n_rec].copy(),
+                    _pairs64(state.rec_checks[s, :n_rec]),
                     state.rec_sides[s, :n_rec].astype(np.int8),
                     bool(success[s]), bool(state.overflow[s]),
                     int(state.rounds[s]), residual,
-                    total // len(ms) + (s < total % len(ms))))
+                    total // len(ms) + (s < total % len(ms)),
+                    _pairs64(state.rec_seeds[s, :n_rec])))
         self._results = out
         self._state = self._success = None   # free device references
         return out

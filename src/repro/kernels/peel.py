@@ -48,23 +48,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.mapping import map_seeds_pair
+from repro.trace import APPLY_SCOPE, HASH_SCOPE
+
 from .common import checksum_pair, pallas_interpret
 from .iblt_dense import iblt_apply_dense
 from .iblt_encode import iblt_apply
 from .map_indices import map_indices
-from .ref import iblt_apply_ref, map_indices_ref
+from .ref import chains_ref, iblt_apply_ref
 
 
 # ---------------------------------------------------------------------------
 # Stage 1: purity scan.
 # ---------------------------------------------------------------------------
-def _purity_body(sums, checks, counts, *, key, nbytes: int):
+def _purity_body(sums, checks, counts, *, key, nbytes: int,
+                 in_kernel: bool = False):
     """(BM, L) sums, (BM, 2) checks, (BM, 1) counts -> (BM,) int32 side.
 
     ``+1`` / ``-1`` where the symbol is pure (checksum matches the keyed
-    hash of its sum and it is non-empty), ``0`` otherwise.
+    hash of its sum and it is non-empty), ``0`` otherwise.  ``in_kernel``
+    inside a Pallas kernel's body.
     """
-    h_hi, h_lo = checksum_pair(sums, key, nbytes)
+    with jax.named_scope(HASH_SCOPE):
+        h_hi, h_lo = checksum_pair(sums, key, nbytes, in_kernel=in_kernel)
     cnt = counts[:, 0]
     pure = (h_hi == checks[:, 0]) & (h_lo == checks[:, 1]) & (cnt != 0)
     side = jnp.where(cnt > 0, jnp.int32(1), jnp.int32(-1))
@@ -74,7 +80,7 @@ def _purity_body(sums, checks, counts, *, key, nbytes: int):
 def _purity_kernel(sums_ref, checks_ref, counts_ref, side_ref, *, key,
                    nbytes: int):
     side = _purity_body(sums_ref[...], checks_ref[...], counts_ref[...],
-                        key=key, nbytes=nbytes)
+                        key=key, nbytes=nbytes, in_kernel=True)
     side_ref[...] = side[:, None]
 
 
@@ -110,6 +116,7 @@ class PeelState(NamedTuple):
     counts: jax.Array      # (mp, 1) int32  — residual signed counts
     rec_items: jax.Array   # (D, L) uint32  — recovered source symbols
     rec_checks: jax.Array  # (D, 2) uint32  — their checksums
+    rec_seeds: jax.Array   # (D, 2) uint32  — their mapping seeds (hi, lo)
     rec_sides: jax.Array   # (D,) int32     — +1 remote-only, -1 local-only
     n_rec: jax.Array       # () int32
     changed: jax.Array     # () bool — last wave recovered something
@@ -158,10 +165,11 @@ def _stage2(state: PeelState, p_items, p_chk, p_side, keep, m, *, mp: int,
     Flags (``changed``/``overflow``/``rounds``) are managed by the caller.
     """
     n_new = jnp.sum(keep.astype(jnp.int32))
-    idxs, _ = map_fn(p_items, m)
+    idxs, seeds = map_fn(p_items, m)
     idxs = jnp.where(keep[:, None], idxs, jnp.asarray(m, jnp.int32))
-    d_sums, d_checks, d_counts = apply_fn(
-        p_items, idxs, p_chk, jnp.where(keep, p_side, jnp.int32(0)), m)
+    with jax.named_scope(APPLY_SCOPE):
+        d_sums, d_checks, d_counts = apply_fn(
+            p_items, idxs, p_chk, jnp.where(keep, p_side, jnp.int32(0)), m)
 
     pos = state.n_rec + jnp.cumsum(keep.astype(jnp.int32)) - 1
     dest = jnp.where(keep, pos, max_diff)          # index max_diff = dropped
@@ -171,6 +179,7 @@ def _stage2(state: PeelState, p_items, p_chk, p_side, keep, m, *, mp: int,
         counts=state.counts - d_counts[:mp],
         rec_items=state.rec_items.at[dest].set(p_items, mode="drop"),
         rec_checks=state.rec_checks.at[dest].set(p_chk, mode="drop"),
+        rec_seeds=state.rec_seeds.at[dest].set(seeds, mode="drop"),
         rec_sides=state.rec_sides.at[dest].set(p_side, mode="drop"),
         n_rec=state.n_rec + n_new,
     )
@@ -197,16 +206,19 @@ def _wave(state: PeelState, m, *, mp: int, cap: int, max_diff: int,
 
 def _engines(*, nbytes: int, key, K: int, kernel: str, mp: int, block_m: int,
              block_n: int, interpret: bool):
-    """Build (purity_fn, map_fn(items, m), apply_fn(items, idxs, chks,
-    sides, m)) for one engine.  Both engines take ``m`` as data, so one
-    compiled program serves every prefix length within a tile bucket."""
+    """Build (purity_fn, map_fn(items, m) -> (idxs, seeds), apply_fn(items,
+    idxs, chks, sides, m)) for one engine.  Both engines take ``m`` as
+    data, so one compiled program serves every prefix length within a tile
+    bucket."""
     if kernel == "pallas":
         purity_fn = functools.partial(purity_scan, key=key, nbytes=nbytes,
                                       block_m=block_m, interpret=interpret)
 
         def map_fn(items, m):
-            return map_indices(items, K=K, m=m, nbytes=nbytes, key=key,
-                               block_n=block_n, interpret=interpret)
+            idxs, _, seeds = map_indices(items, K=K, m=m, nbytes=nbytes,
+                                         key=key, block_n=block_n,
+                                         interpret=interpret)
+            return idxs, seeds
 
         def apply_fn(items, idxs, chks, sides, m):
             return iblt_apply(items, idxs, chks, sides, m=m, m_out=mp,
@@ -217,7 +229,10 @@ def _engines(*, nbytes: int, key, K: int, kernel: str, mp: int, block_m: int,
             return _purity_body(sums, checks, counts, key=key, nbytes=nbytes)
 
         def map_fn(items, m):
-            return map_indices_ref(items, K=K, m=m, nbytes=nbytes, key=key)
+            # the candidates' checksums are known: hash for the seed only
+            with jax.named_scope(HASH_SCOPE):
+                h, l = map_seeds_pair(items, key, nbytes)
+            return chains_ref(h, l, K=K, m=m), jnp.stack([h, l], axis=1)
 
         def apply_fn(items, idxs, chks, sides, m):
             return iblt_apply_ref(items, idxs, chks, sides, m=m, m_out=mp)
@@ -240,6 +255,7 @@ def _init_state(sums, checks, counts, D: int) -> PeelState:
         counts=jnp.asarray(counts, jnp.int32),
         rec_items=jnp.zeros(lead + (D, L), jnp.uint32),
         rec_checks=jnp.zeros(lead + (D, 2), jnp.uint32),
+        rec_seeds=jnp.zeros(lead + (D, 2), jnp.uint32),
         rec_sides=jnp.zeros(lead + (D,), jnp.int32),
         n_rec=jnp.zeros(lead, jnp.int32),
         changed=jnp.ones(lead, bool),

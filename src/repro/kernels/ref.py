@@ -15,8 +15,15 @@ from .common import checksum_and_seed
 
 
 def map_indices_ref(items, *, K: int, m: int, nbytes: int, key):
+    """The ``map_indices`` kernel's chains and checksums in plain jnp."""
     chk_hi, chk_lo, h, l = checksum_and_seed(items, key, nbytes)
-    n = items.shape[0]
+    return chains_ref(h, l, K=K, m=m), jnp.stack([chk_hi, chk_lo], axis=1)
+
+
+def chains_ref(h, l, *, K: int, m):
+    """(n,) uint32 seed pairs -> (n, K) int32: the first K mapped indices
+    of each chain, saturated at ``m`` (which may be traced)."""
+    n = h.shape[0]
     msat = jnp.asarray(m, jnp.int32)     # m may be traced (peel stages)
 
     def step(k, carry):     # rolled: compiles in a fraction of the time
@@ -28,7 +35,7 @@ def map_indices_ref(items, *, K: int, m: int, nbytes: int, key):
     *_, idxs = jax.lax.fori_loop(
         0, K, step, (jnp.zeros(n, jnp.int32), h, l,
                      jnp.zeros((n, K), jnp.int32)))
-    return idxs, jnp.stack([chk_hi, chk_lo], axis=1)
+    return idxs
 
 
 def _unpack_bits(x):

@@ -109,9 +109,11 @@ class PeerState:
         self.device_decodes = 0
         self.host_decodes = 0
         self.overflows = 0
-        # device work of those decodes: peel waves run, and host<->device
-        # bytes staged and fetched (a batched bucket's split over its units)
+        # device work of those decodes: peel waves run, tile-padded symbols
+        # (each decode's mp), and host<->device bytes staged and fetched
+        # (a batched bucket's split over its units)
         self.device_waves = 0
+        self.device_symbols = 0
         self.transfer_bytes = 0
         # the ENGINE owns decode dispatch (plan/execute), so the decoders
         # never self-dispatch here; their backend/max_diff are still kept
@@ -377,6 +379,7 @@ def execute_round(units: list[DecodeUnit], block_m: int = 256,
     for (mp, L, nbytes, key, D), us in plan.buckets.items():
         for du in us:
             du.peer.device_decodes += 1
+            du.peer.device_symbols += mp
         works = [du.unit.decoder.work for du in us]
         if pipeline:
             pending = ops.decode_device_batched_start(

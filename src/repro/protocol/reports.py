@@ -31,9 +31,11 @@ class ReportBase:
     device_decodes: int       # unit decodes dispatched to the device
     host_decodes: int         # unit decodes on the host, fallbacks included
     overflows: int            # device decodes past max_diff (host re-ran)
-    # peel waves the device decodes ran, and the host<->device bytes they
-    # staged and fetched (0 where a report was built without them)
+    # peel waves the device decodes ran, the tile-padded symbols (mp) they
+    # peeled, summed over decodes, and the host<->device bytes they staged
+    # and fetched (0 where a report was built without them)
     device_waves: int = dataclasses.field(default=0, kw_only=True)
+    device_symbols: int = dataclasses.field(default=0, kw_only=True)
     transfer_bytes: int = dataclasses.field(default=0, kw_only=True)
 
     def only_remote_bytes(self) -> np.ndarray:
@@ -101,6 +103,7 @@ def _decode_counts(peer) -> dict:
                 device_decodes=peer.device_decodes,
                 host_decodes=peer.host_decodes, overflows=peer.overflows,
                 device_waves=peer.device_waves,
+                device_symbols=peer.device_symbols,
                 transfer_bytes=peer.transfer_bytes)
 
 

@@ -46,6 +46,12 @@ NAMES = (TICK, SERVE, WIRE_ENCODE, WIRE_DECODE, ABSORB, PLAN, STAGE, WAIT,
          UNSTAGE, MERGE, HOST_PEEL, REPORT)
 
 
+# Name scopes of the device peel's ops (``jax.named_scope``): a device op's
+# ``tf_op`` path in the profiler trace holds the scope it was traced in.
+HASH_SCOPE = "repro.hash"     # SipHash of the rows: purity scan, map seeds
+APPLY_SCOPE = "repro.apply"   # chain removal of a wave's recovered rows
+
+
 def span(name: str, **args) -> TraceAnnotation:
     """A host span ``name`` with ``args`` as its trace arguments; use it as
     a context manager.  Arguments known only inside the span are added

@@ -32,6 +32,20 @@ def test_siphash_host_device_bitexact(L, n, k0, k1):
     np.testing.assert_array_equal(h, h2)
 
 
+@pytest.mark.parametrize("L", [1, 2, 23, 1000, 32768])
+def test_siphash_device_bitexact_wide(L):
+    """The device hash reads each 8-byte block by its position (O(L) per
+    row): bit-exact with the host's up to 128-KiB rows, the odd last word
+    and the length byte of a row that ends mid-word included."""
+    w = rand_words(5, L)
+    for nbytes in {4 * L, 4 * L - 3}:
+        h = siphash24(w, (3, 11), nbytes=nbytes)
+        hi, lo = jax.jit(lambda x: siphash24_pair(x, (3, 11), nbytes))(w)
+        h2 = (np.asarray(hi).astype(np.uint64) << np.uint64(32)) | \
+            np.asarray(lo).astype(np.uint64)
+        np.testing.assert_array_equal(h, h2)
+
+
 def test_siphash_rfc_vector():
     """RFC/reference test vector: key 000102..0f, msg 000102..07."""
     key = (0x0706050403020100, 0x0F0E0D0C0B0A0908)

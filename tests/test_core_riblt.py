@@ -1,4 +1,6 @@
 """Rateless IBLT encoder/decoder system invariants (paper §3–§4)."""
+import zlib
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -6,26 +8,31 @@ from hypothesis import given, settings, strategies as st
 from repro.core import (CodedSymbols, Encoder, Sketch, StreamDecoder, encode,
                         peel, reconcile, reconcile_sets)
 
-RNG = np.random.default_rng(99)
+
+@pytest.fixture
+def rng(request):
+    """A generator of the test's own, seeded by its name and parameters,
+    so no test's draws depend on which tests ran before it."""
+    return np.random.default_rng(zlib.crc32(request.node.name.encode()))
 
 
-def rand_items(n, nbytes, tag=None):
-    out = RNG.integers(0, 256, size=(n, nbytes), dtype=np.uint8)
+def rand_items(rng, n, nbytes, tag=None):
+    out = rng.integers(0, 256, size=(n, nbytes), dtype=np.uint8)
     if tag is not None:
         out[:, 0] = tag  # disjointness between groups
     return out
 
 
 # ---------------------------------------------------------------- encode --
-def test_symbol_zero_contains_every_item():
-    items = rand_items(50, 16)
+def test_symbol_zero_contains_every_item(rng):
+    items = rand_items(rng, 50, 16)
     sym = encode(items, 16, 8)
     assert sym.counts[0] == 50  # rho(0) = 1
 
 
-def test_encode_prefix_consistency():
+def test_encode_prefix_consistency(rng):
     """Rateless property: a longer prefix extends, never rewrites (Fig 3)."""
-    items = rand_items(64, 24)
+    items = rand_items(rng, 64, 24)
     s1 = encode(items, 24, 32)
     s2 = encode(items, 24, 512)
     np.testing.assert_array_equal(s1.sums, s2.sums[:32])
@@ -33,8 +40,8 @@ def test_encode_prefix_consistency():
     np.testing.assert_array_equal(s1.counts, s2.counts[:32])
 
 
-def test_incremental_extension_equals_oneshot():
-    items = rand_items(64, 8)
+def test_incremental_extension_equals_oneshot(rng):
+    items = rand_items(rng, 64, 8)
     enc = Encoder(8)
     enc.add_items(items)
     for m in (1, 2, 5, 17, 63, 200):
@@ -46,11 +53,11 @@ def test_incremental_extension_equals_oneshot():
     np.testing.assert_array_equal(a.counts, b.counts)
 
 
-def test_incremental_add_remove_equals_rebuild():
+def test_incremental_add_remove_equals_rebuild(rng):
     """Linearity (§4.1): updating the cached symbols in place == re-encoding
     the updated set from scratch."""
-    base = rand_items(100, 16, tag=0)
-    add = rand_items(10, 16, tag=1)
+    base = rand_items(rng, 100, 16, tag=0)
+    add = rand_items(rng, 10, 16, tag=1)
     rm = base[:7]
     enc = Encoder(16)
     enc.add_items(base)
@@ -69,9 +76,10 @@ def test_incremental_add_remove_equals_rebuild():
 @given(st.integers(0, 40), st.integers(0, 40), st.integers(5, 33))
 def test_linearity_subtraction(na, nb, nbytes):
     """IBLT(A) ⊖ IBLT(B) == IBLT(A △ B)  (the enabling identity, §3)."""
-    common = rand_items(30, nbytes, tag=0)
-    a_only = rand_items(na, nbytes, tag=1)
-    b_only = rand_items(nb, nbytes, tag=2)
+    rng = np.random.default_rng([na, nb, nbytes])
+    common = rand_items(rng, 30, nbytes, tag=0)
+    a_only = rand_items(rng, na, nbytes, tag=1)
+    b_only = rand_items(rng, nb, nbytes, tag=2)
     m = 64
     sa = encode(np.concatenate([common, a_only]), nbytes, m)
     sb = encode(np.concatenate([common, b_only]), nbytes, m)
@@ -86,8 +94,8 @@ def test_linearity_subtraction(na, nb, nbytes):
 
 # ----------------------------------------------------------------- decode --
 @pytest.mark.parametrize("d", [1, 2, 5, 40, 300])
-def test_roundtrip_pure_set(d):
-    items = rand_items(d, 16)
+def test_roundtrip_pure_set(d, rng):
+    items = rand_items(rng, d, 16)
     m = max(8, int(2.2 * d))
     res = peel(encode(items, 16, m))
     assert res.success
@@ -101,10 +109,10 @@ def test_roundtrip_pure_set(d):
 
 
 @pytest.mark.parametrize("da,db", [(0, 5), (5, 0), (13, 7), (50, 50)])
-def test_reconcile_directions(da, db):
-    common = rand_items(200, 32, tag=0)
-    ai = rand_items(da, 32, tag=1)
-    bi = rand_items(db, 32, tag=2)
+def test_reconcile_directions(da, db, rng):
+    common = rand_items(rng, 200, 32, tag=0)
+    ai = rand_items(rng, da, 32, tag=1)
+    bi = rand_items(rng, db, 32, tag=2)
     A = Sketch.from_items(np.concatenate([common, ai]), 32)
     B = Sketch.from_items(np.concatenate([common, bi]), 32)
     only_a, only_b, m_used = reconcile_sets(A, B)
@@ -114,8 +122,8 @@ def test_reconcile_directions(da, db):
     assert m_used <= max(8, 8 * d)  # sane overhead even with block rounding
 
 
-def test_identical_sets_decode_immediately():
-    items = rand_items(64, 16)
+def test_identical_sets_decode_immediately(rng):
+    items = rand_items(rng, 64, 16)
     A = Sketch.from_items(items, 16)
     B = Sketch.from_items(items.copy(), 16)
     only_a, only_b, m_used = reconcile_sets(A, B)
@@ -123,18 +131,18 @@ def test_identical_sets_decode_immediately():
     assert m_used <= 8  # first block: all-zero symbols, symbol 0 empty
 
 
-def test_undecodable_prefix_reports_failure():
+def test_undecodable_prefix_reports_failure(rng):
     """With m ≪ d the peeling decoder must stall, not hallucinate."""
-    items = rand_items(500, 16)
+    items = rand_items(rng, 500, 16)
     res = peel(encode(items, 16, 16))
     assert not res.success
     assert len(res.items) < 500
 
 
-def test_symbol_zero_decodes_last():
+def test_symbol_zero_decodes_last(rng):
     """ρ(0)=1 ⇒ symbol 0 empties only when everything is recovered — the
     paper's termination signal."""
-    items = rand_items(60, 16)
+    items = rand_items(rng, 60, 16)
     sym = encode(items, 16, 200)
     res = peel(sym)
     assert res.success
@@ -149,10 +157,10 @@ def test_symbol_zero_decodes_last():
 
 
 # ----------------------------------------------------------------- stream --
-def test_stream_decoder_matches_batch():
-    common = rand_items(300, 16, tag=0)
-    ai = rand_items(25, 16, tag=1)
-    bi = rand_items(11, 16, tag=2)
+def test_stream_decoder_matches_batch(rng):
+    common = rand_items(rng, 300, 16, tag=0)
+    ai = rand_items(rng, 25, 16, tag=1)
+    bi = rand_items(rng, 11, 16, tag=2)
     A = Sketch.from_items(np.concatenate([common, ai]), 16)
     B = Sketch.from_items(np.concatenate([common, bi]), 16)
     dec = StreamDecoder(16, local=B)
@@ -167,13 +175,13 @@ def test_stream_decoder_matches_batch():
     assert only_a.shape[0] == 25 and only_b.shape[0] == 11
 
 
-def test_overhead_band_small_d():
+def test_overhead_band_small_d(rng):
     """Paper Fig. 4: average overhead ≤ ~1.72 at the worst d (≈4), with
     slack for variance at small sample counts."""
     trials, d = 40, 8
     used = []
     for t in range(trials):
-        items = rand_items(d, 8)
+        items = rand_items(rng, d, 8)
         enc = Encoder(8)
         enc.add_items(items)
         m = d  # smallest prefix that could possibly decode has m >= d
@@ -186,9 +194,9 @@ def test_overhead_band_small_d():
     assert 1.0 <= avg < 2.3, f"overhead {avg}"
 
 
-def test_wire_roundtrip():
+def test_wire_roundtrip(rng):
     from repro.core.wire import decode_stream, encode_stream
-    items = rand_items(500, 20)
+    items = rand_items(rng, 500, 20)
     sym = encode(items, 20, 128)
     blob = encode_stream(sym)
     back, n = decode_stream(blob)

@@ -13,8 +13,9 @@ from repro.core.decoder import resolve_backend
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.stream import StreamDecoder
 from repro.core.symbols import CodedSymbols
-from repro.kernels.ops import (decode_device, device_symbols_to_host,
-                               host_symbols_to_device)
+from repro.core.mapping import map_seeds
+from repro.kernels.ops import (decode_device, decode_device_batched,
+                               device_symbols_to_host, host_symbols_to_device)
 
 RNG = np.random.default_rng(2025)
 
@@ -137,6 +138,24 @@ def test_stream_decoder_device_incremental_windows():
     da, db = dev_dec.result()
     assert {r.tobytes() for r in ha} == {r.tobytes() for r in da}
     assert {r.tobytes() for r in hb} == {r.tobytes() for r in db}
+
+
+@pytest.mark.parametrize("path", ["lone_ref", "lone_pallas", "batched"])
+def test_device_results_carry_the_map_seeds(path):
+    """Every recovered row comes back with the mapping seed the wave
+    derived its chain from, the one the host's ``map_seeds`` gives it, so
+    merging a result hashes no row on the host."""
+    L = 2
+    sym, ai, bi = diff_symbols(9, 6, L, 64)
+    if path == "batched":
+        (res,) = decode_device_batched([sym], nbytes=4 * L)
+    else:
+        res = decode_device(*host_symbols_to_device(sym), nbytes=4 * L,
+                            kernel=path.split("_")[1], interpret=True)
+    assert res.success and res.items.shape[0] == 15
+    assert res.seeds.dtype == np.uint64
+    np.testing.assert_array_equal(res.seeds,
+                                  map_seeds(res.items, DEFAULT_KEY, 4 * L))
 
 
 # --------------------------------------------------- layout round-trip ----

@@ -258,8 +258,9 @@ def test_engine_mixes_plain_and_sharded_peers():
 def test_device_counters_add_up(monkeypatch, entry):
     """The reports' ``transfer_bytes`` sum to the bytes of every array
     staged to and fetched from the device (a batched bucket's padding
-    unit included), and their ``device_waves`` to the waves of the decode
-    results: three pipelined peers in one padded bucket, or one lone
+    unit included), their ``device_waves`` to the waves of the decode
+    results, and their ``device_symbols`` to each decode's tile-padded
+    prefix: three pipelined peers in one padded bucket, or one lone
     session on the non-pipelined path."""
     import jax
     from repro.kernels import ops
@@ -304,3 +305,5 @@ def test_device_counters_add_up(monkeypatch, entry):
         sum(r.rounds for r in results.values()) > 0
     for r in reports:
         assert r.device_waves >= r.device_decodes > 0
+        # every decode here lands in the first tile bucket, mp = 256
+        assert r.device_symbols == 256 * r.device_decodes

@@ -8,6 +8,7 @@ import pytest
 from repro.core.encoder import Encoder
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.mapping import kmax
+from repro.kernels import iblt_dense
 from repro.kernels.iblt_dense import iblt_apply_dense
 from repro.kernels.ops import host_symbols_to_device
 from repro.kernels.peel import peel_waves, peel_waves_batched
@@ -46,11 +47,9 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("L", [2, 23])
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_dense_apply_equals_oracle(case, L):
+def _check_dense(case, L, n=128):
     m, m_chain, sides, drop = CASES[case]
-    items, idxs, chks, side = _rows(128, L, m_chain, sides)
+    items, idxs, chks, side = _rows(n, L, m_chain, sides)
     if drop:
         idxs = jnp.where(side[:, None] != 0, idxs, jnp.int32(m))
     # m is traced: one compiled program for every prefix in the bucket
@@ -63,6 +62,26 @@ def test_dense_apply_equals_oracle(case, L):
     else:
         assert np.asarray(got[2]).any()      # the case removes something
     assert not any(np.asarray(g)[m:].any() for g in got)
+
+
+@pytest.mark.parametrize("L", [2, 23])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_dense_apply_equals_oracle(case, L):
+    _check_dense(case, L)
+
+
+@pytest.mark.parametrize("L", [7, 8, 21])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_tiled_apply_equals_oracle(monkeypatch, case, L):
+    """Rows wider than a tile go one word tile at a time: below one tile
+    (one product), exactly one, and two whole tiles and a partial one."""
+    monkeypatch.setattr(iblt_dense, "TILE_WORDS", 8)
+    _check_dense(case, L)
+
+
+def test_tiled_apply_equals_oracle_at_the_real_tile():
+    """Four whole tiles of the real width and a partial fifth."""
+    _check_dense("past_m", 4 * iblt_dense.TILE_WORDS + 5, n=32)
 
 
 @pytest.mark.parametrize("L", [2, 23])

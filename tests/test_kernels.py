@@ -37,8 +37,8 @@ def rand_items(n, L):
 def test_map_indices_kernel_vs_ref(K):
     L, block_n = 2, 64
     items = jnp.asarray(rand_items(block_n, L))
-    ki, kc = map_indices(items, K=K, m=256, nbytes=4 * L, key=DEFAULT_KEY,
-                         block_n=block_n)
+    ki, kc, _ = map_indices(items, K=K, m=256, nbytes=4 * L,
+                            key=DEFAULT_KEY, block_n=block_n)
     ri, rc = map_indices_ref(items, K=K, m=256, nbytes=4 * L, key=DEFAULT_KEY)
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))
     np.testing.assert_array_equal(np.asarray(kc), np.asarray(rc))
@@ -48,8 +48,8 @@ def test_map_indices_kernel_vs_ref(K):
 @pytest.mark.parametrize("L,block_n,K", [(3, 64, 8), (4, 64, 8), (8, 128, 6)])
 def test_map_indices_kernel_vs_ref_wide(L, block_n, K):
     items = jnp.asarray(rand_items(block_n * 2, L))
-    ki, kc = map_indices(items, K=K, m=256, nbytes=4 * L, key=DEFAULT_KEY,
-                         block_n=block_n)
+    ki, kc, _ = map_indices(items, K=K, m=256, nbytes=4 * L,
+                            key=DEFAULT_KEY, block_n=block_n)
     ri, rc = map_indices_ref(items, K=K, m=256, nbytes=4 * L, key=DEFAULT_KEY)
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))
     np.testing.assert_array_equal(np.asarray(kc), np.asarray(rc))
@@ -59,9 +59,12 @@ def test_map_indices_kernel_vs_host_chain():
     """Kernel indices == exact host (numpy uint64) chains."""
     L, n, m, K = 2, 64, 64, 8
     items = rand_items(n, L)
-    ki, _ = map_indices(jnp.asarray(items), K=K, m=m, nbytes=4 * L,
-                        key=DEFAULT_KEY, block_n=64)
+    ki, _, ks = map_indices(jnp.asarray(items), K=K, m=m, nbytes=4 * L,
+                            key=DEFAULT_KEY, block_n=64)
     seeds = map_seeds(items, DEFAULT_KEY, 4 * L)
+    ks = np.asarray(ks).astype(np.uint64)
+    np.testing.assert_array_equal((ks[:, 0] << np.uint64(32)) | ks[:, 1],
+                                  seeds)
     hm = indices_matrix_np(seeds, m, K=K)
     # host chains saturate at pad=m exactly like the kernel
     np.testing.assert_array_equal(

@@ -32,6 +32,12 @@ KEY = tuple(DEFAULT_KEY)
 # removed item's K chain slots it replaced took 3.46 GB, and would fail
 # this budget)
 BATCHED_TEMP_BUDGET = 256e6
+# temporaries of the batched decode of 128-KiB rows (EIP-4844 blobs,
+# L=32,768 words) at U=8, mp=256, max_diff=256: the state, the recovered
+# rows and a wave's candidate rows are 256 MiB each, and the engine keeps
+# two buckets in flight (measured 808.6 MB with the tiled chain removal;
+# the untiled one needed more than the chip's 16 GB)
+WIDE_TEMP_BUDGET = 2 * 2**30
 
 
 @pytest.fixture(scope="module")
@@ -120,3 +126,21 @@ def test_batched_peel_program_fits(one_chip):
         _shape(one_chip, (8,), jnp.int32)).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < BATCHED_TEMP_BUDGET, temp
+
+
+def test_batched_peel_program_fits_wide_rows(one_chip):
+    """The engine's batched decode of 8 units of 131,072-byte rows, with an
+    O(L) hash and chain removal one word tile at a time."""
+    mp, L = 256, 32768
+
+    def decode(sums, checks, counts, m):
+        return peel_waves_batched(sums, checks, counts, m=m, nbytes=4 * L,
+                                  key=KEY, max_diff=256, K=kmax(mp),
+                                  use_while_loop=True)
+    compiled = jax.jit(decode).lower(
+        _shape(one_chip, (8, mp, L), jnp.uint32),
+        _shape(one_chip, (8, mp, 2), jnp.uint32),
+        _shape(one_chip, (8, mp, 1), jnp.int32),
+        _shape(one_chip, (8,), jnp.int32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < WIDE_TEMP_BUDGET, temp
