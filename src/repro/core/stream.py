@@ -12,6 +12,12 @@ residual prefix goes to the device, recovered items and the peeled residual
 come back, and the host keeps only the chain bookkeeping that extends
 recovered items into future windows.  Both engines maintain the identical
 ``work``/recovered state, so the backend can be switched between windows.
+
+Through the protocol engine's batched decode the peeled residual stays on
+the device between decodes: ``work`` is a :class:`~repro.core.symbols.
+Residual` whose leading rows are the device's and whose tail, the rows
+absorbed since, is the host's; a host peel first copies the device's rows
+back (:meth:`StreamDecoder.to_host`).
 """
 from __future__ import annotations
 
@@ -21,7 +27,7 @@ from .decoder import resolve_backend
 from .encoder import Encoder, _xor_accumulate
 from .hashing import DEFAULT_KEY, siphash24
 from .mapping import map_seeds, walk_chains
-from .symbols import CodedSymbols
+from .symbols import CodedSymbols, Residual
 
 
 class StreamDecoder:
@@ -43,7 +49,7 @@ class StreamDecoder:
         self.local = local
         self.backend = resolve_backend(backend)
         self.max_diff = max_diff
-        self.work = CodedSymbols.zeros(0, nbytes)
+        self.work = Residual(CodedSymbols.zeros(0, nbytes))
         self.rec_items = np.zeros((0, (nbytes + 3) // 4), np.uint32)
         self.rec_hashes = np.zeros(0, np.uint64)
         self.rec_sides = np.zeros(0, np.int8)
@@ -56,9 +62,7 @@ class StreamDecoder:
     # ------------------------------------------------------------------
     @property
     def decoded(self) -> bool:
-        if self.work.m == 0:
-            return False
-        return bool(self.work.is_empty()[0])
+        return bool(self.work.decoded())
 
     def receive(self, sym: CodedSymbols) -> bool:
         """Feed symbols [m, m+sym.m) of A's stream.  Returns `decoded`."""
@@ -83,9 +87,11 @@ class StreamDecoder:
         """
         old = self.work.m
         if self.local is not None:
-            loc = self.local.window(old, old + sym.m)
-            sym = sym.subtract(loc)
-        self.work = self.work.concat(sym.copy())
+            sym = sym.subtract(self.local.window(old, old + sym.m))
+        else:
+            sym = sym.copy()
+        host = self.work.host
+        self.work.host = host.concat(sym) if host.m else sym
         self.symbols_received = self.work.m
         m = self.work.m
         # extend recovered items' chains through the new rows
@@ -97,7 +103,21 @@ class StreamDecoder:
         """Host-peel rows [old, m) of the residual (plus whatever their
         removals touch) — the exact engine, also the per-shard overflow
         fallback of the batched device path."""
+        self.to_host()
         self._peel(np.arange(old, m, dtype=np.int64))
+
+    def to_host(self) -> int:
+        """Copy the residual's device rows back, so that the host holds the
+        whole prefix again; returns the bytes fetched (0 if it did)."""
+        w = self.work
+        if not w.base:
+            return 0
+        if w.device is None:
+            raise RuntimeError("the residual's device rows were released "
+                               "when the decoder settled")
+        rows, fetched = w.device.fetch()
+        self.work = Residual(rows.concat(w.host))
+        return fetched
 
     def mark_decoded(self, at: int | None = None) -> bool:
         """Record the ρ(0)=1 termination point once; returns ``decoded``.
@@ -112,29 +132,34 @@ class StreamDecoder:
         if done and self.decoded_at is None:
             self.decoded_at = self.symbols_received if at is None \
                 else min(at, self.symbols_received)
+        if done:
+            self.work.device = None   # settled: release the device's rows
         return done
 
     # ------------------------------------------------------------------
     def _walk(self, items, hashes, sides, nxt, state, hi):
+        # chains reach only rows at or past the host's first row
+        host, base = self.work.host, self.work.base
+
         def remove(live, idx):
-            _xor_accumulate(self.work.sums, self.work.checks,
-                            self.work.counts, idx, items[live], hashes[live],
-                            -sides[live].astype(np.int64))
+            _xor_accumulate(host.sums, host.checks, host.counts, idx,
+                            items[live], hashes[live],
+                            -sides[live].astype(np.int64), base)
 
         return walk_chains(nxt, state, hi, remove)
 
     def _peel(self, cand: np.ndarray) -> None:
-        m = self.work.m
+        work = self.work.host       # the whole prefix (``to_host``)
+        m = work.m
         while cand.size:
             cand = np.unique(cand)
-            h = siphash24(self.work.sums[cand], self.key, self.nbytes)
-            pure = cand[(h == self.work.checks[cand]) &
-                        (self.work.counts[cand] != 0)]
+            h = siphash24(work.sums[cand], self.key, self.nbytes)
+            pure = cand[(h == work.checks[cand]) & (work.counts[cand] != 0)]
             if pure.size == 0:
                 return
-            items = self.work.sums[pure]
-            hashes = self.work.checks[pure]
-            sides = np.sign(self.work.counts[pure]).astype(np.int8)
+            items = work.sums[pure]
+            hashes = work.checks[pure]
+            sides = np.sign(work.counts[pure]).astype(np.int8)
             _, first = np.unique(hashes, return_index=True)
             items, hashes, sides = items[first], hashes[first], sides[first]
             fresh = ~np.isin(hashes, self.rec_hashes)
@@ -162,13 +187,14 @@ class StreamDecoder:
         exact host peel for this window.
         """
         from repro.kernels.ops import decode_device, host_symbols_to_device
-        res = decode_device(*host_symbols_to_device(self.work),
+        self.to_host()
+        res = decode_device(*host_symbols_to_device(self.work.host),
                             nbytes=self.nbytes, key=self.key,
                             max_diff=self.max_diff)
         if res.overflow:
             self.peel_window(old, m)
             return
-        self.merge_device_result(res)
+        self.merge_device_result(res._replace(residual=Residual(res.residual)))
 
     def merge_device_result(self, res) -> None:
         """Fold a successful :func:`repro.kernels.ops.decode_device` (or one
@@ -180,6 +206,10 @@ class StreamDecoder:
         False — overflowed decodes leave state untouched and the caller
         falls back to :meth:`peel_window`.
 
+        ``res.residual`` is a :class:`~repro.core.symbols.Residual`: host
+        symbols (a lone decode) or the rows a batched decode left on the
+        device, in which case the host drops the rows it had staged.
+
         Tail-aware: the decode may cover only a *prefix* of the current
         ``work`` (``res.residual.m ≤ work.m``) — a pipelined engine absorbs
         the next window while the device result is still in flight.  The
@@ -188,14 +218,15 @@ class StreamDecoder:
         exactly as :meth:`absorb` does for previously recovered items.
         """
         assert not res.overflow
+        got = res.residual
+        m0 = got.m
+        assert self.work.base <= m0 <= self.work.m
+        tail = self.work.host.window(m0 - self.work.base)
+        host = got.host.concat(tail) if got.host.m and tail.m else \
+            got.host if got.host.m else tail
+        self.work = Residual(host, got.base, got.device, got.row0_empty)
         if res.items.shape[0] == 0:
             return
-        m0 = res.residual.m
-        assert m0 <= self.work.m
-        if m0 < self.work.m:
-            self.work = res.residual.concat(self.work.window(m0))
-        else:
-            self.work = res.residual
         nxt = np.zeros(res.items.shape[0], np.int64)
         state = res.seeds.copy()   # the device hashed them as it peeled
         walk_chains(nxt, state, m0)  # position each chain at first idx >= m0

@@ -85,3 +85,47 @@ class CodedSymbols:
         (§6): sum (ℓ) + checksum (8) + ~1 byte amortized varint count."""
         from .wire import varint_count_bytes
         return self.m * (self.nbytes + 8) + varint_count_bytes(self.counts)
+
+
+@dataclasses.dataclass
+class Residual:
+    """A decoder's residual prefix [0, m), split by where its rows live.
+
+    Rows [0, ``base``) stay on the device, as the last batched decode left
+    them (``device``: a handle whose ``fetch()`` returns them as host
+    :class:`CodedSymbols` and the bytes it copied); rows [``base``, m) are
+    on the host (``host``: the rows absorbed since).  ``base`` is 0 while
+    the host holds the whole prefix.  ``row0_empty`` is the device's reading
+    of row 0 after that decode; the host's own row 0 is read while
+    ``base`` is 0.  A settled decoder drops its handle: ``device`` is then
+    None with ``base`` still set.
+    """
+    host: CodedSymbols
+    base: int = 0
+    device: object = None
+    row0_empty: bool = False
+
+    @property
+    def m(self) -> int:
+        return self.base + self.host.m
+
+    @property
+    def L(self) -> int:
+        return self.host.L
+
+    @property
+    def nbytes(self) -> int:
+        return self.host.nbytes
+
+    def copy(self) -> "Residual":
+        """The host rows copied; the device rows, immutable, are shared."""
+        return dataclasses.replace(self, host=self.host.copy())
+
+    def decoded(self) -> bool:
+        """Row 0 is empty (counts, checksum and sum all zero): the ρ(0)=1
+        termination signal.  False for an empty prefix."""
+        if self.base:
+            return self.row0_empty
+        h = self.host
+        return h.m > 0 and h.counts[0] == 0 and h.checks[0] == 0 and \
+            not h.sums[0].any()

@@ -243,66 +243,180 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
             _pairs64(state.rec_seeds[:n_rec]))
 
 
+# Bytes of recovered rows per chunk that crosses device→host (about 2 MiB).
+CHUNK_BYTES = 2 << 20
+# uint32 columns a packed recovered row carries past the item's L words:
+# its checksum (hi, lo), its map seed (hi, lo) and its side
+PACKED_EXTRA = 5
+
+
+class DeviceRows(NamedTuple):
+    """Rows [0, m) of one unit's residual, left on the device by a batched
+    decode: unit ``row`` of the bucket's output ``arrays`` (sums, checks,
+    counts, each with the leading unit axis), shared by the bucket's
+    units and freed when the last of them lets go."""
+    arrays: tuple
+    row: int
+    m: int
+    nbytes: int
+
+    def fetch(self):
+        """Copy the rows to the host: ``(CodedSymbols, bytes fetched)``."""
+        (sums, checks, counts), got = _fetch(
+            tuple(a[self.row, :self.m] for a in self.arrays))
+        return device_symbols_to_host(sums, checks, counts[:, 0],
+                                      self.nbytes), got
+
+
+def chunk_rows(L: int, rows: int, chunk_bytes: int) -> int:
+    """Recovered rows per fetched chunk: about ``chunk_bytes`` of packed
+    rows, at most ``rows`` (one unit's buffer)."""
+    return max(1, min(rows, -(-chunk_bytes // (4 * (L + PACKED_EXTRA)))))
+
+
+def stage_batched(sources, tails, layout, *, mp: int):
+    """Build a bucket's decode input on the device.
+
+    ``layout`` is ``(Up, 5)`` int32, one row per unit: the index of its
+    source in ``sources`` (-1: none), its row there, ``base`` (the rows
+    [0, base) it keeps from that source), the offset of its own rows in
+    ``tails`` and its prefix length ``m``.  Each source is a previous
+    bucket's (sums, checks, counts); ``tails`` holds every unit's rows
+    [base, m), concatenated.  Rows at and past ``m`` are zero.  Returns
+    (sums (Up, mp, L), checks (Up, mp, 2), counts (Up, mp, 1), m (Up,)).
+    """
+    src, row, base, start, m = (layout[:, j] for j in range(5))
+    i = jnp.arange(mp)[None, :]
+    held = i < base[:, None]
+    new = ~held & (i < m[:, None])
+    R = tails[0].shape[0]
+    if R:
+        at = jnp.where(new, start[:, None] + i - base[:, None], R)
+        out = [jnp.take(t, at, axis=0, mode="fill", fill_value=0)
+               for t in tails]
+    else:
+        out = [jnp.zeros((layout.shape[0], mp) + t.shape[1:], t.dtype)
+               for t in tails]
+    for k, arrays in enumerate(sources):
+        keep = (held & (src == k)[:, None])[..., None]
+        r = jnp.clip(row, 0, arrays[0].shape[0] - 1)
+        for f, a in enumerate(arrays):
+            a = a[r, :mp]
+            if a.shape[1] < mp:
+                a = jnp.pad(a, ((0, 0), (0, mp - a.shape[1]), (0, 0)))
+            out[f] = jnp.where(keep, a, out[f])
+    return (*out, m)
+
+
+def unstage_batched(state, success, *, chunk_bytes: int):
+    """What the host reads of a batched decode: ``(flags, chunks)``.
+
+    ``flags`` is ``(Up, 5)`` int32 per unit: ``n_rec``, overflow, success,
+    waves, and whether residual row 0 is empty.  ``chunks`` splits the
+    units' recovered rows, packed (:data:`PACKED_EXTRA`) and laid end to
+    end in unit order, into :func:`chunk_rows`-row arrays, each at most
+    one unit's buffer: the host reads the first with the flags and the
+    others only where they hold rows.
+    """
+    Up, D, L = state.rec_items.shape
+    n = state.n_rec
+    row0 = (state.counts[:, 0, 0] == 0) & (state.checks[:, 0, 0] == 0) & \
+        (state.checks[:, 0, 1] == 0) & jnp.all(state.sums[:, 0] == 0, axis=1)
+    flags = jnp.stack([n, state.overflow, success, state.rounds, row0],
+                      axis=1).astype(jnp.int32)
+    packed = jnp.concatenate([
+        state.rec_items, state.rec_checks, state.rec_seeds,
+        jax.lax.bitcast_convert_type(state.rec_sides, jnp.uint32)[..., None]],
+        axis=-1)
+    C = chunk_rows(L, D, chunk_bytes)
+    n_chunks = -(-(Up * D) // C)
+    ends = jnp.cumsum(n)
+    r = jnp.arange(n_chunks * C)
+    unit = jnp.searchsorted(ends, r, side="right")
+    u = jnp.minimum(unit, Up - 1)
+    j = jnp.clip(r - ends[u] + n[u], 0, D - 1)
+    rows = jnp.where((unit < Up)[:, None], packed[u, j], jnp.uint32(0))
+    return flags, tuple(rows[c * C:(c + 1) * C] for c in range(n_chunks))
+
+
+_stage_program = jax.jit(stage_batched, static_argnames="mp")
+_unstage_program = jax.jit(unstage_batched, static_argnames="chunk_bytes")
+
+
 class PendingBatchedDecode:
     """An in-flight :func:`decode_device_batched_start` dispatch.
 
-    Holds the device-resident :class:`~repro.kernels.peel.PeelState` (with
-    its leading unit axis) before host materialization.  ``ready()`` polls
-    the underlying JAX arrays non-blockingly — on TPU the whole wave loop
-    is one async dispatch, so a caller can overlap host work (e.g. frame
-    ingest for the next round) with the decode and only then ``wait()``.
-    On CPU the Python wave loop has already run by construction and
-    ``ready()`` is immediately True.
+    Holds the device's per-unit flags, the chunks of recovered rows and
+    the bucket's output residual (the arrays each unit's
+    :class:`DeviceRows` keeps) before host materialization.  ``ready()``
+    polls the underlying JAX arrays non-blockingly — on TPU the whole wave
+    loop is one async dispatch, so a caller can overlap host work (e.g.
+    frame ingest for the next round) with the decode and only then
+    ``wait()``.  On CPU the Python wave loop has already run by
+    construction and ``ready()`` is immediately True.
     """
 
-    __slots__ = ("_state", "_success", "_ms", "_nbytes", "_results",
-                 "_sent")
+    __slots__ = ("_flags", "_chunks", "_ms", "_nbytes", "_results",
+                 "_sent", "_arrays")
 
-    def __init__(self, state, success, ms, nbytes, results=None, sent=0):
-        self._state = state
-        self._success = success
+    def __init__(self, flags, chunks, ms, nbytes, results=None, sent=0,
+                 arrays=None):
+        self._flags = flags
+        self._chunks = chunks
         self._ms = ms
         self._nbytes = nbytes
         self._results = results
         self._sent = sent
+        self._arrays = arrays
 
     def ready(self) -> bool:
         """Non-blocking: True once the device results can be read without
         stalling (always True for trivially-empty or materialized work)."""
         if self._results is not None:
             return True
-        is_ready = getattr(self._success, "is_ready", None)
+        is_ready = getattr(self._flags, "is_ready", None)
         return bool(is_ready()) if callable(is_ready) else True
 
     def wait(self) -> list[DeviceDecodeResult]:
         """Materialize (blocking) — one result per input unit, in order.
 
+        Copies back the flags and the first chunk of recovered rows in one
+        read, and the chunks after it, in one more, only where the units
+        recovered more rows than a chunk holds; the residual stays on the
+        device, each unit's as the :class:`DeviceRows` of its result's
+        ``residual``.
         The bucket's bytes, staged and fetched (padding units included),
         are split evenly over its units' ``transfer_bytes``, so they sum
         to the bucket's total."""
         if self._results is not None:
             return self._results
+        from repro.core.symbols import CodedSymbols, Residual
         ms, nbytes = self._ms, self._nbytes
         with span(WAIT):
-            (state, success), got = _fetch((self._state, self._success))
+            (flags, first), got = _fetch((self._flags, self._chunks[0]))
+            n = flags[:len(ms), 0]
+            k = -(-int(n.sum()) // first.shape[0])
+            rest, got_rest = _fetch(self._chunks[1:k]) if k > 1 else ((), 0)
         with span(UNSTAGE):
-            total = self._sent + got
-            out = []
+            total = self._sent + got + got_rest
+            L = first.shape[1] - PACKED_EXTRA
+            rows = np.concatenate((first, *rest)) if rest else first
+            out, at = [], 0
             for s, m_s in enumerate(ms):
-                n_rec = int(state.n_rec[s])
-                residual = device_symbols_to_host(
-                    state.sums[s, :m_s], state.checks[s, :m_s],
-                    state.counts[s, :m_s, 0], nbytes)
+                r = rows[at:at + n[s]]
+                at += n[s]
+                residual = Residual(CodedSymbols.zeros(0, nbytes), m_s,
+                                    DeviceRows(self._arrays, s, m_s, nbytes),
+                                    bool(flags[s, 4]))
                 out.append(DeviceDecodeResult(
-                    state.rec_items[s, :n_rec].copy(),
-                    _pairs64(state.rec_checks[s, :n_rec]),
-                    state.rec_sides[s, :n_rec].astype(np.int8),
-                    bool(success[s]), bool(state.overflow[s]),
-                    int(state.rounds[s]), residual,
-                    total // len(ms) + (s < total % len(ms)),
-                    _pairs64(state.rec_seeds[s, :n_rec])))
+                    r[:, :L], _pairs64(r[:, L:L + 2]),
+                    r[:, L + 4].view(np.int32).astype(np.int8),
+                    bool(flags[s, 2]), bool(flags[s, 1]), int(flags[s, 3]),
+                    residual, total // len(ms) + (s < total % len(ms)),
+                    _pairs64(r[:, L + 2:L + 4])))
         self._results = out
-        self._state = self._success = None   # free device references
+        # free the device references but the residual the results keep
+        self._flags = self._chunks = self._arrays = None
         return out
 
 
@@ -314,16 +428,20 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
                                 ) -> PendingBatchedDecode:
     """Dispatch the batched wave decode of U units without materializing.
 
-    ``units`` is a sequence of host :class:`~repro.core.symbols.CodedSymbols`
-    — one ragged residual prefix per unit (the ``work`` buffers of U
-    decoders; a unit is a shard of one session or, through the protocol
-    engine, any peer×shard pair sharing this shape bucket).  Every unit is
-    padded to a single shared tile bucket
-    ``mp = ceil(max_u m_u / block_m) · block_m`` and the per-unit true
-    prefix lengths travel as a traced ``(U,)`` data vector into
+    ``units`` is a sequence of :class:`~repro.core.symbols.Residual` — one
+    ragged residual prefix per unit (the ``work`` of U decoders; a unit is a shard of one session
+    or, through the protocol engine, any peer×shard pair sharing this
+    shape bucket).  Only the rows the host holds are copied up, in one
+    array for the bucket; the rows an earlier decode left on the device
+    are taken where they are, and one small program
+    (:func:`stage_batched`) lays out every unit in a single shared tile
+    bucket ``mp = ceil(max_u m_u / block_m) · block_m``.  The per-unit
+    true prefix lengths travel as a traced ``(U,)`` data vector into
     :func:`repro.kernels.peel.peel_waves_batched`, which ``vmap``s the wave
     engine over the unit axis: one compiled program, one dispatch per wave
     (or one total under ``lax.while_loop`` on TPU), regardless of U.
+    Another small program (:func:`unstage_batched`) packs what the host
+    reads back.
 
     ``max_diff`` bounds each unit's fixed recovered-item buffer
     *individually*; a unit that trips it freezes only itself and comes
@@ -339,28 +457,30 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     compiled program instead of recompiling per departure.
 
     Returns a :class:`PendingBatchedDecode`; ``wait()`` yields one
-    :class:`DeviceDecodeResult` per unit, in input order.
+    :class:`DeviceDecodeResult` per unit, in input order, whose
+    ``residual`` is a :class:`~repro.core.symbols.Residual` held on the
+    device.
     """
     # the batched stages are the same everywhere; ``interpret``
     # only picks one staged program (compiled platforms) or a Python loop
     _, interpret = _engine(None, interpret)
-    from repro.core.symbols import CodedSymbols
+    from repro.core.symbols import CodedSymbols, Residual
     U = len(units)
     if U == 0:
         return PendingBatchedDecode(None, None, (), nbytes, results=[])
-    ms = [sym.m for sym in units]
+    ms = [u.m for u in units]
     m_hi = max(ms)
     if m_hi == 0:
         L = units[0].L
         empty = DeviceDecodeResult(
             np.zeros((0, L), np.uint32), np.zeros(0, np.uint64),
             np.zeros(0, np.int8), True, False, 0,
-            CodedSymbols.zeros(0, nbytes))
+            Residual(CodedSymbols.zeros(0, nbytes)))
         return PendingBatchedDecode(None, None, ms, nbytes,
                                     results=[empty] * U)
     L = units[0].L
-    assert all(sym.L == L and sym.nbytes == units[0].nbytes
-               for sym in units), "units must share one item geometry"
+    assert all(u.L == L and u.nbytes == units[0].nbytes
+               for u in units), "units must share one item geometry"
     Up = max(U, pad_units) if pad_units else U
     mp = ((m_hi + block_m - 1) // block_m) * block_m
     if K is None:
@@ -368,24 +488,48 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     D = mp if max_diff is None else max(int(max_diff), 1)
 
     with span(STAGE, units=U, mp=mp):
-        sums = np.zeros((Up, mp, L), np.uint32)
-        checks = np.zeros((Up, mp, 2), np.uint32)
-        counts = np.zeros((Up, mp, 1), np.int32)
-        for s, sym in enumerate(units):
-            sums[s, : sym.m] = sym.sums
-            checks[s, : sym.m, 0] = \
-                (sym.checks >> np.uint64(32)).astype(np.uint32)
-            checks[s, : sym.m, 1] = (sym.checks &
-                                     np.uint64(0xFFFFFFFF)).astype(np.uint32)
-            counts[s, : sym.m, 0] = sym.counts.astype(np.int32)
-        (sums, checks, counts, m), sent = _stage(
-            sums, checks, counts, np.asarray(ms + [0] * (Up - U), np.int32))
+        # every unit's host rows, end to end in one fresh array a field,
+        # padded to a power of two rows: peers settling at different ticks
+        # then meet the few staging programs the first rounds compiled
+        R = sum(u.host.m for u in units)
+        Rp = 1 << (R - 1).bit_length() if R else 0
+        sums = np.empty((Rp, L), np.uint32)
+        checks = np.empty((Rp, 2), np.uint32)
+        counts = np.empty((Rp, 1), np.int32)
+        for a in (sums, checks, counts):
+            a[R:] = 0
+        sources, where = [], {}
+        layout = np.zeros((Up, 5), np.int32)
+        layout[:, 0] = -1
+        at = 0
+        for s, u in enumerate(units):
+            if u.base:
+                if u.device is None:
+                    raise ValueError("a settled unit's residual was released")
+                k = where.setdefault(id(u.device.arrays), len(sources))
+                if k == len(sources):
+                    sources.append(u.device.arrays)
+                layout[s, :3] = k, u.device.row, u.base
+            layout[s, 3:] = at, u.m
+            h, end = u.host, at + u.host.m
+            sums[at:end] = h.sums
+            checks[at:end, 0] = h.checks >> np.uint64(32)
+            checks[at:end, 1] = h.checks & np.uint64(0xFFFFFFFF)
+            counts[at:end, 0] = h.counts
+            at = end
+        (*tails, layout), sent = _stage(sums, checks, counts, layout)
+        *arrays, m = _stage_program(tuple(sources), tuple(tails), layout,
+                                    mp=mp)
         state, success = peel_waves_batched(
-            sums, checks, counts, m=m, nbytes=nbytes, key=key, max_diff=D,
-            K=K, max_rounds=max_rounds, use_while_loop=not interpret)
+            *arrays, m=m, nbytes=nbytes, key=key, max_diff=D, K=K,
+            max_rounds=max_rounds, use_while_loop=not interpret)
+        flags, chunks = _unstage_program(state, success,
+                                         chunk_bytes=CHUNK_BYTES)
     # wait() materializes per entry of ms (length U): dummy pad units past
     # U are simply never read back
-    return PendingBatchedDecode(state, success, ms, nbytes, sent=sent)
+    return PendingBatchedDecode(
+        flags, chunks, ms, nbytes, sent=sent,
+        arrays=(state.sums, state.checks, state.counts))
 
 
 def decode_device_batched(units, *, nbytes: int, key=DEFAULT_KEY,

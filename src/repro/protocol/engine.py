@@ -53,6 +53,7 @@ from typing import NamedTuple
 
 from repro.core.decoder import resolve_backend
 from repro.core.stream import StreamDecoder
+from repro.core.symbols import Residual
 from repro.core.wire import decode_frames, decode_shard_frames
 from repro.trace import (ABSORB, HOST_PEEL, MERGE, PLAN, REPORT, SERVE, TICK,
                          WIRE_DECODE, span)
@@ -111,7 +112,10 @@ class PeerState:
         self.overflows = 0
         # device work of those decodes: peel waves run, tile-padded symbols
         # (each decode's mp), and host<->device bytes staged and fetched
-        # (a batched bucket's split over its units)
+        # (a batched bucket's split over its units); resident_decodes
+        # counts the device decodes that found the unit's residual still
+        # on the device from its last decode and staged only the new rows
+        self.resident_decodes = 0
         self.device_waves = 0
         self.device_symbols = 0
         self.transfer_bytes = 0
@@ -342,9 +346,11 @@ class PendingRound:
 
 
 def _host_peel(du: DecodeUnit) -> None:
-    """Peel one unit's new rows on the exact host engine."""
+    """Peel one unit's new rows on the exact host engine, the residual's
+    device rows copied back first."""
     du.peer.host_decodes += 1
     with span(HOST_PEEL, peer=du.peer.index):
+        du.peer.transfer_bytes += du.unit.decoder.to_host()
         du.unit.decoder.peel_window(du.old, du.m)
         du.unit.decoder.mark_decoded(at=du.m)
 
@@ -367,29 +373,34 @@ def execute_round(units: list[DecodeUnit], block_m: int = 256,
     The unit axis is padded to the next power of two (``pad_units``): the
     unit count is a static shape in the per-bucket jit cache, so peers
     settling one by one re-use one compiled program instead of
-    recompiling per departure.  A lone plain session in sync mode skips
-    the batch entirely and takes :func:`~repro.kernels.ops.decode_device`
-    — the PR-2 path whose Pallas peel kernels serve single-peer decodes
-    on TPU."""
+    recompiling per departure.  A unit's residual stays on the device
+    from one batched decode to the next, and only the rows absorbed since
+    are staged.  A lone plain session in sync mode whose residual is on
+    the host skips the batch entirely and takes
+    :func:`~repro.kernels.ops.decode_device`, whose Pallas peel kernels
+    serve single-peer decodes on TPU."""
     from repro.kernels import ops
     plan = build_plan(units, block_m)
     for du in plan.host:
         _host_peel(du)
     dispatches = []
     for (mp, L, nbytes, key, D), us in plan.buckets.items():
-        for du in us:
-            du.peer.device_decodes += 1
-            du.peer.device_symbols += mp
         works = [du.unit.decoder.work for du in us]
+        for du, work in zip(us, works):
+            du.peer.device_decodes += 1
+            du.peer.resident_decodes += work.base > 0
+            du.peer.device_symbols += mp
         if pipeline:
             pending = ops.decode_device_batched_start(
                 works, nbytes=nbytes, key=key, max_diff=D, block_m=block_m,
                 pad_units=_next_pow2(len(us)))
-        elif len(us) == 1 and not us[0].peer.sharded:
+        elif len(us) == 1 and not us[0].peer.sharded and not works[0].base:
+            res = ops.decode_device(
+                *ops.host_symbols_to_device(works[0].host), nbytes=nbytes,
+                key=key, max_diff=us[0].peer.max_diff, block_m=block_m)
             pending = ops.PendingBatchedDecode(
-                None, None, (), nbytes, results=[ops.decode_device(
-                    *ops.host_symbols_to_device(works[0]), nbytes=nbytes,
-                    key=key, max_diff=us[0].peer.max_diff, block_m=block_m)])
+                None, None, (), nbytes,
+                results=[res._replace(residual=Residual(res.residual))])
         else:
             pending = ops.PendingBatchedDecode(
                 None, None, (), nbytes, results=ops.decode_device_batched(
@@ -518,7 +529,18 @@ class ReconcileEngine:
 
     def run(self) -> list:
         """Drive every registered peer to termination; returns reports in
-        registration order."""
+        registration order.  A settled unit lets go of its residual's
+        device rows; should the run raise, every unit still at work gets
+        its rows back on the host, so the device keeps none."""
+        try:
+            return self._run()
+        finally:
+            for entry in self._peers:
+                for u in entry.peer.units:
+                    if not u.decoder.decoded:
+                        entry.peer.transfer_bytes += u.decoder.to_host()
+
+    def _run(self) -> list:
         if not self.pipeline:
             while self.tick():
                 pass

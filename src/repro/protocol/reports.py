@@ -37,6 +37,9 @@ class ReportBase:
     device_waves: int = dataclasses.field(default=0, kw_only=True)
     device_symbols: int = dataclasses.field(default=0, kw_only=True)
     transfer_bytes: int = dataclasses.field(default=0, kw_only=True)
+    # device decodes that found the residual on the device from the
+    # unit's last decode and staged only the rows absorbed since
+    resident_decodes: int = dataclasses.field(default=0, kw_only=True)
 
     def only_remote_bytes(self) -> np.ndarray:
         """(r, ℓ) uint8 — remote-exclusive items as raw bytes."""
@@ -101,6 +104,7 @@ def build_session_report(peer) -> SessionReport:
 def _decode_counts(peer) -> dict:
     return dict(grow_steps=peer.grow_steps,
                 device_decodes=peer.device_decodes,
+                resident_decodes=peer.resident_decodes,
                 host_decodes=peer.host_decodes, overflows=peer.overflows,
                 device_waves=peer.device_waves,
                 device_symbols=peer.device_symbols,

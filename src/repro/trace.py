@@ -18,7 +18,7 @@ Spans, with their layer:
 * ``repro.wire.decode`` (codec): frame decoding on ingest;
 * ``repro.absorb`` (absorb): local subtract and chain walk of one round;
 * ``repro.plan`` (staging): bucketing a tick's units by shape;
-* ``repro.stage`` (staging): host padding, host→device copy, dispatch;
+* ``repro.stage`` (staging): the host's rows, host→device copy, dispatch;
 * ``repro.wait`` (staging): blocked on the device, device→host copies;
 * ``repro.unstage`` (staging): fetched arrays → ``DeviceDecodeResult``\\ s;
 * ``repro.merge`` (absorb): folding a peer's device results in;

@@ -12,7 +12,7 @@ from repro.core import Encoder, peel
 from repro.core.decoder import resolve_backend
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.stream import StreamDecoder
-from repro.core.symbols import CodedSymbols
+from repro.core.symbols import CodedSymbols, Residual
 from repro.core.mapping import map_seeds
 from repro.kernels.ops import (decode_device, decode_device_batched,
                                device_symbols_to_host, host_symbols_to_device)
@@ -148,7 +148,7 @@ def test_device_results_carry_the_map_seeds(path):
     L = 2
     sym, ai, bi = diff_symbols(9, 6, L, 64)
     if path == "batched":
-        (res,) = decode_device_batched([sym], nbytes=4 * L)
+        (res,) = decode_device_batched([Residual(sym)], nbytes=4 * L)
     else:
         res = decode_device(*host_symbols_to_device(sym), nbytes=4 * L,
                             kernel=path.split("_")[1], interpret=True)
@@ -176,3 +176,36 @@ def test_symbols_device_roundtrip_uint64_checks():
     np.testing.assert_array_equal(back.checks, sym.checks)
     np.testing.assert_array_equal(back.counts, sym.counts)
     assert back.sums.flags.writeable
+
+
+# ------------------------------------------------ resident staging ----
+def test_stage_batched_lays_out_units():
+    """The bucket's input built on the device: each unit keeps rows
+    [0, base) of its source (two sources of different buckets here), takes
+    its own rows [base, m) from the staged tails, and reads zero past m;
+    a unit with no source is staged whole, a padding unit is empty."""
+    from repro.kernels.ops import stage_batched
+    L, mp = 3, 32
+
+    def rows(*shape):
+        return RNG.integers(0, 2**32, size=shape + (L,), dtype=np.uint32)
+
+    src_a, src_b = rows(2, 8), rows(1, 16)
+    tails = rows(3 + 4 + 6)
+    # (source, row, base, tail offset, m) per unit
+    layout = np.array([[0, 1, 5, 0, 8], [1, 0, 10, 3, 14],
+                       [-1, 0, 0, 7, 6], [-1, 0, 0, 13, 0]], np.int32)
+
+    def fields(sums):
+        # checks and counts ride along: derive them from the sums
+        return (sums, sums[..., :2], sums[..., :1].astype(np.int32))
+
+    out = stage_batched((fields(src_a), fields(src_b)), fields(tails),
+                        layout, mp=mp)
+    want = np.zeros((4, mp, L), np.uint32)
+    want[0, :5], want[0, 5:8] = src_a[1, :5], tails[0:3]
+    want[1, :10], want[1, 10:14] = src_b[0, :10], tails[3:7]
+    want[2, :6] = tails[7:13]
+    for got, w in zip(out[:3], fields(want)):
+        np.testing.assert_array_equal(np.asarray(got), w)
+    np.testing.assert_array_equal(np.asarray(out[3]), [8, 14, 6, 0])

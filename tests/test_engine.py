@@ -261,13 +261,26 @@ def test_device_counters_add_up(monkeypatch, entry):
     unit included), their ``device_waves`` to the waves of the decode
     results, and their ``device_symbols`` to each decode's tile-padded
     prefix: three pipelined peers in one padded bucket, or one lone
-    session on the non-pipelined path."""
+    session on the non-pipelined path.  Every batched decode after a
+    unit's first finds its residual on the device (``resident_decodes``),
+    and the three peers move fewer bytes than staging and fetching each
+    bucket's whole residual alone would; the lone session stages whole."""
     import jax
     from repro.kernels import ops
     moved = [0]
     results = {}
+    restaged = [0]
+    reads, waits = [0], [0]
     put, get = jax.device_put, jax.device_get
     wait = ops.PendingBatchedDecode.wait
+    start = ops.decode_device_batched_start
+
+    def spy_start(units, **k):
+        # what staging and fetching the whole padded residual would move
+        up = max(len(units), k.get("pad_units") or 0)
+        mp = -(-max(u.m for u in units) // 256) * 256
+        restaged[0] += 2 * up * mp * (4 * units[0].L + 12)
+        return start(units, **k)
 
     def nbytes(tree):
         return sum(np.asarray(a).nbytes for a in jax.tree.leaves(tree))
@@ -279,15 +292,18 @@ def test_device_counters_add_up(monkeypatch, entry):
     def spy_get(x):
         out = get(x)
         moved[0] += nbytes(out)
+        reads[0] += 1
         return out
 
     def spy_wait(self):
+        waits[0] += self._results is None
         out = wait(self)
         results.update((id(r), r) for r in out)
         return out
     monkeypatch.setattr(jax, "device_put", spy_put)
     monkeypatch.setattr(jax, "device_get", spy_get)
     monkeypatch.setattr(ops.PendingBatchedDecode, "wait", spy_wait)
+    monkeypatch.setattr(ops, "decode_device_batched_start", spy_start)
 
     nbytes_ = 16
     state = rand_items(900, nbytes_, tag=0)
@@ -307,3 +323,168 @@ def test_device_counters_add_up(monkeypatch, entry):
         assert r.device_waves >= r.device_decodes > 0
         # every decode here lands in the first tile bucket, mp = 256
         assert r.device_symbols == 256 * r.device_decodes
+        assert r.resident_decodes == \
+            (r.device_decodes - 1 if entry == "engine" else 0)
+    if entry == "engine":
+        assert moved[0] < restaged[0]
+        # each bucket's recovered rows fit one chunk: one read a dispatch
+        assert reads[0] == waits[0] > 0
+
+
+# ------------------------------------------- the residual stays resident ----
+# case: (pipelined engine, shards, each peer's (lost, added), max_diff)
+RESIDENT_CASES = {
+    # a d = 0 peer settles on absorb, the others at different ticks: the
+    # batch shrinks through the padded unit counts 4, 2 and 1
+    "pipelined": (True, 1, [(0, 0), (8, 2), (60, 4), (200, 8)], None),
+    "sync": (False, 1, [(0, 0), (8, 2), (60, 4), (200, 8)], None),
+    "sharded": (False, 4, [(140, 20)], None),
+    # recovered rows cross in chunks of 16 rows, not one
+    "chunked": (True, 1, [(0, 0), (8, 2), (60, 4), (200, 8)], None),
+    # the deep peer recovers past max_diff in one decode mid-run: it takes
+    # its residual back from the device and peels on the host
+    "overflow": (True, 1, [(8, 2), (12, 2), (80, 8)], 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_resident_residual_decodes_exactly(monkeypatch, case):
+    """With each unit's residual kept on the device between decodes, every
+    peer recovers the numpy set difference exactly, and the same sets at
+    the same prefix as the exact host peel."""
+    from repro.kernels import ops
+    pipeline, n_shards, peers, max_diff = RESIDENT_CASES[case]
+    nbytes = 16
+    state = rand_items(1500, nbytes, tag=0)
+    replicas = [stale_replica(state, lost, added, nbytes)
+                for lost, added in peers]
+    reads = []
+    if case == "chunked":
+        import jax
+        monkeypatch.setattr(ops, "CHUNK_BYTES", 16 * 4 * (nbytes // 4 + 5))
+        get = jax.device_get
+        monkeypatch.setattr(jax, "device_get", lambda tree: reads.append(
+            len(jax.tree.leaves(tree))) or get(tree))
+    fetched = []
+    fetch = ops.DeviceRows.fetch
+    monkeypatch.setattr(ops.DeviceRows, "fetch",
+                        lambda rows: fetched.append(rows.m) or fetch(rows))
+
+    def run(backend):
+        if n_shards == 1:
+            stream = SymbolStream.from_items(state, nbytes)
+        else:
+            stream = ShardedStream.from_items(state, nbytes,
+                                              n_shards=n_shards)
+        engine = ReconcileEngine(pipeline=pipeline)
+        for items, *_ in replicas:
+            if n_shards == 1:
+                session = Session(local=Sketch.from_items(items, nbytes),
+                                  pacing=FixedBlock(16), backend=backend,
+                                  max_diff=max_diff, max_m=4096)
+            else:
+                session = stream.session(
+                    local=ShardedStream.from_items(items, nbytes,
+                                                   n_shards=n_shards),
+                    pacing=FixedBlock(8), backend=backend,
+                    max_diff=max_diff, max_m=4096)
+            engine.register(stream, session, wire=True)
+        return engine.run()
+
+    device, host = run("device"), run("host")
+    for dev, ref, (_, only_remote, only_local) in zip(device, host,
+                                                      replicas):
+        assert as_sorted_bytes(dev.only_remote_bytes()) == \
+            as_sorted_bytes(ref.only_remote_bytes()) == \
+            as_sorted_bytes(only_remote)
+        assert as_sorted_bytes(dev.only_local_bytes()) == \
+            as_sorted_bytes(ref.only_local_bytes()) == \
+            as_sorted_bytes(only_local)
+        assert dev.symbols_used == ref.symbols_used
+    assert 0 < sum(r.resident_decodes for r in device) < \
+        sum(r.device_decodes for r in device)
+    if case == "chunked":
+        # the flags and first chunk in one read, several chunks in another
+        assert max(reads) > 2
+    if case == "overflow":
+        assert [r.overflows > 0 for r in device] == [False, False, True]
+        assert device[2].resident_decodes > 0 and fetched
+    else:
+        assert sum(r.overflows + r.host_decodes for r in device) == 0
+        assert fetched == []
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half"])
+def test_planted_faults_reach_the_check(monkeypatch, fault):
+    """The benchmark's planted faults stand in for the batched decode
+    with resident residuals and show as what they are, not as a failing
+    stand-in: results that leave each residual as it came never let a
+    peer settle, so the round runs out of symbols; results of half the
+    batch handed to the other half settle every peer, some on another
+    peer's difference."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import control
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "decode_device_batched_start",
+                        control.plant(fault))
+    nbytes = 16
+    state = rand_items(900, nbytes, tag=0)
+    stream = SymbolStream.from_items(state, nbytes)
+    # same staleness, different extras: one bucket, different answers
+    replicas = [stale_replica(state, 24, 4, nbytes) for _ in range(4)]
+    engine = ReconcileEngine()
+    for items, *_ in replicas:
+        engine.register(stream, Session(local=Sketch.from_items(items, nbytes),
+                                        pacing=FixedBlock(16),
+                                        backend="device", max_m=1024),
+                        wire=True)
+    if fault == "unchanged":
+        with pytest.raises(RuntimeError, match="did not converge"):
+            engine.run()
+        return
+    reports = engine.run()
+    wrong = [as_sorted_bytes(r.only_local_bytes()) != as_sorted_bytes(extra)
+             for r, (_, _, extra) in zip(reports, replicas)]
+    assert wrong == [False, False, True, True]
+
+
+def test_second_round_compiles_nothing_and_keeps_no_buffer():
+    """A second engine round on the same shapes triggers no backend
+    compile, and once ``run()`` returns no device buffer of the round is
+    left alive: every settled unit let go of its residual."""
+    import gc
+
+    import jax
+    nbytes = 16
+    state = rand_items(900, nbytes, tag=0)
+    stream = SymbolStream.from_items(state, nbytes)
+
+    def round_():
+        # the sessions outlive the round, as a caller's would
+        sessions = [Session(local=Sketch.from_items(state[:-lost], nbytes),
+                            pacing=FixedBlock(16), backend="device")
+                    for lost in (20, 30, 40)]
+        return sessions, serve([(stream, s) for s in sessions])
+
+    first = round_()
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+    gc.collect()
+    before = len(jax.live_arrays())
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        sessions, reports = round_()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    gc.collect()
+    assert [r.only_remote.shape[0] for r in reports] == [20, 30, 40]
+    assert all(r.resident_decodes for r in reports) and first and sessions
+    assert compiles == []
+    assert len(jax.live_arrays()) == before

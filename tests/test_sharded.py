@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core import Encoder, Sketch
+from repro.core.symbols import Residual
 from repro.core.wire import (decode_shard_frames, encode_frames,
                              encode_shard_frames)
 from repro.protocol import (FixedBlock, ProtocolError, ShardedReport,
@@ -199,7 +200,8 @@ def test_decode_device_batched_overflow_is_per_shard():
         A.add_items(items)
         B.add_items(items[:-d])
         shards.append(A.symbols(m).subtract(B.symbols(m)))
-    res = decode_device_batched(shards, nbytes=nbytes, max_diff=8)
+    res = decode_device_batched([Residual(s) for s in shards], nbytes=nbytes,
+                                max_diff=8)
     assert res[0].success and not res[0].overflow
     assert res[0].items.shape[0] == 2
     assert res[1].overflow and not res[1].success

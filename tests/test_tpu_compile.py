@@ -22,7 +22,9 @@ from repro.core.mapping import kmax
 from repro.kernels.iblt_dense import iblt_apply_dense
 from repro.kernels.iblt_encode import iblt_apply
 from repro.kernels.map_indices import map_indices
-from repro.kernels.peel import peel_waves, peel_waves_batched, purity_scan
+from repro.kernels import ops
+from repro.kernels.peel import (PeelState, peel_waves, peel_waves_batched,
+                                purity_scan)
 
 MP = 2048                  # symbols: the tile bucket of a d≈1,000 decode
 K = kmax(16384)            # 65 chain slots
@@ -144,3 +146,34 @@ def test_batched_peel_program_fits_wide_rows(one_chip):
         _shape(one_chip, (8,), jnp.int32)).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < WIDE_TEMP_BUDGET, temp
+
+
+@pytest.mark.parametrize("L,mp,D,budget", [
+    (23, MP, 1024, BATCHED_TEMP_BUDGET), (32768, 256, 256, WIDE_TEMP_BUDGET)])
+def test_staging_programs_fit(one_chip, L, mp, D, budget):
+    """The programs around the batched decode of 8 units: the input laid
+    out from the last decode's residual (at half the bucket, or the same
+    one) and the new rows, and the read-back packed into chunks."""
+    U, prev = 8, max(mp // 2, 256)
+
+    def fields(lead, n):
+        return (_shape(one_chip, lead + (n, L), jnp.uint32),
+                _shape(one_chip, lead + (n, 2), jnp.uint32),
+                _shape(one_chip, lead + (n, 1), jnp.int32))
+    stage = ops._stage_program.lower(
+        (fields((U,), prev),), fields((), U * (mp - prev) or U * mp),
+        _shape(one_chip, (U, 5), jnp.int32), mp=mp).compile()
+    lead = (U,)
+    state = PeelState(
+        *fields(lead, mp), _shape(one_chip, (U, D, L), jnp.uint32),
+        _shape(one_chip, (U, D, 2), jnp.uint32),
+        _shape(one_chip, (U, D, 2), jnp.uint32),
+        _shape(one_chip, (U, D), jnp.int32), _shape(one_chip, lead, jnp.int32),
+        _shape(one_chip, lead, bool), _shape(one_chip, lead, bool),
+        _shape(one_chip, lead, jnp.int32))
+    unstage = ops._unstage_program.lower(
+        state, _shape(one_chip, lead, bool),
+        chunk_bytes=ops.CHUNK_BYTES).compile()
+    for compiled in (stage, unstage):
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < budget, temp

@@ -2,11 +2,14 @@
 of 16,384-byte rows (4,096 words, sixteen tiles of the chain removal) that
 differ by adds and drops reconcile in one engine round on the device,
 both sides exact by the benchmark's plain reference, and no row is hashed
-on the host once the round has started."""
+on the host once the round has started.  Each replica's residual stays on
+the device from one decode to the next, on the pipelined engine and the
+synchronous one."""
 import sys
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
@@ -37,11 +40,12 @@ def _count_host_hashes(monkeypatch) -> list:
     return calls
 
 
-def test_engine_round_of_wide_rows(monkeypatch):
+@pytest.mark.parametrize("pipeline", [True, False])
+def test_engine_round_of_wide_rows(monkeypatch, pipeline):
     traffic = {"d": [24], "pool": 2}
     server, enc, pool = data.build(4844, CONFIG, traffic, n=200)
     stream = SymbolStream(enc)
-    engine = ReconcileEngine()
+    engine = ReconcileEngine(pipeline=pipeline)
     for rep in pool:
         engine.register(stream, Session(local=rep.encoder, backend="device",
                                         max_diff=64,
@@ -57,4 +61,6 @@ def test_engine_round_of_wide_rows(monkeypatch):
         assert reference.same_rows(report.only_remote, only_server, NBYTES)
         assert reference.same_rows(report.only_local, only_replica, NBYTES)
         assert report.host_decodes == report.overflows == 0
-        assert report.device_decodes > 0
+        assert report.device_decodes > 1
+        # every decode but the first staged only the rows absorbed since
+        assert report.resident_decodes == report.device_decodes - 1
