@@ -8,7 +8,7 @@ value); each replica drops d/2 of them and adds d/2 of its own.  Two phases
 run through the entry points users call:
 
 * ``single`` — one replica at d = 1,000 through ``run_session(..., wire=True)``
-  with ``backend="device", max_diff=1024``: the lone-session decode.
+  with ``backend="device", max_diff=1024``: one unit a decode.
 * ``engine`` — one default (pipelined) ``ReconcileEngine`` serving 8 plain
   replicas at d ∈ {0, 1, 10, 100, 250, 500, 1000, 1000} and one 8-shard
   ``ShardedSession`` at d = 1,000, all on the device with ``max_diff=1024``.
@@ -119,7 +119,6 @@ def _line(phase: str, **fields) -> str:
 def phase_single(rng, server: np.ndarray, stream, d: int) -> str:
     """One replica through ``run_session`` on the device backend."""
     from repro.core.encoder import Encoder
-    from repro.kernels.ops import peel_engine
     from repro.protocol import Session, run_session
 
     local = make_replica(rng, server, d)
@@ -131,7 +130,7 @@ def phase_single(rng, server: np.ndarray, stream, d: int) -> str:
                                              max_diff=MAX_DIFF), wire=True)
         wall = time.perf_counter() - t0
     check_report(report, server, local)
-    return _line("single", engine=peel_engine(), wall_s=wall,
+    return _line("single", wall_s=wall,
                  compiles=cc.compiles, compile_s=cc.seconds,
                  ticks=report.grow_steps, dispatches=report.device_decodes,
                  overflows=report.overflows, host_decodes=report.host_decodes,
@@ -144,7 +143,6 @@ def phase_engine(rng, server: np.ndarray, stream, sharded_stream, ds,
                  d_sharded: int) -> str:
     """Plain replicas and one sharded replica on one pipelined engine."""
     from repro.core.encoder import Encoder
-    from repro.kernels.ops import peel_engine
     from repro.protocol import (ReconcileEngine, Session, ShardedSession,
                                 ShardedStream)
 
@@ -169,7 +167,7 @@ def phase_engine(rng, server: np.ndarray, stream, sharded_stream, ds,
         wall = time.perf_counter() - t0
     for report, (_, local) in zip(reports, peers):
         check_report(report, server, local)
-    return _line("engine", engine=peel_engine(batched=True), wall_s=wall,
+    return _line("engine", wall_s=wall,
                  compiles=cc.compiles, compile_s=cc.seconds,
                  ticks=engine.ticks, dispatches=engine.dispatches,
                  overflows=sum(r.overflows for r in reports),

@@ -8,8 +8,9 @@ the ρ(0)=1 property symbol 0 empties last, which is the stream-termination
 signal used by the incremental decoder.
 
 ``backend`` selects the peel engine: ``"host"`` (numpy, this module),
-``"device"`` (the :mod:`repro.kernels.peel` wave decoder — one jit program
-on TPU, pure-jnp engine on CPU), or ``"auto"`` (device iff a TPU backend is
+``"device"`` (:func:`repro.kernels.ops.decode_device`, one unit of the
+batched wave decoder — one dispatch under ``lax.while_loop`` on TPU, a
+Python wave loop elsewhere), or ``"auto"`` (device iff a TPU backend is
 present).  Both engines recover the identical difference; a device decode
 that overflows its fixed ``max_diff`` buffers falls back to the host path.
 """

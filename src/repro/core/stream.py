@@ -6,15 +6,15 @@ symbol 0 empties (ρ(0)=1 ⇒ it is decoded last).  Already-recovered items are
 XOR-ed out of newly arriving symbols by extending their mapping chains — the
 decoder mirror of the encoder's incrementality.
 
-With ``backend="device"`` the per-window peel runs through the
-:mod:`repro.kernels.peel` wave decoder instead of the numpy loop: the
-residual prefix goes to the device, recovered items and the peeled residual
-come back, and the host keeps only the chain bookkeeping that extends
-recovered items into future windows.  Both engines maintain the identical
-``work``/recovered state, so the backend can be switched between windows.
+With ``backend="device"`` the per-window peel runs through the batched
+device decode (:func:`repro.kernels.ops.decode_device_batched`, here of one
+unit) instead of the numpy loop: recovered items come back, and the host
+keeps only the chain bookkeeping that extends recovered items into future
+windows.  Both engines maintain the identical ``work``/recovered state, so
+the backend can be switched between windows.
 
-Through the protocol engine's batched decode the peeled residual stays on
-the device between decodes: ``work`` is a :class:`~repro.core.symbols.
+The peeled residual stays on the device between decodes, this decoder's
+own or the protocol engine's: ``work`` is a :class:`~repro.core.symbols.
 Residual` whose leading rows are the device's and whose tail, the rows
 absorbed since, is the host's; a host peel first copies the device's rows
 back (:meth:`StreamDecoder.to_host`).
@@ -132,8 +132,6 @@ class StreamDecoder:
         if done and self.decoded_at is None:
             self.decoded_at = self.symbols_received if at is None \
                 else min(at, self.symbols_received)
-        if done:
-            self.work.device = None   # settled: release the device's rows
         return done
 
     # ------------------------------------------------------------------
@@ -177,28 +175,28 @@ class StreamDecoder:
             self._rstate = np.concatenate([self._rstate, state])
 
     def _peel_device(self, old: int, m: int) -> None:
-        """Wave-peel the whole residual prefix on device and merge.
+        """Wave-peel the residual prefix on device and merge.
 
         ``self.work`` already has previously recovered items removed, so
-        the device decoder starts from a clean residual; it returns the
-        newly recovered items plus the peeled residual, and the host walks
-        each new item's chain to its first index ≥ m so later windows keep
-        extending it (`_walk`).  A ``max_diff`` overflow falls back to the
-        exact host peel for this window.
+        the device decoder starts from a clean residual; only its host rows
+        are staged.  It returns the newly recovered items, the peeled
+        residual stays on the device, and the host walks each new item's
+        chain to its first index ≥ m so later windows keep extending it
+        (`_walk`).  A ``max_diff`` overflow falls back to the exact host
+        peel for this window.
         """
-        from repro.kernels.ops import decode_device, host_symbols_to_device
-        self.to_host()
-        res = decode_device(*host_symbols_to_device(self.work.host),
-                            nbytes=self.nbytes, key=self.key,
-                            max_diff=self.max_diff)
+        from repro.kernels.ops import decode_device_batched
+        (res,) = decode_device_batched([self.work], nbytes=self.nbytes,
+                                       key=self.key, max_diff=self.max_diff)
         if res.overflow:
             self.peel_window(old, m)
             return
-        self.merge_device_result(res._replace(residual=Residual(res.residual)))
+        self.merge_device_result(res)
 
     def merge_device_result(self, res) -> None:
-        """Fold a successful :func:`repro.kernels.ops.decode_device` (or one
-        unit of ``decode_device_batched``) outcome into host state: adopt
+        """Fold one unit's successful
+        :func:`repro.kernels.ops.decode_device_batched` outcome into host
+        state: adopt
         the peeled residual as ``work`` and register each newly recovered
         item with its chain, started from the seed the device returned,
         advanced to the first index ≥ the prefix length (so later windows
@@ -206,9 +204,9 @@ class StreamDecoder:
         False — overflowed decodes leave state untouched and the caller
         falls back to :meth:`peel_window`.
 
-        ``res.residual`` is a :class:`~repro.core.symbols.Residual`: host
-        symbols (a lone decode) or the rows a batched decode left on the
-        device, in which case the host drops the rows it had staged.
+        ``res.residual`` is a :class:`~repro.core.symbols.Residual`,
+        normally the rows the decode left on the device, in which case the
+        host drops the rows it had staged.
 
         Tail-aware: the decode may cover only a *prefix* of the current
         ``work`` (``res.residual.m ≤ work.m``) — a pipelined engine absorbs

@@ -97,8 +97,8 @@ class Residual:
     on the host (``host``: the rows absorbed since).  ``base`` is 0 while
     the host holds the whole prefix.  ``row0_empty`` is the device's reading
     of row 0 after that decode; the host's own row 0 is read while
-    ``base`` is 0.  A settled decoder drops its handle: ``device`` is then
-    None with ``base`` still set.
+    ``base`` is 0.  The engine drops a settled unit's handle: ``device`` is
+    then None with ``base`` still set.
     """
     host: CodedSymbols
     base: int = 0

@@ -1,10 +1,10 @@
-"""Shared device-side hashing for the encode and peel kernels.
+"""Shared device-side hashing for the encoder's mapping.
 
-Both the mapping kernel (`map_indices`) and the wave-peeling decoder
-(`peel`) need the same two keyed hashes of an item block: the SipHash-2-4
-checksum (paper §4.3) and the mapping-PRNG seed derived from the tweaked
-session key.  Factored here so the encoder and decoder kernels stay
-bit-identical by construction.
+Both the mapping kernel (`map_indices`) and its pure-jnp twin
+(`ref.map_indices_ref`) need the same two keyed hashes of an item block:
+the SipHash-2-4 checksum (paper §4.3) and the mapping-PRNG seed derived
+from the tweaked session key.  Factored here so the two stay bit-identical
+by construction.
 """
 from __future__ import annotations
 
@@ -24,12 +24,6 @@ def pallas_interpret(interpret: bool):
 
 def _siphash(in_kernel: bool):
     return siphash24_pair_kernel if in_kernel else siphash24_pair
-
-
-def checksum_pair(items, key, nbytes: int, *, in_kernel: bool = False):
-    """(hi, lo) uint32 checksum of an item block ``(..., L)``;
-    ``in_kernel`` inside a Pallas kernel's body."""
-    return _siphash(in_kernel)(items, key, nbytes)
 
 
 def checksum_and_seed(items, key, nbytes: int, *, in_kernel: bool = False):

@@ -2,12 +2,13 @@
 
 ``encode_device`` (pad → map_indices → iblt_encode) is the TPU-native
 counterpart of ``repro.core.encode`` and produces bit-identical coded
-symbols; ``decode_device`` (pad → wave peeling, :mod:`kernels.peel`) is the
-counterpart of ``repro.core.peel`` and recovers the identical difference.
-:func:`peel_engine` is the one place the platform picks the engine: the
-compiled Pallas kernels on TPU, the pure-jnp "ref" engine elsewhere (the
-Pallas interpreter pays ~10 ms/op; the kernels themselves are validated in
-interpret mode in tests at small sizes).
+symbols.  Every device decode is :func:`decode_device_batched_start` over
+U ≥ 1 units: their residual rows staged in one array, the batched wave
+peel (:func:`repro.kernels.peel.peel_waves_batched`), and what the host
+reads packed into flags and chunks of recovered rows; each unit's peeled
+residual stays on the device.  :func:`decode_device` is one unit of it
+over host arrays, with the residual fetched — the counterpart of
+``repro.core.peel``, recovering the identical difference.
 """
 from __future__ import annotations
 
@@ -23,32 +24,7 @@ from repro.trace import STAGE, UNSTAGE, WAIT, span
 
 from .iblt_encode import iblt_encode
 from .map_indices import map_indices
-from .peel import peel_waves, peel_waves_batched
-
-
-def peel_engine(batched: bool = False) -> str:
-    """The kernel engine the device path runs here: ``"pallas"`` (compiled
-    kernels) or ``"ref"`` (pure jnp).
-
-    A lone decode and the encoder take the Pallas kernels on a TPU and the
-    ref engine elsewhere.  The batched decode takes ``"ref"`` everywhere:
-    the ref engine's purity scan and chains, whose dense stages ``vmap``
-    over the unit axis and compile for any backend, with chain removal as
-    one matrix product over the wave's candidate rows
-    (:func:`repro.kernels.iblt_dense.iblt_apply_dense`).
-    """
-    if batched or jax.default_backend() != "tpu":
-        return "ref"
-    return "pallas"
-
-
-def _engine(kernel, interpret):
-    """Fill unset ``kernel``/``interpret`` from :func:`peel_engine`: Pallas
-    kernels run compiled where the ref engine is not the default, in
-    interpret mode otherwise."""
-    auto = peel_engine()
-    return (auto if kernel is None else kernel,
-            auto == "ref" if interpret is None else interpret)
+from .peel import peel_waves_batched
 
 
 def _pad_items(items, block_n):
@@ -70,9 +46,14 @@ def encode_device(items, *, m: int, nbytes: int | None = None,
     kmax(m); truncation probability < 1e-12).
 
     ``mapping``: "pallas" (map_indices kernel) or "ref" (pure-jnp chain);
-    the default is :func:`peel_engine`'s.  Both produce identical
-    indices."""
-    mapping, interpret = _engine(mapping, interpret)
+    both produce identical indices.  By default a TPU runs the compiled
+    kernels, any other platform the ref chain and the Pallas interpreter
+    (~10 ms/op; the kernels are validated there at small sizes)."""
+    on_tpu = jax.default_backend() == "tpu"
+    if mapping is None:
+        mapping = "pallas" if on_tpu else "ref"
+    if interpret is None:
+        interpret = not on_tpu
     items = jnp.asarray(items, dtype=jnp.uint32)
     n0 = items.shape[0]
     L = items.shape[1]
@@ -136,7 +117,7 @@ def device_symbols_to_host(sums, checks, counts, nbytes: int):
 def host_symbols_to_device(sym):
     """CodedSymbols -> (sums (m, L) u32, checks (m, 2) u32, counts (m,) i32),
     the device layout (uint64 checksums split into (hi, lo) word pairs) in
-    host arrays; :func:`decode_device` pads and stages them.  Inverse of
+    host arrays, as :func:`decode_device` takes them.  Inverse of
     :func:`device_symbols_to_host` (tested round-trip)."""
     checks = np.empty((sym.m, 2), np.uint32)
     checks[:, 0] = (sym.checks >> np.uint64(32)).astype(np.uint32)
@@ -146,14 +127,16 @@ def host_symbols_to_device(sym):
 
 
 class DeviceDecodeResult(NamedTuple):
-    """Host-materialized outcome of :func:`decode_device`."""
+    """Host-materialized outcome of one unit's device decode."""
     items: np.ndarray     # (r, L) uint32 — recovered source symbols
     hashes: np.ndarray    # (r,) uint64   — their checksums
     sides: np.ndarray     # (r,) int8     — +1 remote-only, -1 local-only
     success: bool         # all symbols emptied (difference fully recovered)
     overflow: bool        # max_diff exceeded — decode stopped mid-peel
     rounds: int           # peel waves executed
-    residual: object      # CodedSymbols — symbols after all removals
+    # symbols after all removals: host CodedSymbols from decode_device, a
+    # Residual on the device from the batched decode
+    residual: object
     transfer_bytes: int = 0   # host→device staged + device→host fetched
     # (r,) uint64 — the recovered items' mapping seeds, as
     # :func:`repro.core.mapping.map_seeds` gives them
@@ -174,14 +157,17 @@ def _fetch(tree):
 
 def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
                   max_diff: int | None = None, max_rounds: int = 10_000,
-                  K: int | None = None, block_n: int = 256,
-                  block_m: int = 256, interpret: bool | None = None,
-                  kernel: str | None = None) -> DeviceDecodeResult:
+                  K: int | None = None,
+                  block_m: int = 256) -> DeviceDecodeResult:
     """Wave-peel difference symbols on device (paper §3 decode).
 
     Inputs are device-layout difference symbols — sums (m, L) uint32,
     checks (m, 2) uint32, counts (m,) int32, e.g. from
     :func:`host_symbols_to_device` or an ``encode_device`` subtraction.
+    They decode as one unit of :func:`decode_device_batched`, and the
+    peeled residual is fetched: ``residual`` is host
+    :class:`~repro.core.symbols.CodedSymbols`, its bytes counted in
+    ``transfer_bytes``.
 
     ``max_diff`` bounds the fixed-shape recovered-item buffers; it defaults
     to the tile-padded prefix length (≥ m), which can never overflow:
@@ -190,57 +176,18 @@ def decode_device(sums, checks, counts, *, nbytes: int, key=DEFAULT_KEY,
     recovers at most m items.  A tighter bound trades buffer size for a possible
     ``overflow=True`` outcome — the decode stops with the overflowing wave
     unapplied (items/residual cover only the completed waves) and the
-    caller should fall back to the host decoder.
-
-    ``kernel``: "pallas" (purity/map/apply kernels) or "ref" (pure jnp);
-    the default is :func:`peel_engine`'s.  Off the interpreter the whole
-    wave loop is one jit program per tile bucket under
-    ``jax.lax.while_loop``, with ``m`` traced, so a growing prefix
-    recompiles only when it crosses a tile boundary.  Chains are truncated
+    caller should fall back to the host decoder.  Chains are truncated
     at ``kmax(mp)`` like the device encoder (< 1e-12 probability).
     """
-    kernel, interpret = _engine(kernel, interpret)
-    sums = np.asarray(sums, np.uint32)
-    m, L = sums.shape
-    if nbytes is None:
-        nbytes = 4 * L
-    if m == 0:
-        from repro.core.symbols import CodedSymbols
-        return DeviceDecodeResult(
-            np.zeros((0, L), np.uint32), np.zeros(0, np.uint64),
-            np.zeros(0, np.int8), True, False, 0,
-            CodedSymbols.zeros(0, nbytes))
-    mp = ((m + block_m - 1) // block_m) * block_m
-    # defaults quantize to the tile bucket (mp ≥ m) so a growing stream
-    # prefix re-uses one compiled program per bucket
-    if K is None:
-        K = kmax(mp)
-    D = mp if max_diff is None else max(int(max_diff), 1)
-    with span(STAGE, units=1, mp=mp):
-        # pad on the host: the padded shapes are the bucket's, whatever m
-        sums_p = np.zeros((mp, L), np.uint32)
-        checks_p = np.zeros((mp, 2), np.uint32)
-        counts_p = np.zeros((mp, 1), np.int32)
-        sums_p[:m] = sums
-        checks_p[:m] = np.asarray(checks, np.uint32)
-        counts_p[:m, 0] = np.asarray(counts, np.int32)
-        staged, sent = _stage(sums_p, checks_p, counts_p)
-        out = peel_waves(
-            *staged, m=m, nbytes=nbytes, key=key, max_diff=D, K=K,
-            max_rounds=max_rounds, kernel=kernel, block_m=block_m,
-            block_n=block_n, interpret=interpret,
-            use_while_loop=not interpret)
-    with span(WAIT):
-        (state, success), got = _fetch(out)
-    with span(UNSTAGE):
-        n_rec = int(state.n_rec)
-        residual = device_symbols_to_host(
-            state.sums[:m], state.checks[:m], state.counts[:m, 0], nbytes)
-        return DeviceDecodeResult(
-            state.rec_items[:n_rec], _pairs64(state.rec_checks[:n_rec]),
-            state.rec_sides[:n_rec].astype(np.int8), bool(success),
-            bool(state.overflow), int(state.rounds), residual, sent + got,
-            _pairs64(state.rec_seeds[:n_rec]))
+    from repro.core.symbols import Residual
+    unit = Residual(device_symbols_to_host(sums, checks, counts, nbytes))
+    (res,) = decode_device_batched(
+        [unit], nbytes=nbytes, key=key, max_diff=max_diff,
+        max_rounds=max_rounds, K=K, block_m=block_m)
+    got = res.residual
+    rows, fetched = got.device.fetch() if got.base else (got.host, 0)
+    return res._replace(residual=rows,
+                        transfer_bytes=res.transfer_bytes + fetched)
 
 
 # Bytes of recovered rows per chunk that crosses device→host (about 2 MiB).
@@ -423,8 +370,7 @@ class PendingBatchedDecode:
 def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
                                 max_diff: int | None = None,
                                 max_rounds: int = 10_000, K: int | None = None,
-                                block_m: int = 256, pad_units: int | None = None,
-                                interpret: bool | None = None
+                                block_m: int = 256, pad_units: int | None = None
                                 ) -> PendingBatchedDecode:
     """Dispatch the batched wave decode of U units without materializing.
 
@@ -438,8 +384,9 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     bucket ``mp = ceil(max_u m_u / block_m) · block_m``.  The per-unit
     true prefix lengths travel as a traced ``(U,)`` data vector into
     :func:`repro.kernels.peel.peel_waves_batched`, which ``vmap``s the wave
-    engine over the unit axis: one compiled program, one dispatch per wave
-    (or one total under ``lax.while_loop`` on TPU), regardless of U.
+    engine over the unit axis: one compiled program, one dispatch in all
+    under ``lax.while_loop`` on a TPU (one per wave of a Python loop
+    elsewhere), regardless of U.
     Another small program (:func:`unstage_batched`) packs what the host
     reads back.
 
@@ -447,7 +394,8 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     *individually*; a unit that trips it freezes only itself and comes
     back with ``overflow=True`` while its neighbours finish — the caller
     falls back to the host decoder for exactly those units.  The default
-    (``mp``) can never overflow, same argument as :func:`decode_device`.
+    (``mp``) can never overflow: a unit recovers at most one item per
+    symbol (see :func:`decode_device`).
 
     ``pad_units`` pads the unit axis to a fixed batch size with empty
     (m=0) dummy units, which no-op after their first wave.  The unit
@@ -461,9 +409,6 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
     ``residual`` is a :class:`~repro.core.symbols.Residual` held on the
     device.
     """
-    # the batched stages are the same everywhere; ``interpret``
-    # only picks one staged program (compiled platforms) or a Python loop
-    _, interpret = _engine(None, interpret)
     from repro.core.symbols import CodedSymbols, Residual
     U = len(units)
     if U == 0:
@@ -522,7 +467,8 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
                                     mp=mp)
         state, success = peel_waves_batched(
             *arrays, m=m, nbytes=nbytes, key=key, max_diff=D, K=K,
-            max_rounds=max_rounds, use_while_loop=not interpret)
+            max_rounds=max_rounds,
+            use_while_loop=jax.default_backend() == "tpu")
         flags, chunks = _unstage_program(state, success,
                                          chunk_bytes=CHUNK_BYTES)
     # wait() materializes per entry of ms (length U): dummy pad units past
@@ -535,8 +481,7 @@ def decode_device_batched_start(units, *, nbytes: int, key=DEFAULT_KEY,
 def decode_device_batched(units, *, nbytes: int, key=DEFAULT_KEY,
                           max_diff: int | None = None,
                           max_rounds: int = 10_000, K: int | None = None,
-                          block_m: int = 256, pad_units: int | None = None,
-                          interpret: bool | None = None
+                          block_m: int = 256, pad_units: int | None = None
                           ) -> list[DeviceDecodeResult]:
     """Wave-peel U units' difference symbols in ONE batched device call.
 
@@ -547,5 +492,5 @@ def decode_device_batched(units, *, nbytes: int, key=DEFAULT_KEY,
     """
     return decode_device_batched_start(
         units, nbytes=nbytes, key=key, max_diff=max_diff,
-        max_rounds=max_rounds, K=K, block_m=block_m, pad_units=pad_units,
-        interpret=interpret).wait()
+        max_rounds=max_rounds, K=K, block_m=block_m,
+        pad_units=pad_units).wait()

@@ -1,43 +1,39 @@
-"""Device-side wave-peeling decoder (paper §3 peeling as dense VPU work).
+"""Device-side wave-peeling decoder (paper §3 peeling as dense XLA work).
 
 Host peeling walks a sparse graph one pure symbol at a time; on TPU we
 restate each belief-propagation round as three dense, fixed-shape stages:
 
-1. **purity scan** — a tiled Pallas kernel re-keys every coded symbol's sum
-   (SipHash-2-4, shared with the encoder via :mod:`kernels.common`) and
-   compares it with the stored checksum: ``±1`` where the symbol holds
-   exactly one source symbol, ``0`` elsewhere.
+1. **purity scan** — every coded symbol's sum is re-keyed (SipHash-2-4,
+   O(L) a row) and compared with the stored checksum: ``±1`` where the
+   symbol holds exactly one source symbol, ``0`` elsewhere.
 2. **compaction + dedupe** — pure rows are gathered into a fixed ``cap``-row
    buffer (``jnp.nonzero(..., size=cap)``), deduped pairwise by checksum
    within the wave and against the already-recovered buffer.  The same item
    being pure at several indices at once is the common case near the end of
    a decode.
 3. **chain removal** — recovered items re-derive their mapped-index chains
-   with the *encoder's own* ``map_indices`` kernel and are XOR-ed out of
-   every position with ``iblt_apply``: the identical (BN items × BM symbols)
-   masked XOR-tree of ``iblt_encode``, plus a signed count update
-   (``counts -= Σ mask·side``).
+   from their map seeds and are XOR-ed out of every position, with a signed
+   count update (``counts -= Σ mask·side``).
 
-The three stages iterate to a fixed point — ``jax.lax.while_loop`` when the
-whole program is jitted for TPU, a plain Python loop in eager/interpret
-mode on CPU.  Every shape is static: symbols are
-padded to ``block_m`` tiles, per-wave compaction holds ``cap`` rows, and the
-recovered-item buffer holds ``max_diff`` rows — a wave that would overflow
-it leaves the state untouched and raises the ``overflow`` flag so the
-caller can fall back to the exact host decoder.
+Every device decode runs :func:`peel_waves_batched`: the wave ``vmap``-ed
+over a leading **unit axis** — U ≥ 1 independent decodes, ragged prefix
+lengths as data, one compiled program per shape bucket (see
+``ops.decode_device_batched``).  Its chain removal is one matrix product
+over the wave's candidate rows
+(:func:`repro.kernels.iblt_dense.iblt_apply_dense`).  The waves iterate to
+a fixed point — ``jax.lax.while_loop`` when the whole program is jitted
+for TPU, a plain Python loop elsewhere.  Every shape is static: symbols
+are padded to a tile bucket, per-wave compaction holds ``cap`` rows, and
+the recovered-item buffer holds ``max_diff`` rows — a wave that would
+overflow it leaves the unit's state untouched and raises its ``overflow``
+flag so the caller can fall back to the exact host decoder.
 
-A pure-jnp engine (``kernel="ref"``) mirrors each stage op-for-op for
-CPU runs and oracle tests; both engines produce bit-identical waves.
-
-For batched serving, :func:`peel_waves_batched` ``vmap``s the identical
-wave over a leading **unit axis** — U independent decodes, ragged prefix
-lengths as data, one compiled program (see ``ops.decode_device_batched``).
-Its chain removal is one matrix product over the wave's candidate rows
-(:func:`repro.kernels.iblt_dense.iblt_apply_dense`), bit-identical to the
-ref engine's bit-parity scatter.
-A unit was originally one shard of a sharded session; through
-``repro.protocol.engine`` it is any (peer, shard) pair in a shape bucket,
-so N concurrent peers cost one dispatch per tick, not N.
+:func:`peel_waves` is the oracle the tests hold it to: one unit, a Python
+loop over jitted stages, and chain removal as the bit-parity scatter of
+:func:`repro.kernels.ref.iblt_apply_ref`; each unit's waves are its waves,
+bit for bit.  A unit was originally one shard of a sharded session;
+through ``repro.protocol.engine`` it is any (peer, shard) pair in a shape
+bucket, so N concurrent peers cost one dispatch per tick, not N.
 """
 from __future__ import annotations
 
@@ -46,65 +42,30 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
+from repro.core.hashing import siphash24_pair
 from repro.core.mapping import map_seeds_pair
 from repro.trace import APPLY_SCOPE, HASH_SCOPE
 
-from .common import checksum_pair, pallas_interpret
 from .iblt_dense import iblt_apply_dense
-from .iblt_encode import iblt_apply
-from .map_indices import map_indices
 from .ref import chains_ref, iblt_apply_ref
 
 
 # ---------------------------------------------------------------------------
 # Stage 1: purity scan.
 # ---------------------------------------------------------------------------
-def _purity_body(sums, checks, counts, *, key, nbytes: int,
-                 in_kernel: bool = False):
-    """(BM, L) sums, (BM, 2) checks, (BM, 1) counts -> (BM,) int32 side.
+def _purity_body(sums, checks, counts, *, key, nbytes: int):
+    """(mp, L) sums, (mp, 2) checks, (mp, 1) counts -> (mp,) int32 side.
 
     ``+1`` / ``-1`` where the symbol is pure (checksum matches the keyed
-    hash of its sum and it is non-empty), ``0`` otherwise.  ``in_kernel``
-    inside a Pallas kernel's body.
+    hash of its sum and it is non-empty), ``0`` otherwise.
     """
     with jax.named_scope(HASH_SCOPE):
-        h_hi, h_lo = checksum_pair(sums, key, nbytes, in_kernel=in_kernel)
+        h_hi, h_lo = siphash24_pair(sums, key, nbytes)
     cnt = counts[:, 0]
     pure = (h_hi == checks[:, 0]) & (h_lo == checks[:, 1]) & (cnt != 0)
     side = jnp.where(cnt > 0, jnp.int32(1), jnp.int32(-1))
     return jnp.where(pure, side, jnp.int32(0))
-
-
-def _purity_kernel(sums_ref, checks_ref, counts_ref, side_ref, *, key,
-                   nbytes: int):
-    side = _purity_body(sums_ref[...], checks_ref[...], counts_ref[...],
-                        key=key, nbytes=nbytes, in_kernel=True)
-    side_ref[...] = side[:, None]
-
-
-def purity_scan(sums, checks, counts, *, key, nbytes: int,
-                block_m: int = 256, interpret: bool = True):
-    """Tiled purity test: (mp, ...) symbol arrays -> (mp,) int32 sides.
-
-    mp must be a multiple of block_m (``ops.decode_device`` pads).
-    """
-    mp, L = sums.shape
-    assert mp % block_m == 0, (mp, block_m)
-    grid = (mp // block_m,)
-    kernel = functools.partial(_purity_kernel, key=key, nbytes=nbytes)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_m, L), lambda i: (i, 0)),
-                  pl.BlockSpec((block_m, 2), lambda i: (i, 0)),
-                  pl.BlockSpec((block_m, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_m, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((mp, 1), jnp.int32),
-        interpret=pallas_interpret(interpret),
-    )(sums, checks, counts)
-    return out[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -204,38 +165,21 @@ def _wave(state: PeelState, m, *, mp: int, cap: int, max_diff: int,
         lambda a, b: jnp.where(overflow, a, b), frozen, out)
 
 
-def _engines(*, nbytes: int, key, K: int, kernel: str, mp: int, block_m: int,
-             block_n: int, interpret: bool):
-    """Build (purity_fn, map_fn(items, m) -> (idxs, seeds), apply_fn(items,
-    idxs, chks, sides, m)) for one engine.  Both engines take ``m`` as
-    data, so one compiled program serves every prefix length within a tile
-    bucket."""
-    if kernel == "pallas":
-        purity_fn = functools.partial(purity_scan, key=key, nbytes=nbytes,
-                                      block_m=block_m, interpret=interpret)
+def _ref_stages(*, nbytes: int, key, K: int, mp: int):
+    """Build the ref engine's (purity_fn, map_fn(items, m) -> (idxs,
+    seeds), apply_fn(items, idxs, chks, sides, m)).  ``m`` is data, so one
+    compiled program serves every prefix length within a tile bucket."""
+    def purity_fn(sums, checks, counts):
+        return _purity_body(sums, checks, counts, key=key, nbytes=nbytes)
 
-        def map_fn(items, m):
-            idxs, _, seeds = map_indices(items, K=K, m=m, nbytes=nbytes,
-                                         key=key, block_n=block_n,
-                                         interpret=interpret)
-            return idxs, seeds
+    def map_fn(items, m):
+        # the candidates' checksums are known: hash for the seed only
+        with jax.named_scope(HASH_SCOPE):
+            h, l = map_seeds_pair(items, key, nbytes)
+        return chains_ref(h, l, K=K, m=m), jnp.stack([h, l], axis=1)
 
-        def apply_fn(items, idxs, chks, sides, m):
-            return iblt_apply(items, idxs, chks, sides, m=m, m_out=mp,
-                              block_m=block_m, block_n=block_n,
-                              interpret=interpret)
-    else:
-        def purity_fn(sums, checks, counts):
-            return _purity_body(sums, checks, counts, key=key, nbytes=nbytes)
-
-        def map_fn(items, m):
-            # the candidates' checksums are known: hash for the seed only
-            with jax.named_scope(HASH_SCOPE):
-                h, l = map_seeds_pair(items, key, nbytes)
-            return chains_ref(h, l, K=K, m=m), jnp.stack([h, l], axis=1)
-
-        def apply_fn(items, idxs, chks, sides, m):
-            return iblt_apply_ref(items, idxs, chks, sides, m=m, m_out=mp)
+    def apply_fn(items, idxs, chks, sides, m):
+        return iblt_apply_ref(items, idxs, chks, sides, m=m, m_out=mp)
     return purity_fn, map_fn, apply_fn
 
 
@@ -279,9 +223,8 @@ def _ref_stages_jit(mp: int, cap: int, max_diff: int, K: int, L: int,
     ``m`` enters both stages as a traced scalar, so a growing stream prefix
     re-uses one compiled program until it crosses a tile boundary.
     """
-    purity_fn, map_fn, apply_fn = _engines(
-        nbytes=nbytes, key=key, K=K, kernel="ref", mp=mp,
-        block_m=mp, block_n=cap, interpret=True)
+    purity_fn, map_fn, apply_fn = _ref_stages(nbytes=nbytes, key=key, K=K,
+                                              mp=mp)
     s1 = jax.jit(functools.partial(_stage1, mp=mp, cap=cap,
                                    max_diff=max_diff, purity_fn=purity_fn))
     s2 = jax.jit(functools.partial(_stage2, mp=mp, max_diff=max_diff,
@@ -289,70 +232,27 @@ def _ref_stages_jit(mp: int, cap: int, max_diff: int, K: int, L: int,
     return s1, s2
 
 
-@functools.lru_cache(maxsize=64)
-def _peel_program(mp: int, D: int, K: int, nbytes: int, key,
-                  max_rounds: int, kernel: str, block_m: int, block_n: int,
-                  interpret: bool):
-    """The whole decode of one shape bucket as one jitted program: state
-    set-up, the wave loop under ``lax.while_loop``, and the success flag.
-    ``m`` is a traced argument, so the program compiles once per bucket."""
-    cap = _cap(D, mp, block_n)
-    purity_fn, map_fn, apply_fn = _engines(
-        nbytes=nbytes, key=key, K=K, kernel=kernel, mp=mp, block_m=block_m,
-        block_n=block_n, interpret=interpret)
-    body = functools.partial(_wave, mp=mp, cap=cap, max_diff=D,
-                             purity_fn=purity_fn, map_fn=map_fn,
-                             apply_fn=apply_fn)
-
-    # the function's name is the program's name in a profiler trace
-    def peel_lone(sums, checks, counts, m):
-        state = jax.lax.while_loop(
-            lambda s: s.changed & ~s.overflow & (s.rounds < max_rounds),
-            lambda s: body(s, m), _init_state(sums, checks, counts, D))
-        return state, _success(state)
-
-    return jax.jit(peel_lone)
-
-
 def peel_waves(sums, checks, counts, *, m: int, nbytes: int, key,
                max_diff: int, K: int, max_rounds: int = 10_000,
-               kernel: str = "ref", block_m: int = 256, block_n: int = 256,
-               interpret: bool = True, use_while_loop: bool = False):
-    """Iterate purity → compact/dedupe → remove to a fixed point.
+               block_n: int = 256):
+    """The oracle: iterate purity → compact/dedupe → remove to a fixed
+    point over one unit.
 
     Inputs are the *padded* difference symbols: sums (mp, L) uint32, checks
-    (mp, 2) uint32, counts (mp, 1) int32 with mp a multiple of block_m and
-    rows [m, mp) zero.  Returns the final :class:`PeelState` plus a
-    ``success`` scalar (all symbols empty — the ρ(0)=1 termination signal
-    holds: symbol 0 empties last).
+    (mp, 2) uint32, counts (mp, 1) int32 with rows [m, mp) zero.  Returns
+    the final :class:`PeelState` plus a ``success`` scalar (all symbols
+    empty — the ρ(0)=1 termination signal holds: symbol 0 empties last).
 
-    ``use_while_loop=True`` runs the cached per-bucket program of
-    :func:`_peel_program` (the TPU path: one dispatch, no recompile as
-    ``m`` grows within a bucket).  Otherwise the loop runs in Python: the
-    ref engine's stages are jitted per shape bucket (with ``m`` as data),
-    and waves that recover nothing skip the removal stage entirely — the
-    common case while a stream decoder is still below the decode threshold.
+    The loop runs in Python over the ref engine's stages, jitted per shape
+    bucket (with ``m`` as data); the wave that recovers nothing ends it
+    before its removal stage.  Tests hold :func:`peel_waves_batched`, the
+    decode every device path runs, to it wave for wave.
     """
     mp, L = sums.shape
     D = max_diff
-    key = tuple(key)
-    if use_while_loop:
-        program = _peel_program(mp, D, K, nbytes, key, max_rounds, kernel,
-                                block_m, block_n, interpret)
-        return program(sums, checks, counts, jnp.int32(m))
-
     cap = _cap(D, mp, block_n)
     state = _init_state(sums, checks, counts, D)
-    if kernel == "ref":
-        s1, s2 = _ref_stages_jit(mp, cap, D, K, L, nbytes, key)
-    else:
-        purity_fn, map_fn, apply_fn = _engines(
-            nbytes=nbytes, key=key, K=K, kernel=kernel, mp=mp,
-            block_m=block_m, block_n=block_n, interpret=interpret)
-        s1 = functools.partial(_stage1, mp=mp, cap=cap, max_diff=D,
-                               purity_fn=purity_fn)
-        s2 = functools.partial(_stage2, mp=mp, max_diff=D,
-                               map_fn=map_fn, apply_fn=apply_fn)
+    s1, s2 = _ref_stages_jit(mp, cap, D, K, L, nbytes, tuple(key))
     rounds = 0
     while rounds < max_rounds:
         p_items, p_chk, p_side, keep, n_new, overflow = s1(
@@ -391,9 +291,7 @@ def _batched_wave_jit(mp: int, cap: int, max_diff: int, K: int, nbytes: int,
 def _batched_wave(mp, cap, max_diff, K, nbytes, key):
     """The vmapped wave: the ref engine's purity scan and chains, and chain
     removal as one product over the ``cap`` candidate rows."""
-    purity_fn, map_fn, _ = _engines(
-        nbytes=nbytes, key=key, K=K, kernel="ref", mp=mp,
-        block_m=mp, block_n=cap, interpret=True)
+    purity_fn, map_fn, _ = _ref_stages(nbytes=nbytes, key=key, K=K, mp=mp)
 
     def apply_fn(items, idxs, chks, sides, m):
         return iblt_apply_dense(items, idxs, chks, sides, m=m, m_out=mp)
@@ -428,7 +326,7 @@ def peel_waves_batched(sums, checks, counts, *, m, nbytes: int, key,
                        block_n: int = 256, use_while_loop: bool = False):
     """Wave-peel ``S`` decode units' difference symbols in lockstep.
 
-    The batched counterpart of :func:`peel_waves` for fan-out serving: the
+    Every device decode, of one unit or many, runs here: the
     inputs carry a leading **unit axis** — sums ``(S, mp, L)`` uint32,
     checks ``(S, mp, 2)`` uint32, counts ``(S, mp, 1)`` int32 — where
     ``mp`` is the *shared* tile bucket (every unit padded to the longest
@@ -443,7 +341,7 @@ def peel_waves_batched(sums, checks, counts, *, m, nbytes: int, key,
     (:func:`_batched_wave_jit`): the ref engine's purity scan and chains,
     and chain removal as one matrix product over the wave's candidate rows
     (:func:`repro.kernels.iblt_dense.iblt_apply_dense`), so each unit's
-    waves are the lone ref engine's, bit for bit.  A unit whose wave
+    waves are the :func:`peel_waves` oracle's, bit for bit.  A unit whose wave
     recovers nothing simply no-ops while hotter units keep peeling, and a
     unit that trips ``max_diff`` freezes its own state and raises only its
     own ``overflow`` flag — the other units are unaffected (per-unit host

@@ -53,7 +53,6 @@ from typing import NamedTuple
 
 from repro.core.decoder import resolve_backend
 from repro.core.stream import StreamDecoder
-from repro.core.symbols import Residual
 from repro.core.wire import decode_frames, decode_shard_frames
 from repro.trace import (ABSORB, HOST_PEEL, MERGE, PLAN, REPORT, SERVE, TICK,
                          WIRE_DECODE, span)
@@ -341,8 +340,10 @@ class PendingRound:
                     _host_peel(du)
                     continue
                 with span(MERGE, peer=du.peer.index):
-                    du.unit.decoder.merge_device_result(res)
-                    du.unit.decoder.mark_decoded(at=du.m)
+                    dec = du.unit.decoder
+                    dec.merge_device_result(res)
+                    if dec.mark_decoded(at=du.m):
+                        dec.work.device = None   # settled: free its rows
 
 
 def _host_peel(du: DecodeUnit) -> None:
@@ -375,10 +376,8 @@ def execute_round(units: list[DecodeUnit], block_m: int = 256,
     settling one by one re-use one compiled program instead of
     recompiling per departure.  A unit's residual stays on the device
     from one batched decode to the next, and only the rows absorbed since
-    are staged.  A lone plain session in sync mode whose residual is on
-    the host skips the batch entirely and takes
-    :func:`~repro.kernels.ops.decode_device`, whose Pallas peel kernels
-    serve single-peer decodes on TPU."""
+    are staged.  A bucket of one unit, a lone plain session's, is a batch
+    like any other."""
     from repro.kernels import ops
     plan = build_plan(units, block_m)
     for du in plan.host:
@@ -394,13 +393,6 @@ def execute_round(units: list[DecodeUnit], block_m: int = 256,
             pending = ops.decode_device_batched_start(
                 works, nbytes=nbytes, key=key, max_diff=D, block_m=block_m,
                 pad_units=_next_pow2(len(us)))
-        elif len(us) == 1 and not us[0].peer.sharded and not works[0].base:
-            res = ops.decode_device(
-                *ops.host_symbols_to_device(works[0].host), nbytes=nbytes,
-                key=key, max_diff=us[0].peer.max_diff, block_m=block_m)
-            pending = ops.PendingBatchedDecode(
-                None, None, (), nbytes,
-                results=[res._replace(residual=Residual(res.residual))])
         else:
             pending = ops.PendingBatchedDecode(
                 None, None, (), nbytes, results=ops.decode_device_batched(

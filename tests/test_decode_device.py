@@ -1,8 +1,8 @@
 """Device wave-peeling decoder ≡ host peel (items, sides, success).
 
-These run the decoder's pure-jnp "ref" engine (the CPU path of
-``decode_device``); the Pallas kernels behind the same wave algebra are
-validated in tests/test_kernels.py at interpret-friendly sizes.
+``decode_device`` is one unit of the batched decode; here it runs the
+Python wave loop of the CPU, and its waves are held to the
+:func:`repro.kernels.peel.peel_waves` oracle.
 """
 import numpy as np
 import pytest
@@ -13,9 +13,10 @@ from repro.core.decoder import resolve_backend
 from repro.core.hashing import DEFAULT_KEY
 from repro.core.stream import StreamDecoder
 from repro.core.symbols import CodedSymbols, Residual
-from repro.core.mapping import map_seeds
+from repro.core.mapping import kmax, map_seeds
 from repro.kernels.ops import (decode_device, decode_device_batched,
                                device_symbols_to_host, host_symbols_to_device)
+from repro.kernels.peel import peel_waves
 
 RNG = np.random.default_rng(2025)
 
@@ -75,6 +76,36 @@ def test_decode_device_empty_prefix():
     sym = CodedSymbols.zeros(0, 8)
     res = decode_device(*host_symbols_to_device(sym), nbytes=8)
     assert res.success and not res.overflow and res.items.shape == (0, 2)
+
+
+@pytest.mark.parametrize("max_diff", [None, 5])
+def test_decode_device_equals_the_oracle(max_diff):
+    """One unit of the batched decode == the lone ref waves of
+    ``peel_waves``, bit for bit: items, sides, waves, flags and the whole
+    residual, on a full decode and on one that overflows ``max_diff``."""
+    L, m, mp = 3, 150, 256
+    sym, _, _ = diff_symbols(20, 17, L, m)
+    sums, checks, counts = host_symbols_to_device(sym)
+    res = decode_device(sums, checks, counts, nbytes=4 * L,
+                        max_diff=max_diff)
+    pad = ((0, mp - m), (0, 0))
+    state, success = peel_waves(
+        np.pad(sums, pad), np.pad(checks, pad), np.pad(counts[:, None], pad),
+        m=m, nbytes=4 * L, key=DEFAULT_KEY, max_diff=max_diff or mp,
+        K=kmax(mp))
+    n = int(state.n_rec)
+    assert res.items.shape[0] == n
+    np.testing.assert_array_equal(res.items, state.rec_items[:n])
+    np.testing.assert_array_equal(res.sides, state.rec_sides[:n])
+    assert (res.rounds, res.success, res.overflow) == \
+        (int(state.rounds), bool(success), bool(state.overflow))
+    assert res.overflow == (max_diff is not None)
+    assert res.success == (max_diff is None) == (n == 37)
+    want = device_symbols_to_host(state.sums[:m], state.checks[:m],
+                                  state.counts[:m, 0], 4 * L)
+    np.testing.assert_array_equal(res.residual.sums, want.sums)
+    np.testing.assert_array_equal(res.residual.checks, want.checks)
+    np.testing.assert_array_equal(res.residual.counts, want.counts)
 
 
 # ------------------------------------------- overflow -> host fallback ----
@@ -140,7 +171,29 @@ def test_stream_decoder_device_incremental_windows():
     assert {r.tobytes() for r in hb} == {r.tobytes() for r in db}
 
 
-@pytest.mark.parametrize("path", ["lone_ref", "lone_pallas", "batched"])
+def test_stream_decoder_device_keeps_its_residual_on_the_device():
+    """A device-backed decoder's peeled residual stays on the device from
+    one window to the next: after its second window only the rows
+    absorbed since are on the host, and it decodes what the host does."""
+    nbytes = 8
+    sym, ai, bi = diff_symbols(30, 25, 2, 160)
+    host_dec = StreamDecoder(nbytes)
+    dev_dec = StreamDecoder(nbytes, backend="device")
+    for lo in range(0, 160, 16):
+        win = sym.window(lo, lo + 16)
+        assert host_dec.receive(win.copy()) == dev_dec.receive(win.copy())
+        if lo == 16:
+            assert not dev_dec.decoded
+            assert dev_dec.work.base == 32 and dev_dec.work.host.m == 0
+            assert dev_dec.work.device is not None
+    assert dev_dec.decoded and dev_dec.decoded_at == host_dec.decoded_at
+    for got, want in zip(dev_dec.result(), host_dec.result()):
+        assert {r.tobytes() for r in got} == {r.tobytes() for r in want}
+    assert {r.tobytes() for r in dev_dec.result()[0]} == \
+        {r.tobytes() for r in ai}
+
+
+@pytest.mark.parametrize("path", ["lone", "batched"])
 def test_device_results_carry_the_map_seeds(path):
     """Every recovered row comes back with the mapping seed the wave
     derived its chain from, the one the host's ``map_seeds`` gives it, so
@@ -150,8 +203,7 @@ def test_device_results_carry_the_map_seeds(path):
     if path == "batched":
         (res,) = decode_device_batched([Residual(sym)], nbytes=4 * L)
     else:
-        res = decode_device(*host_symbols_to_device(sym), nbytes=4 * L,
-                            kernel=path.split("_")[1], interpret=True)
+        res = decode_device(*host_symbols_to_device(sym), nbytes=4 * L)
     assert res.success and res.items.shape[0] == 15
     assert res.seeds.dtype == np.uint64
     np.testing.assert_array_equal(res.seeds,

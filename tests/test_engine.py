@@ -263,8 +263,8 @@ def test_device_counters_add_up(monkeypatch, entry):
     prefix: three pipelined peers in one padded bucket, or one lone
     session on the non-pipelined path.  Every batched decode after a
     unit's first finds its residual on the device (``resident_decodes``),
-    and the three peers move fewer bytes than staging and fetching each
-    bucket's whole residual alone would; the lone session stages whole."""
+    and the peers move fewer bytes than staging and fetching each
+    bucket's whole residual alone would."""
     import jax
     from repro.kernels import ops
     moved = [0]
@@ -323,12 +323,33 @@ def test_device_counters_add_up(monkeypatch, entry):
         assert r.device_waves >= r.device_decodes > 0
         # every decode here lands in the first tile bucket, mp = 256
         assert r.device_symbols == 256 * r.device_decodes
-        assert r.resident_decodes == \
-            (r.device_decodes - 1 if entry == "engine" else 0)
-    if entry == "engine":
-        assert moved[0] < restaged[0]
-        # each bucket's recovered rows fit one chunk: one read a dispatch
-        assert reads[0] == waits[0] > 0
+        assert r.resident_decodes == r.device_decodes - 1
+    assert moved[0] < restaged[0]
+    # each bucket's recovered rows fit one chunk: one read a dispatch
+    assert reads[0] == waits[0] > 0
+
+
+def test_lone_session_decodes_through_the_batched_program(monkeypatch):
+    """``run_session`` on the device backend decodes each window as a
+    batch of one unit, its residual kept on the device, and never through
+    ``decode_device``; it recovers the difference exactly."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "decode_device",
+                        lambda *a, **k: (_ for _ in ()).throw(
+                            AssertionError("decode_device called")))
+    nbytes = 16
+    state = rand_items(900, nbytes, tag=0)
+    items, only_remote, only_local = stale_replica(state, 40, 6, nbytes)
+    stream = SymbolStream.from_items(state, nbytes)
+    rep = run_session(stream, Session(local=Sketch.from_items(items, nbytes),
+                                      pacing=FixedBlock(16),
+                                      backend="device"), wire=True)
+    assert as_sorted_bytes(rep.only_remote_bytes()) == \
+        as_sorted_bytes(only_remote)
+    assert as_sorted_bytes(rep.only_local_bytes()) == \
+        as_sorted_bytes(only_local)
+    assert rep.host_decodes == rep.overflows == 0
+    assert rep.resident_decodes == rep.device_decodes - 1 > 0
 
 
 # ------------------------------------------- the residual stays resident ----

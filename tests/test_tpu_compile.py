@@ -23,8 +23,7 @@ from repro.kernels.iblt_dense import iblt_apply_dense
 from repro.kernels.iblt_encode import iblt_apply
 from repro.kernels.map_indices import map_indices
 from repro.kernels import ops
-from repro.kernels.peel import (PeelState, peel_waves, peel_waves_batched,
-                                purity_scan)
+from repro.kernels.peel import PeelState, peel_waves_batched
 
 MP = 2048                  # symbols: the tile bucket of a d≈1,000 decode
 K = kmax(16384)            # 65 chain slots
@@ -64,13 +63,6 @@ def _symbols(sharding, L, lead=()):
             _shape(sharding, lead + (MP, 1), jnp.int32))
 
 
-def test_purity_scan_compiles(one_chip):
-    def scan(sums, checks, counts):
-        return purity_scan(sums, checks, counts, key=KEY, nbytes=92,
-                           interpret=False)
-    jax.jit(scan).lower(*_symbols(one_chip, 23)).compile()
-
-
 @pytest.mark.parametrize("L", [2, 23])
 def test_iblt_apply_compiles(one_chip, L):
     def apply(items, idxs, chks, sides, m):
@@ -106,27 +98,29 @@ def test_map_indices_compiles(one_chip, L):
                           _shape(one_chip, (), jnp.int32)).compile()
 
 
-def test_pallas_peel_program_compiles(one_chip):
-    """The lone-session decode: the wave loop over the three kernels, as
-    ``decode_device`` stages it off the interpreter."""
-    def decode(sums, checks, counts, m):
-        return peel_waves(sums, checks, counts, m=m, nbytes=92, key=KEY,
-                          max_diff=1024, K=kmax(MP), kernel="pallas",
-                          interpret=False, use_while_loop=True)
-    jax.jit(decode).lower(*_symbols(one_chip, 23),
-                          _shape(one_chip, (), jnp.int32)).compile()
-
-
-def test_batched_peel_program_fits(one_chip):
-    """The engine's batched decode of 8 units in one bucket."""
+def _batched_peel_temp(sharding, U):
+    """Temporaries of the batched decode of U units of 92-byte rows in the
+    bucket mp = 2048 at max_diff = 1024."""
     def decode(sums, checks, counts, m):
         return peel_waves_batched(sums, checks, counts, m=m, nbytes=92,
                                   key=KEY, max_diff=1024, K=kmax(MP),
                                   use_while_loop=True)
     compiled = jax.jit(decode).lower(
-        *_symbols(one_chip, 23, lead=(8,)),
-        _shape(one_chip, (8,), jnp.int32)).compile()
-    temp = compiled.memory_analysis().temp_size_in_bytes
+        *_symbols(sharding, 23, lead=(U,)),
+        _shape(sharding, (U,), jnp.int32)).compile()
+    return compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_batched_peel_program_fits(one_chip):
+    """The engine's batched decode of 8 units in one bucket."""
+    temp = _batched_peel_temp(one_chip, 8)
+    assert temp < BATCHED_TEMP_BUDGET, temp
+
+
+def test_one_unit_batched_peel_program_fits(one_chip):
+    """A lone session's decode: the same program at U = 1, in the single
+    cell's largest bucket."""
+    temp = _batched_peel_temp(one_chip, 1)
     assert temp < BATCHED_TEMP_BUDGET, temp
 
 
